@@ -53,6 +53,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import context as ctx_mod
+from repro.core.machine import get_machine
 
 __all__ = [
     "CoroRefs",
@@ -539,7 +540,12 @@ def coro_call(
                           body=body, prologue=prologue, epilogue=epilogue,
                           carry_init=carry_init, grid_step=grid_step)
 
-        kwargs = {}
+        # Mosaic compiles the kernel under the same VMEM budget the depth
+        # solver capped its context bytes at (autotune.choose_depth reads
+        # machine.vmem_bytes); its default scoped limit (16 MiB on v5e)
+        # would refuse the depths the solver hands out.
+        kwargs = {"name": spec.name, "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=get_machine().vmem_bytes)}
         if input_output_aliases is not None:
             kwargs["input_output_aliases"] = input_output_aliases
         if num_scalar_prefetch:

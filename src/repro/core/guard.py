@@ -68,6 +68,8 @@ __all__ = [
     "check_injected",
     "guarded_call",
     "last_ladder",
+    "numerics_fault",
+    "parity_matches",
     "parity_mode",
     "reset",
     "scan_output",
@@ -105,7 +107,8 @@ class SubstrateError(RuntimeError):
 
     def __init__(self, message: str, *, kernel: str = "?",
                  machine: Optional[str] = None, depth: Optional[int] = None,
-                 tile: Optional[Tuple[int, ...]] = None):
+                 tile: Optional[Tuple[int, ...]] = None,
+                 injected: bool = False):
         if machine is None:
             machine = _machine_name()
         super().__init__(
@@ -115,6 +118,7 @@ class SubstrateError(RuntimeError):
         self.machine = machine
         self.depth = depth
         self.tile = tile
+        self.injected = injected   # raised by the fault injector on purpose
 
 
 class KernelCompileError(SubstrateError):
@@ -342,14 +346,21 @@ def scan_output(kernel: str, out: Any, *,
         if not jnp.issubdtype(leaf.dtype, jnp.floating):
             continue
         if not bool(jnp.isfinite(leaf).all()):
-            _count("numerics_faults")
-            from repro.obs import trace
-            _tracer().instant("substrate_nonfinite", tid=trace.TID_KERNEL,
-                              kernel=kernel, depth=depth)
-            return KernelNumericsError(
-                "non-finite values in kernel output", kernel=kernel,
-                depth=depth)
+            return numerics_fault(kernel, depth=depth)
     return None
+
+
+def numerics_fault(kernel: str, *,
+                   depth: Optional[int] = None) -> KernelNumericsError:
+    """Count and trace one non-finite output of `kernel`; returns the typed
+    error for the caller to raise (`scan_output`, and callers that check a
+    finite flag their jitted step computed on the device)."""
+    _count("numerics_faults")
+    from repro.obs import trace
+    _tracer().instant("substrate_nonfinite", tid=trace.TID_KERNEL,
+                      kernel=kernel, depth=depth)
+    return KernelNumericsError("non-finite values in kernel output",
+                               kernel=kernel, depth=depth)
 
 
 def _tolerance(leaves: Sequence[Any]) -> Tuple[float, float]:
@@ -359,7 +370,9 @@ def _tolerance(leaves: Sequence[Any]) -> Tuple[float, float]:
     return 2e-3, 2e-3
 
 
-def _parity_matches(out: Any, ref: Any) -> bool:
+def parity_matches(out: Any, ref: Any) -> bool:
+    """Leafwise allclose at the guard's tolerances (bf16/f16 anywhere in
+    `out`: 3e-2, else 2e-3); integer leaves must match exactly."""
     a = jax.tree_util.tree_leaves(out)
     b = jax.tree_util.tree_leaves(ref)
     if len(a) != len(b):
@@ -393,26 +406,26 @@ def check_injected(kernel: str, injector: Optional[Any] = None,
     if inj.fire("kernel_compile", kernel=kernel, **ctx):
         _count("injected_faults")
         raise KernelCompileError("injected kernel compile failure",
-                                 kernel=kernel)
+                                 kernel=kernel, injected=True)
     if inj.fire("kernel_oom", kernel=kernel, **ctx):
         _count("injected_faults")
         raise KernelResourceError("injected RESOURCE_EXHAUSTED",
-                                  kernel=kernel)
+                                  kernel=kernel, injected=True)
     if inj.fire("kernel_nan", kernel=kernel, **ctx):
         _count("injected_faults")
         raise KernelNumericsError("injected non-finite kernel output",
-                                  kernel=kernel)
+                                  kernel=kernel, injected=True)
 
 
 def _inject_pre(kernel: str, depth: int) -> None:
     if _injector.fire("kernel_compile", kernel=kernel, depth=depth):
         _count("injected_faults")
         raise KernelCompileError("injected kernel compile failure",
-                                 kernel=kernel, depth=depth)
+                                 kernel=kernel, depth=depth, injected=True)
     if _injector.fire("kernel_oom", kernel=kernel, depth=depth):
         _count("injected_faults")
         raise KernelResourceError("injected RESOURCE_EXHAUSTED",
-                                  kernel=kernel, depth=depth)
+                                  kernel=kernel, depth=depth, injected=True)
 
 
 def _inject_poison(kernel: str, out: Any) -> Any:
@@ -510,7 +523,7 @@ def _maybe_parity(spec: Any, operands: Sequence[Any], out: Any, depth: int,
         ref = twin(spec, *operands)
     except Exception:
         return out, False           # the twin cannot police this call
-    if _parity_matches(out, ref):
+    if parity_matches(out, ref):
         return out, False
     _count("parity_mismatches")
     from repro.obs import trace

@@ -50,7 +50,9 @@ __all__ = [
     "set_machine",
     "machine_profile",
     "profile_names",
+    "check_device",
     "default_interpret",
+    "DeviceProfileError",
 ]
 
 MACHINE_ENV = "REPRO_MACHINE"
@@ -69,6 +71,7 @@ class MachineModel:
     request_slots: int       # outstanding-DMA bound ("SPM request slots")
     clock_ghz: float         # core clock (cycles <-> seconds in core.sim)
     backend: str = "tpu"     # "tpu" | "interpret": kernel dispatch default
+    device_kind: str = ""    # jax's device_kind of the chip described
 
     def replace(self, **kw) -> "MachineModel":
         return dataclasses.replace(self, **kw)
@@ -93,6 +96,7 @@ _V5E = MachineModel(
     ici_bw=50e9,                  # per link
     request_slots=64,             # paper's "capped only by SPM request slots"
     clock_ghz=0.94,
+    device_kind="TPU v5 lite",    # what jax reports for a v5e chip
 )
 
 # The paper's latency dial (§V): the same chip in front of far memory that
@@ -197,14 +201,42 @@ def set_machine(m: Union[str, MachineModel, None] = None) -> MachineModel:
         return _active
 
 
+class DeviceProfileError(RuntimeError):
+    """The active profile does not describe the TPU this process drives."""
+
+
+def check_device(device_kind: str) -> MachineModel:
+    """The profile that drives a TPU must describe that chip: a kind no
+    profile knows, a profile of another chip, or an interpret-backend
+    profile (which would run every kernel in the interpreter) is an error,
+    never a silent default. Returns the checked active profile."""
+    m = get_machine()
+    if m.backend == "interpret":
+        raise DeviceProfileError(
+            f"machine profile {m.name!r} runs kernels in interpret mode; "
+            f"this process drives a {device_kind!r}")
+    known = sorted({p.device_kind for p in MACHINES.values() if p.device_kind})
+    if device_kind not in known:
+        raise DeviceProfileError(
+            f"no machine profile describes device kind {device_kind!r}; "
+            f"known: {known}")
+    if m.device_kind != device_kind:
+        raise DeviceProfileError(
+            f"machine profile {m.name!r} describes {m.device_kind!r}, not "
+            f"the attached {device_kind!r}")
+    return m
+
+
 def default_interpret() -> bool:
-    """Kernel entry points' interpret default: the declared backend when the
-    active profile pins one, else whatever jax is actually running on."""
-    if get_machine().backend == "interpret":
-        return True
+    """Kernel entry points' interpret default: compiled kernels on a TPU
+    (whose kind the active profile must describe — `check_device`), the
+    Pallas interpreter on any other backend."""
     import jax  # local: keep machine importable without jax
 
-    return jax.default_backend() != "tpu"
+    if jax.default_backend() != "tpu":
+        return True
+    check_device(jax.devices()[0].device_kind)
+    return False
 
 
 _ALIASES = {

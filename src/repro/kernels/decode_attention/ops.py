@@ -36,9 +36,16 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
 from repro.kernels import register_twin  # noqa: E402
 
 
+def _heads_unfolded(cache, d: int):
+    """The kernel streams [..., KH*D]; the twins take [..., KH, D]."""
+    return cache.reshape(*cache.shape[:-1], cache.shape[-1] // d, d)
+
+
 def _flash_decode_twin(spec, pos, q, k_cache, v_cache):
     from repro.kernels.decode_attention.ref import decode_attention_ref
-    return decode_attention_ref(q, k_cache, v_cache, pos[0])
+    d = q.shape[-1]
+    return decode_attention_ref(q, _heads_unfolded(k_cache, d),
+                                _heads_unfolded(v_cache, d), pos[0])
 
 
 def _paged_decode_twin(spec, bt_flat, lengths, q, k_pool, v_pool):
@@ -46,9 +53,10 @@ def _paged_decode_twin(spec, bt_flat, lengths, q, k_pool, v_pool):
     # serving engine already trusts; the seed paged_decode_attention_ref is
     # a host loop (int(lengths[r])) and cannot police traced calls.
     from repro.models.common import paged_decode_attention as paged_twin
-    b = q.shape[0]
+    b, _, d = q.shape
     m = bt_flat.shape[0] // b
-    out = paged_twin(q[:, None], k_pool, v_pool, bt_flat.reshape(b, m),
+    out = paged_twin(q[:, None], _heads_unfolded(k_pool, d),
+                     _heads_unfolded(v_pool, d), bt_flat.reshape(b, m),
                      lengths)
     return out[:, 0].astype(q.dtype)
 

@@ -58,9 +58,12 @@ def gmm(tokens, weights, *, f_tile: int = 128, depth: int | None = None,
         return ctx.t[0]  # [c, dm] token block for this expert
 
     def body(ctx, t, slot, carry):
+        # explicit DEFAULT: Mosaic refuses an fp32 contract precision on
+        # bf16 operands, which a process-wide "highest" setting would ask
         ctx.o[0, :, pl.ds(t * f_tile, f_tile)] = jnp.einsum(
             "cd,df->cf", carry, ctx.w[slot],
             preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT,
         ).astype(ctx.o.dtype)
         return carry
 

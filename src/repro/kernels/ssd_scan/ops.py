@@ -25,13 +25,17 @@ from repro.kernels import register_twin  # noqa: E402
 
 def _ssd_twin(spec, x, dt, A, B, C):
     # same chunking as the kernel (spec.loads[0] is the x chunk stream), so
-    # the parity sentinel compares like-for-like chunked math
+    # the parity sentinel compares like-for-like chunked math; the kernel's
+    # operands carry x as [b, s, H*P] and dt / A lane-padded past H
     import jax.numpy as jnp
 
     from repro.models.ssm import ssd_chunked
     chunk = spec.loads[0].tile[0]
-    y, h_final = ssd_chunked(x, dt, A, B, C, chunk)
-    return [y.astype(x.dtype), h_final.astype(jnp.float32)]
+    nh, p, _ = spec.vars[0].shape
+    b, s, _ = dt.shape
+    y, h_final = ssd_chunked(x.reshape(b, s, nh, p), dt[..., :nh],
+                             A[0, :nh], B, C, chunk)
+    return [y.reshape(x.shape).astype(x.dtype), h_final.astype(jnp.float32)]
 
 
 register_twin("ssd_scan", _ssd_twin)

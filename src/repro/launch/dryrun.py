@@ -90,8 +90,6 @@ def _compile(cfg, shape, mesh, rules=None, accum=1):
         compiled = lowered.compile()
         t_compile = time.time() - t0
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x returns [dict]
-        ca = ca[0] if ca else {}
     txt = compiled.as_text()
     return {
         "model": model,

@@ -3,26 +3,16 @@
 A function (not a module-level constant) so importing this module never
 touches jax device state. The single-pod mesh is 16x16 = 256 chips; the
 multi-pod mesh is 2 pods x 256 = 512 chips with DP extended over the `pod`
-axis (only gradient all-reduce crosses the pod/DCN boundary).
-
-Compatibility floor: jax >= 0.4.35 (for `jax.make_mesh`). `AxisType` only
-exists from jax 0.5; on older versions (the pinned 0.4.37 environment) the
-`axis_types` argument is omitted — every axis then defaults to the same
-auto sharding behaviour, which is what we pass explicitly on newer jax.
+axis (only gradient all-reduce crosses the pod/DCN boundary). Every axis is
+`AxisType.Auto`: shardings propagate from the annotations GSPMD sees.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

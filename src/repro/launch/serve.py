@@ -27,6 +27,7 @@ import numpy as np
 from repro.configs import get_config, token_split
 from repro.core import autotune, guard
 from repro.core.machine import get_machine
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import latency_report
@@ -179,13 +180,15 @@ def serve_paged(cfg, *, batch: int, prompt_len: int, gen: int, seed: int = 0,
                              prefix_cache=prefix_cache,
                              prefill_chunk=prefill_chunk,
                              deadline_s=deadline_s, faults=faults)
+    rids = []
     for i, plen in enumerate(plens):
         body = rng.integers(0, cfg.vocab, plen)
         if i % 2 == 0:  # every other request opens with the system prompt
             body[: len(system)] = system[: plen]
-        eng.submit(body, max_new_tokens=gen)
+        rids.append(eng.submit(body, max_new_tokens=gen))
     stats = eng.run()
     stats["prompt_lens"] = plens
+    stats["generated_lens"] = [len(eng.request(r).generated) for r in rids]
     return stats
 
 
@@ -223,6 +226,7 @@ def main(argv=None):
                          "JSON (open in https://ui.perfetto.dev)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -14,6 +14,7 @@ import json
 
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim import AdamWConfig
 from repro.runtime.train_loop import train
@@ -41,6 +42,7 @@ def main(argv=None):
     ap.add_argument("--mesh", default="none", choices=["none", "mini"])
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding import ShardingCtx, shard_map
+from repro.sharding import ShardingCtx
 
 # --------------------------------------------------------------------- basics
 
@@ -277,7 +277,7 @@ def flash_decode_attention(ctx: ShardingCtx, q, k_cache, v_cache, new_k, new_v, 
 
     fn = functools.partial(_decode_core, s_local=s_local, model_axis="model",
                            update=update, update_mode=update_mode)
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(q_s, cache_s, cache_s, new_s, new_s, P()),

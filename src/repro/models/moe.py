@@ -22,7 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.models.params import ParamSpec
-from repro.sharding import ShardingCtx, shard_map
+from repro.sharding import ShardingCtx
 
 
 def moe_param_specs(cfg: ArchConfig) -> Dict[str, ParamSpec]:
@@ -61,6 +61,28 @@ def _gmm_eligible(xs, wg, wu, wd) -> bool:
             and wd.shape[-1] % GMM_F_TILE == 0)
 
 
+@jax.custom_vjp
+def _gmm(xs, w):
+    """[E,T,d] x [E,d,f] -> [E,T,f] through the streamed-weight kernel.
+    The kernel has no transpose rule, so the backward pass is the plain
+    grouped einsum (what lets the EP training step run the kernel)."""
+    from repro.kernels.moe_gmm.ops import moe_gmm
+    return moe_gmm(xs, w, f_tile=GMM_F_TILE)
+
+
+def _gmm_fwd(xs, w):
+    return _gmm(xs, w), (xs, w)
+
+
+def _gmm_bwd(res, g):
+    xs, w = res
+    return (jnp.einsum("etf,edf->etd", g, w),
+            jnp.einsum("etd,etf->edf", xs, g))
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
 def _expert_ffn(xs, wg, wu, wd, *, use_gmm: bool | None = None):
     """Per-expert SwiGLU. On TPU (when shapes allow) each grouped matmul is
     the `kernels/moe_gmm` coroutine pipeline — expert weights are the far-
@@ -71,10 +93,9 @@ def _expert_ffn(xs, wg, wu, wd, *, use_gmm: bool | None = None):
         from repro.core.machine import default_interpret
         use_gmm = not default_interpret()
     if use_gmm and _gmm_eligible(xs, wg, wu, wd):
-        from repro.kernels.moe_gmm.ops import moe_gmm
-        h = jax.nn.silu(moe_gmm(xs, wg.astype(xs.dtype), f_tile=GMM_F_TILE))
-        h = h * moe_gmm(xs, wu.astype(xs.dtype), f_tile=GMM_F_TILE)
-        return moe_gmm(h, wd.astype(xs.dtype), f_tile=GMM_F_TILE)
+        h = jax.nn.silu(_gmm(xs, wg.astype(xs.dtype)))
+        h = h * _gmm(xs, wu.astype(xs.dtype))
+        return _gmm(h, wd.astype(xs.dtype))
     h = jax.nn.silu(jnp.einsum("...td,...df->...tf", xs, wg.astype(xs.dtype)))
     h = h * jnp.einsum("...td,...df->...tf", xs, wu.astype(xs.dtype))
     return jnp.einsum("...tf,...fd->...td", h, wd.astype(xs.dtype))
@@ -178,7 +199,7 @@ def moe_ep_a2a(p, x, cfg: ArchConfig, ctx: ShardingCtx):
         return y.reshape(bl, sl, d), aux
 
     in_x = P(bspec, "model", None)  # sequence-parallel tokens
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(_expert_specs(), in_x),
         out_specs=(in_x, P()),
@@ -213,7 +234,7 @@ def moe_ep_replicated(p, x, cfg: ArchConfig, ctx: ShardingCtx):
         return y.reshape(bl, sl, d), aux
 
     in_x = P(bspec, None, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(_expert_specs(), in_x),
         out_specs=(in_x, P()),

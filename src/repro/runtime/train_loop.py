@@ -6,8 +6,6 @@ import time
 from typing import Any, Callable, Dict, Optional
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from repro.checkpointing.checkpoint import AsyncCheckpointer, latest_step, restore
 from repro.configs.base import ArchConfig
@@ -16,7 +14,11 @@ from repro.models.registry import Model
 from repro.optim import AdamWConfig, init_state
 from repro.optim.compression import ef_compress, init_error_state
 from repro.runtime.fault_tolerance import StragglerMonitor
-from repro.runtime.steps import make_train_step, state_shardings
+from repro.runtime.steps import (
+    batch_shardings,
+    make_train_step,
+    state_shardings,
+)
 
 
 @dataclasses.dataclass
@@ -26,6 +28,7 @@ class TrainReport:
     resumed_from: Optional[int]
     straggler_steps: int
     wall_s: float
+    state: Any = None   # the final train state, in its (sharded) layout
 
 
 def train(model: Model, *, steps: int, data_cfg: DataConfig,
@@ -60,18 +63,29 @@ def train(model: Model, *, steps: int, data_cfg: DataConfig,
     else:
         step_fn = make_train_step(model, opt, accum=accum)
 
-    jit_step = jax.jit(step_fn, donate_argnums=(0,))
+    # under a mesh, parameters and optimizer state are created, stepped and
+    # kept in their sharded layout (`model.ctx` rules); without one, every
+    # sharding is None and jit places everything on the default device
+    shardings = state_shardings(model)
+    if shardings is not None and compress_grads:
+        shardings["grad_error"] = shardings["params"]
+    jit_step = jax.jit(step_fn, in_shardings=(shardings, None),
+                       out_shardings=(shardings, None), donate_argnums=(0,))
+
+    def init(rng):
+        params = model.init(rng)
+        state = init_state(params)
+        if compress_grads:
+            state["grad_error"] = init_error_state(params)
+        return state
 
     # ---- init or resume
     resumed_from = None
-    params = model.init(jax.random.PRNGKey(seed))
-    state = init_state(params)
-    if compress_grads:
-        state["grad_error"] = init_error_state(params)
+    state = jax.jit(init, out_shardings=shardings)(jax.random.PRNGKey(seed))
     if ckpt_dir is not None and latest_step(ckpt_dir) is not None:
         resumed_from = latest_step(ckpt_dir)
         state = restore(ckpt_dir, state)
-        state = jax.tree.map(jnp.asarray, state)
+        state = jax.device_put(state, shardings)
 
     ckpt = AsyncCheckpointer(ckpt_dir) if ckpt_dir is not None else None
     monitor = StragglerMonitor()
@@ -81,7 +95,8 @@ def train(model: Model, *, steps: int, data_cfg: DataConfig,
     for step in range(start, steps):
         if fail_at_step is not None and step == fail_at_step:
             raise RuntimeError(f"injected failure at step {step}")
-        batch = {k: jnp.asarray(v) for k, v in task.batch_for_step(step).items()}
+        raw = task.batch_for_step(step)
+        batch = jax.device_put(raw, batch_shardings(model.ctx, raw))
         with monitor.timed():
             state, metrics = jit_step(state, batch)
         if step % log_every == 0 or step == steps - 1:
@@ -93,4 +108,4 @@ def train(model: Model, *, steps: int, data_cfg: DataConfig,
         ckpt.wait()
     return TrainReport(steps=steps, losses=losses, resumed_from=resumed_from,
                        straggler_steps=len(monitor.flagged),
-                       wall_s=time.time() - t_start)
+                       wall_s=time.time() - t_start, state=state)

@@ -20,8 +20,9 @@ pressure the engine reclaims least-recently-hit cache-only pages before the
 scheduler resorts to preemption.
 
 The decode math runs through the jnp twin (`models.common`), which jits on
-any backend; `kernels/decode_attention.paged_flash_decode` is the TPU
-pipeline the round rides there (validated for parity in
+any backend, on every platform: the compiled decode step holds no Pallas
+kernel yet. `kernels/decode_attention.paged_flash_decode` is the TPU
+pipeline meant to replace it (validated for parity in
 tests/test_serve_paged.py, benchmarked in benchmarks/kernel_bench.py).
 
 Because freed pages are reused immediately, the aggregate KV served over a
@@ -38,7 +39,9 @@ deadlines (`deadline_s`) cancel expired requests at round boundaries;
 `step_round` is exception-safe: a step that raises marks its requests
 faulted and retries them, quarantining any request whose consecutive-fault
 count exceeds `max_request_faults` (pages freed, trace span closed,
-`on_finish` invoked, state FAILED); unresolvable pool pressure
+`on_finish` invoked, state FAILED). Under strict mode (`guard.set_strict`)
+a step error the fault injector did not raise propagates out of
+`step_round` and `run()` instead; unresolvable pool pressure
 (`PoolExhausted` escaping reclaim + preemption) requeues the request at
 the head of the waiting line and quarantines it after `max_stalls`
 attempts. `run()` never raises on a wedged workload: after `max_rounds`
@@ -75,16 +78,33 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import latency_report  # noqa: F401  (re-export; the
 #   one shared implementation lives in obs.metrics — ISSUE-8 satellite)
-from repro.serve.faults import NULL_INJECTOR, FaultInjector
+from repro.serve.faults import NULL_INJECTOR, FaultInjector, InjectedFault
 from repro.serve.kv_pager import KVPager, PoolExhausted
 from repro.serve.prefill import ChunkedPrefiller
-from repro.serve.prefix_cache import MISS, PrefixCache, PrefixMatch
+from repro.serve.prefix_cache import PrefixCache, PrefixMatch
 from repro.serve.scheduler import (
     ContinuousBatchingScheduler,
     Request,
     RequestState,
 )
 from repro.sharding import NULL_CTX, ShardingCtx
+
+
+def decode_round_fn(model):
+    """The body of one decode round, which the engine jits per table width:
+    (params, k_pools, v_pools, tokens [B,1], tables [B,M], lengths [B]) ->
+    (next tokens [B], finite [B], k_pools, v_pools). The numerics check runs
+    on the device: one finite flag per row rides back with the tokens. The
+    function's name names the compiled program in logs and traces."""
+
+    def paged_decode_round(params, k_pools, v_pools, tokens, tables, lengths):
+        logits, k_pools, v_pools = model.decode_step_paged(
+            params, k_pools, v_pools, tables, lengths, {"tokens": tokens})
+        last = logits[:, -1]
+        return (jnp.argmax(last, axis=-1), jnp.isfinite(last).all(axis=-1),
+                k_pools, v_pools)
+
+    return paged_decode_round
 
 
 class PagedServingEngine:
@@ -157,8 +177,7 @@ class PagedServingEngine:
         self._requests: Dict[int, Request] = {}   # live (non-terminal) only
         self._done: Dict[int, Request] = {}       # terminal, any reason
         self._next_rid = 0
-        self._decode_fn = None                  # jit cache keyed by table width
-        self._decode_fn_width = 0
+        self._decode_fns: Dict[int, Any] = {}   # jitted round per table width
         self._decode_fresh = False
         self._tw_hw = 1    # padded-table high-water mark (re-jit guard)
         self.rounds = 0
@@ -323,6 +342,17 @@ class PagedServingEngine:
         self._retire(req, RequestState.FAILED, reason,
                      error=f"{type(err).__name__}: {err}")
 
+    @staticmethod
+    def _raise_if_strict(err: BaseException) -> None:
+        """Strict mode (`guard.set_strict`) surfaces every step error the
+        fault injector did not raise on purpose — a compile failure or an
+        HBM OOM must fail the run, not quarantine its requests. Injected
+        chaos keeps the recovery path in every mode."""
+        injected = (isinstance(err, InjectedFault)
+                    or getattr(err, "injected", False))
+        if guard.strict_mode() and not injected:
+            raise err
+
     def _note_fault(self, req: Request, err: BaseException) -> None:
         """A step serving `req` raised. Transient faults retry (the request
         stays where it is); `max_request_faults` consecutive failures
@@ -363,8 +393,6 @@ class PagedServingEngine:
         return self.prefiller._fns
 
     def _match(self, tokens) -> PrefixMatch:
-        if self.prefix_cache is None:
-            return MISS
         with self.tracer.span("prefix_lookup", n_tokens=len(tokens)):
             return self.prefix_cache.match(tokens)
 
@@ -469,21 +497,16 @@ class PagedServingEngine:
     # -------------------------------------------------------------- decode
 
     def _decode(self, table_width: int):
-        if self._decode_fn is None or table_width != self._decode_fn_width:
-            model = self.model
-
-            def step(params, k_pools, v_pools, tokens, tables, lengths):
-                logits, k_pools, v_pools = model.decode_step_paged(
-                    params, k_pools, v_pools, tables, lengths,
-                    {"tokens": tokens})
-                return jnp.argmax(logits[:, -1], axis=-1), k_pools, v_pools
-
-            self._decode_fn = jax.jit(step, donate_argnums=(1, 2))
-            self._decode_fn_width = table_width
+        """The jitted decode round for `table_width`, kept per width so a
+        width the high-water mark returns to never compiles twice."""
+        fn = self._decode_fns.get(table_width)
+        if fn is None:
+            fn = jax.jit(decode_round_fn(self.model), donate_argnums=(1, 2))
+            self._decode_fns[table_width] = fn
             # the next round's wall clock includes jit compile: keep it out
             # of the transfer-telemetry feedback store
             self._decode_fresh = True
-        return self._decode_fn
+        return fn
 
     def _table_width(self) -> int:
         """Block-table width: every request's table padded to the worst
@@ -550,18 +573,21 @@ class PagedServingEngine:
             decode = self._decode(tw)
             with self.tracer.span("decode_round", width=len(writable),
                                   table_width=tw):
-                nxt, self.k_pools, self.v_pools = decode(
+                nxt, finite, self.k_pools, self.v_pools = decode(
                     self.params, self.k_pools, self.v_pools,
                     jnp.asarray(tokens), jnp.asarray(tables),
                     jnp.asarray(lengths))
-                nxt = np.asarray(jax.block_until_ready(nxt))
+                nxt, finite = jax.device_get((nxt, finite))
+            if not finite[:len(writable)].all():
+                raise guard.numerics_fault("paged_decode_round")
         except Exception as e:
-            # the batched step raised: no KV row was written, so roll the
-            # reservations back and let every member retry next round —
-            # attribution inside a batch is ambiguous, so blame is shared
-            # and `max_request_faults` consecutive failures quarantine
+            # the batched step raised: roll the reservations back and let
+            # every member retry next round (a retry rewrites the same KV
+            # rows) — attribution inside a batch is ambiguous, so blame is
+            # shared and `max_request_faults` consecutive failures quarantine
             for req in writable:
                 self.scheduler.unreserve(req)
+            self._raise_if_strict(e)
             for req in writable:
                 self._note_fault(req, e)
             return 0
@@ -605,7 +631,9 @@ class PagedServingEngine:
         prefix lookup), decode one token for every running request, then
         spend the leftover budget on prefill chunks. Exception-safe: a
         failing step faults (and eventually quarantines) the requests it
-        served, never the engine. Returns tokens emitted this round."""
+        served, never the engine; in strict mode a step error the fault
+        injector did not raise propagates. Returns tokens emitted this
+        round."""
         with self.tracer.span("round", n=self.rounds):
             self._expire_deadlines()
             spike = self.faults.latency_spike("latency")
@@ -613,7 +641,8 @@ class PagedServingEngine:
                 self.tracer.instant("latency_spike",
                                     sleep_ms=round(spike * 1e3, 3))
                 time.sleep(spike)
-            for req in self.scheduler.admit(match=self._match):
+            match = self._match if self.prefix_cache is not None else None
+            for req in self.scheduler.admit(match=match):
                 self.tracer.instant("admit", rid=req.rid,
                                     matched=req.matched_len,
                                     context=len(req.context))
@@ -635,6 +664,7 @@ class PagedServingEngine:
                 except PoolExhausted as e:
                     self._stall(req, e)
                 except Exception as e:
+                    self._raise_if_strict(e)
                     self._note_fault(req, e)
                 emitted += len(req.generated) - before
             self.rounds += 1

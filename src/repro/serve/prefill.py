@@ -54,12 +54,13 @@ class ChunkedPrefiller:
         if fn is None:
             model = self.model
 
-            def step(params, k_pools, v_pools, tokens, table, start, n_valid):
+            def paged_prefill_chunk(params, k_pools, v_pools, tokens, table,
+                                    start, n_valid):
                 return model.prefill_chunk_paged(
                     params, k_pools, v_pools, table, start,
                     {"tokens": tokens}, n_valid)
 
-            fn = jax.jit(step, donate_argnums=(1, 2))
+            fn = jax.jit(paged_prefill_chunk, donate_argnums=(1, 2))
             self._fns[key] = fn
         return fn
 

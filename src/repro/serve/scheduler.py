@@ -180,12 +180,17 @@ class ContinuousBatchingScheduler:
         only the suffix costs fresh blocks, and only the suffix is
         prefilled. FIFO: admission stops at the first request that does not
         fit even after reclaiming cache-only pages, so a large head request
-        cannot be starved by small ones slipping past it."""
+        cannot be starved by small ones slipping past it. With a `match`,
+        it also stops at a request whose whole-block prefix another request
+        is still prefilling (`_prefix_pending`): that prefix is written
+        once, and the waiting request then admits as a cache hit."""
         admitted: List[Request] = []
         while self.waiting and self.in_flight() < self.max_in_flight:
             req = self.waiting[0]
             ctxt = req.context
             m = match(ctxt) if match is not None else MISS
+            if match is not None and self._prefix_pending(ctxt, m):
+                break
             need = self._blocks_needed(ctxt, m)
             if need > self.pager.free_blocks and self.reclaim is not None:
                 self.reclaim(need - self.pager.free_blocks,
@@ -215,6 +220,21 @@ class ContinuousBatchingScheduler:
             self.prefilling.append(req)
             admitted.append(req)
         return admitted
+
+    def _prefix_pending(self, ctxt: Sequence[int], m: PrefixMatch) -> bool:
+        """A request still in prefill shares more whole blocks of `ctxt`
+        than the cache holds yet: admitting now would prefill and page the
+        shared prefix twice, while a few rounds later it is a cache hit."""
+        blk = self.pager.block_size
+        for other in self.prefilling:
+            n = 0
+            for a, b in zip(ctxt, other.context):
+                if a != b:
+                    break
+                n += 1
+            if n // blk * blk > m.n_tokens:
+                return True
+        return False
 
     # --------------------------------------------------------- preemption
 

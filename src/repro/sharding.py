@@ -19,21 +19,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 AxisVal = Union[None, str, Tuple[str, ...]]
 
 
-def shard_map(fn, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable `shard_map` (jax compatibility floor: 0.4.35).
-
-    jax >= 0.6 exposes `jax.shard_map` with `check_vma`; jax 0.4.x has
-    `jax.experimental.shard_map.shard_map` with the same knob named
-    `check_rep`. All manual-collective paths (decode attention, MoE EP,
-    compressed psum) go through this wrapper.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
 # logical axis -> mesh axis (or tuple of mesh axes)
 DEFAULT_RULES: Dict[str, AxisVal] = {
     "batch": ("pod", "data"),   # data parallel (pod axis extends DP across pods)

@@ -70,7 +70,8 @@ def test_scratch_shapes_follow_classification():
     shapes = spec.scratch_shapes(depth=5)
     # 4 load slots + 1 load semaphore + 1 materialized var (the h state)
     assert len(shapes) == 6
-    assert shapes[0].shape == (5, 16, 2, 8)       # x slots: private x depth
+    # x slots: private x depth, heads folded into the lane dim (2 * 8)
+    assert shapes[0].shape == (5, 16, 16)
     assert shapes[-1].shape == (2, 8, 16)         # h state: sequential x 1
 
 

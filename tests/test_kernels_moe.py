@@ -71,3 +71,19 @@ def test_moe_dense_combines_topk_only(rng):
     y, aux = moe_dense(p, x, cfg)
     assert y.shape == x.shape
     assert np.isfinite(np.asarray(y)).all()
+
+
+def test_gmm_expert_ffn_trains_through_the_kernel(rng):
+    """The kernel path has a backward pass (the grouped einsum): gradients
+    through `_expert_ffn(use_gmm=True)` match the einsum twin's."""
+    e, t, dm, dff = 2, 8, 128, 128
+    args = [jnp.asarray(rng.randn(*s) * 0.1, jnp.float32)
+            for s in ((e, t, dm), (e, dm, dff), (e, dm, dff), (e, dff, dm))]
+
+    def grads(use_gmm):
+        loss = lambda *a: (_expert_ffn(*a, use_gmm=use_gmm) ** 2).sum()
+        return jax.grad(loss, argnums=(0, 1, 2, 3))(*args)
+
+    for got, want in zip(grads(True), grads(False)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-3, atol=2e-4)

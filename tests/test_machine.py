@@ -95,6 +95,57 @@ def test_default_interpret_follows_backend():
     assert machine_mod.default_interpret() is True
 
 
+# ------------------------------------------- the profile must fit the chip
+
+
+def test_check_device_accepts_the_chip_the_profile_describes():
+    assert machine_mod.check_device("TPU v5 lite").name == "v5e"
+    set_machine("v5e-far-800ns")   # the latency dial keeps the chip
+    assert machine_mod.check_device("TPU v5 lite").name == "v5e-far-800ns"
+
+
+def test_unknown_tpu_device_kind_raises():
+    with pytest.raises(machine_mod.DeviceProfileError, match="TPU v9"):
+        machine_mod.check_device("TPU v9")
+
+
+def test_interpret_profile_on_a_tpu_raises():
+    for name in ("cpu-interpret", "nh-g"):
+        set_machine(name)
+        with pytest.raises(machine_mod.DeviceProfileError,
+                           match="interpret"):
+            machine_mod.check_device("TPU v5 lite")
+
+
+def test_profile_of_another_chip_raises():
+    set_machine(machine_profile("v5e").replace(name="other",
+                                               device_kind="TPU v6 lite"))
+    with pytest.raises(machine_mod.DeviceProfileError, match="describes"):
+        machine_mod.check_device("TPU v5 lite")
+
+
+def test_default_interpret_on_a_tpu_checks_the_device(monkeypatch):
+    """On a TPU backend the kernels compile — after the active profile is
+    checked against the attached chip; a mismatch raises, it never falls
+    back to the interpreter."""
+    import jax
+
+    class Chip:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [Chip()])
+    assert machine_mod.default_interpret() is False
+    Chip.device_kind = "TPU v9"
+    with pytest.raises(machine_mod.DeviceProfileError):
+        machine_mod.default_interpret()
+    Chip.device_kind = "TPU v5 lite"
+    set_machine("cpu-interpret")
+    with pytest.raises(machine_mod.DeviceProfileError):
+        machine_mod.default_interpret()
+
+
 # --------------------------------------------------- legacy constant aliases
 
 

@@ -23,13 +23,15 @@ def _f32_cfg():
 def _pressured_prefix_run():
     """A paged run that exercises every instant event: a shared system
     prefix diverging mid-block (COW fork), a pool tight enough to reclaim
-    cache-only pages (evict) and preempt an in-flight request."""
+    cache-only pages (evict) and preempt an in-flight request. The later
+    requests wait for the shared block's prefill before they admit, which
+    staggers them: 12 blocks keeps the pool tight enough to preempt."""
     cfg = _f32_cfg()
     rng = np.random.default_rng(3)
     shared = list(rng.integers(0, cfg.vocab, 6))  # 1.5 blocks at blk=4
     prompts = [shared + list(rng.integers(0, cfg.vocab, 18 + 3 * i))
                for i in range(3)]
-    eng = PagedServingEngine(cfg, block_size=4, num_blocks=14,
+    eng = PagedServingEngine(cfg, block_size=4, num_blocks=12,
                              prefix_cache=True)
     for p in prompts:
         eng.submit(p, max_new_tokens=4)

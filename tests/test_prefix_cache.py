@@ -409,3 +409,32 @@ def test_engine_preempted_request_rehits_its_own_pages():
     assert stats["completed"] == len(plens)
     for rid in rids:
         assert len(eng.request(rid).generated) == gen
+
+
+def test_admission_waits_for_a_prefix_still_in_prefill():
+    """Two requests sharing two whole blocks, with room to run both at
+    once: the second waits until the first has written the shared pages,
+    then admits as a cache hit instead of prefilling them a second time.
+    Without the cache both admit at once. Tokens are identical."""
+    cfg = _f32_cfg()
+    rng = np.random.default_rng(9)
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    shared = list(rng.integers(0, cfg.vocab, 8))        # 2 blocks at blk=4
+    prompts = [shared + [1, 2, 3], shared + [4, 5]]
+
+    def run(prefix_cache):
+        eng = PagedServingEngine(cfg, block_size=4, num_blocks=32,
+                                 params=params, max_in_flight=4,
+                                 prefill_chunk=4, prefix_cache=prefix_cache)
+        rids = [eng.submit(p, max_new_tokens=3) for p in prompts]
+        eng.step_round()
+        admitted = eng.scheduler.in_flight()
+        stats = eng.run()
+        return [eng.request(r).generated for r in rids], admitted, stats
+
+    warm_toks, warm_admitted, warm = run(True)
+    cold_toks, cold_admitted, cold = run(False)
+    assert warm_toks == cold_toks
+    assert (warm_admitted, cold_admitted) == (1, 2)
+    assert warm["prefix_hits"] == 1 and warm["prefix_tokens"] == 8
+    assert warm["blocks_allocated"] < cold["blocks_allocated"]

@@ -497,3 +497,80 @@ def test_chaos_serve_script_smoke():
     assert summary["requests"] == 4
     assert (summary["completed"] + summary["cancelled"]
             + summary["failed"]) == 4
+
+
+# ------------------------------------------------------------ strict mode
+
+
+def _break_site(eng, site, monkeypatch):
+    """Make one step site raise what a chip raises on a compile failure or
+    an HBM OOM — an error the fault injector did not cause."""
+    def boom(*args, **kw):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of memory in HBM")
+    if site == "decode":
+        monkeypatch.setattr(eng, "_decode", lambda table_width: boom)
+    else:
+        monkeypatch.setattr(eng.prefiller, "run_chunk", boom)
+
+
+@pytest.mark.parametrize("site", ["decode", "prefill"])
+def test_strict_engine_reraises_a_real_step_error(tiny, monkeypatch, site):
+    from repro.core import guard
+    eng = _eng(tiny)
+    _break_site(eng, site, monkeypatch)
+    eng.submit([3, 1, 4, 1, 5], max_new_tokens=3)
+    guard.set_strict(True)
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        eng.run()
+    assert eng.stats()["step_faults"] == 0
+
+
+@pytest.mark.parametrize("site", ["decode", "prefill"])
+def test_lenient_engine_quarantines_a_real_step_error(tiny, monkeypatch,
+                                                      site):
+    eng = _eng(tiny, max_request_faults=1)
+    _break_site(eng, site, monkeypatch)
+    rid = eng.submit([3, 1, 4, 1, 5], max_new_tokens=3)
+    stats = eng.run()
+    assert eng.request(rid).state is RequestState.FAILED
+    assert "RESOURCE_EXHAUSTED" in eng.request(rid).error
+    assert stats["step_faults"] == 2
+
+
+@pytest.mark.parametrize("site", ["decode", "prefill", "kernel_compile",
+                                  "kernel_oom"])
+def test_strict_engine_still_absorbs_injected_faults(tiny, site):
+    """Chaos keeps its recovery path under strict mode: an injected fault
+    costs a retry, not the run."""
+    from repro.core import guard
+    guard.set_strict(True)
+    inj = FaultInjector(0, rates={site: 1.0}, max_faults=1)
+    eng = _eng(tiny, faults=inj)
+    rid = eng.submit([3, 1, 4, 1, 5], max_new_tokens=4)
+    stats = eng.run()
+    assert eng.request(rid).state is RequestState.FINISHED
+    assert len(eng.request(rid).generated) == 4
+    assert stats["step_faults"] == 1 and inj.injected == 1
+
+
+def test_decode_round_checks_logits_on_device(tiny, monkeypatch):
+    """The decode step returns a finite flag per row; a non-finite row is a
+    numerics fault of the round (counted, and raised under strict mode)."""
+    import jax.numpy as jnp
+    eng = _eng(tiny, max_request_faults=0)
+    real = eng._decode
+
+    def poisoned(table_width):
+        step = real(table_width)
+
+        def run(*args):
+            nxt, finite, k, v = step(*args)
+            return nxt, jnp.zeros_like(finite), k, v
+        return run
+
+    monkeypatch.setattr(eng, "_decode", poisoned)
+    rid = eng.submit([3, 1, 4, 1, 5], max_new_tokens=3)
+    stats = eng.run()
+    assert eng.request(rid).state is RequestState.FAILED
+    assert "KernelNumericsError" in eng.request(rid).error
+    assert stats["substrate"]["numerics_faults"] == 1

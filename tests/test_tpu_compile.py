@@ -1,0 +1,142 @@
+"""Real-width compiles for a described TPU v5e (no chip needed).
+
+Each kernel of the main path is lowered with ``interpret=False`` and
+compiled by the TPU compiler against a described ``v5e:2x2`` topology, at
+the widths it serves: the decode kernels at head_dim 64 (granite-3-2b) and
+128 (yi-6b), ``ssd_scan`` at mamba2-130m, ``moe_gmm`` at
+granite-moe-1b-a400m and ``stream_triad`` at its solved depth. The
+granite-3-2b paged decode step is compiled whole. Nothing runs, so these
+tests say nothing about results or speed; they catch what Mosaic or XLA
+would refuse on the chip (misaligned tiles, missing lowerings, VMEM or HBM
+overcommit).
+
+The topology is described inside a fixture: only one process may load the
+TPU library at a time, so it must never happen while modules are imported.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+PALLAS_CALL = "tpu_custom_call"
+HBM_BYTES = 16 * 10**9          # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means: cannot describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these compiles
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+@pytest.fixture(scope="module")
+def shape(one_chip):
+    def make(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    return make
+
+
+@pytest.mark.parametrize("kind", ["paged", "flash"])
+@pytest.mark.parametrize("kh,d", [(8, 64), (4, 128)],
+                         ids=["granite-3-2b", "yi-6b"])
+def test_decode_kernels_compile(shape, kind, kh, d):
+    from repro.kernels.decode_attention.decode_attention import (
+        flash_decode,
+        paged_flash_decode,
+    )
+    bf16 = jnp.bfloat16
+    q = shape((8, 32, d), bf16)
+    if kind == "paged":
+        pool = shape((1024, 16, kh, d), bf16)
+        compiled = _compile(
+            lambda q, k, v, bt, ln: paged_flash_decode(q, k, v, bt, ln,
+                                                       interpret=False),
+            q, pool, pool, shape((8, 64), jnp.int32), shape((8,), jnp.int32))
+    else:
+        cache = shape((8, 2048, kh, d), bf16)
+        compiled = _compile(
+            lambda q, k, v: flash_decode(q, k, v, 1000, interpret=False),
+            q, cache, cache)
+    assert PALLAS_CALL in compiled.as_text()
+
+
+def test_ssd_scan_compiles_at_mamba2_130m(shape):
+    from repro.kernels.ssd_scan.ssd_scan import ssd_scan
+    f32 = jnp.float32
+    compiled = _compile(
+        lambda x, dt, a, b, c: ssd_scan(x, dt, a, b, c, chunk=256,
+                                        interpret=False),
+        shape((2, 1024, 24, 64), f32), shape((2, 1024, 24), f32),
+        shape((24,), f32), shape((2, 1024, 128), f32),
+        shape((2, 1024, 128), f32))
+    assert PALLAS_CALL in compiled.as_text()
+
+
+def test_stream_triad_compiles_at_solved_depth(shape):
+    """rows=512 over f32 [65536, 1024] solves a depth whose scratch exceeds
+    Mosaic's 16 MiB default: it compiles only under the solver's budget."""
+    from repro.core import autotune
+    from repro.kernels.stream_copy.stream_copy import triad, triad_spec
+    spec = triad_spec(512, 1024, jnp.float32)
+    depth = autotune.choose_depth(spec.profile(), vars=spec.all_vars())
+    assert spec.context_bytes(depth) > 16 * 2**20
+    arr = shape((65536, 1024), jnp.float32)
+    compiled = _compile(
+        lambda b, c: triad(b, c, 3.0, rows=512, depth=depth, interpret=False),
+        arr, arr)
+    assert PALLAS_CALL in compiled.as_text()
+
+
+def test_moe_gmm_compiles_at_granite_moe(shape):
+    from repro.kernels.moe_gmm.moe_gmm import gmm
+    compiled = _compile(lambda t, w: gmm(t, w, interpret=False),
+                        shape((32, 128, 1024), jnp.bfloat16),
+                        shape((32, 1024, 512), jnp.bfloat16))
+    assert PALLAS_CALL in compiled.as_text()
+
+
+def test_granite_3_2b_paged_decode_step_fits_one_chip(one_chip, shape):
+    """The served decode step at published widths (bf16 weights, 8 slots,
+    table width 64, 1024 blocks of 16) compiles and fits one chip's HBM."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.runtime.layouts import serving_config_overrides
+    from repro.serve.engine import decode_round_fn
+
+    cfg = get_config("granite-3-2b").replace(**serving_config_overrides())
+    model = build_model(cfg)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        model.abstract_params())
+    pool = shape((cfg.n_layers, 1025, 16, cfg.kv_heads, cfg.resolved_head_dim),
+                 jnp.dtype(cfg.dtype))
+    compiled = jax.jit(decode_round_fn(model), donate_argnums=(1, 2)).lower(
+        params, pool, pool, shape((8, 1), jnp.int32),
+        shape((8, 64), jnp.int32), shape((8,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 0 < total < HBM_BYTES
