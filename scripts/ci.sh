@@ -41,7 +41,7 @@ doc = json.load(open(path))  # raises on missing/invalid JSON
 events = doc["traceEvents"]
 assert events, f"{path}: traceEvents is empty"
 names = {ev["name"] for ev in events}
-for required in ("round", "decode_round", "pipeline:paged_decode"):
+for required in ("round", "decode_round", "decode_dispatch", "decode_wait"):
     assert required in names, f"{path}: missing '{required}' spans ({sorted(names)})"
 print(f"ok: {len(events)} trace events ({len(names)} span kinds) in {path}")
 EOF

@@ -353,6 +353,31 @@ def block_decode(p, x, layer_cache, cfg: ArchConfig, ctx: ShardingCtx, *, pos):
 # path's single scalar `pos` becomes a [B] vector). Supported families:
 # attention (+ MoE FFN); SSM/hybrid recurrent state, cross-attention, and
 # ring (sliding-window) caches keep the dense path.
+#
+# The paged paths name their parts with `jax.named_scope`, so each HLO
+# instruction's `op_name` says which part of the model emitted it:
+# `SCOPES` at the top, `SUBSCOPES` inside `attention` and `ffn`. The
+# metadata changes no generated code; `model_scope` reads it back.
+
+SCOPES = ("embed", "attention", "ffn", "unembed")
+SUBSCOPES = ("qkv", "kv_append", "attend", "out",    # attention
+             "router", "experts")                    # ffn (MoE)
+
+
+def model_scope(op_name: str) -> str:
+    """The model scope an HLO `op_name` path names: its first component in
+    `SCOPES` and the `SUBSCOPES` that follow it (``attention/kv_append``),
+    or ``other`` where it names none (layer-stack slicing, layout copies)."""
+    parts = op_name.split("/")
+    for i, part in enumerate(parts):
+        if part in SCOPES:
+            path = [part]
+            for sub in parts[i + 1:]:
+                if sub not in SUBSCOPES:
+                    break
+                path.append(sub)
+            return "/".join(path)
+    return "other"
 
 
 def paged_attn_decode(p, x, k_pool, v_pool, cfg: ArchConfig, *, block_tables,
@@ -363,14 +388,22 @@ def paged_attn_decode(p, x, k_pool, v_pool, cfg: ArchConfig, *, block_tables,
     already stored per request (the new KV row is written at that position).
     Returns (attn_out, k_pool, v_pool).
     """
-    h = rms_norm(x, p["ln"], cfg.norm_eps)
-    q, k, v = _project_qkv(p, h, cfg)
-    posv = lengths[:, None]  # [B, 1] per-request decode position
-    q = apply_rope(q, posv, cfg.rope_theta)
-    k = apply_rope(k, posv, cfg.rope_theta)
-    k_pool, v_pool = paged_cache_append(k_pool, v_pool, block_tables, lengths, k, v)
-    o = paged_decode_attention(q, k_pool, v_pool, block_tables, lengths + 1)
-    return _attn_out(p, o, cfg), k_pool, v_pool
+    with jax.named_scope("attention"):
+        with jax.named_scope("qkv"):
+            h = rms_norm(x, p["ln"], cfg.norm_eps)
+            q, k, v = _project_qkv(p, h, cfg)
+            posv = lengths[:, None]  # [B, 1] per-request decode position
+            q = apply_rope(q, posv, cfg.rope_theta)
+            k = apply_rope(k, posv, cfg.rope_theta)
+        with jax.named_scope("kv_append"):
+            k_pool, v_pool = paged_cache_append(k_pool, v_pool, block_tables,
+                                                lengths, k, v)
+        with jax.named_scope("attend"):
+            o = paged_decode_attention(q, k_pool, v_pool, block_tables,
+                                       lengths + 1)
+        with jax.named_scope("out"):
+            o = _attn_out(p, o, cfg)
+    return o, k_pool, v_pool
 
 
 def paged_block_decode(p, x, k_pool, v_pool, cfg: ArchConfig, ctx: ShardingCtx,
@@ -405,18 +438,25 @@ def paged_attn_prefill_chunk(p, x, k_pool, v_pool, cfg: ArchConfig, *,
     causally over all pool positions <= its own. Returns
     (attn_out, k_pool, v_pool).
     """
-    h = rms_norm(x, p["ln"], cfg.norm_eps)
-    q, k, v = _project_qkv(p, h, cfg)
-    c = x.shape[1]
-    pos = (start + jnp.arange(c, dtype=jnp.int32))[None]  # [1, C]
-    q = apply_rope(q, pos, cfg.rope_theta)
-    k = apply_rope(k, pos, cfg.rope_theta)
-    k_pool, v_pool = paged_cache_append_chunk(k_pool, v_pool, block_tables,
-                                              start, k, v, n_valid)
-    o = paged_prefill_attention(q, k_pool, v_pool, block_tables, pos)
-    return _attn_out(p, o, cfg), k_pool, v_pool
+    with jax.named_scope("attention"):
+        with jax.named_scope("qkv"):
+            h = rms_norm(x, p["ln"], cfg.norm_eps)
+            q, k, v = _project_qkv(p, h, cfg)
+            c = x.shape[1]
+            pos = (start + jnp.arange(c, dtype=jnp.int32))[None]  # [1, C]
+            q = apply_rope(q, pos, cfg.rope_theta)
+            k = apply_rope(k, pos, cfg.rope_theta)
+        with jax.named_scope("kv_append"):
+            k_pool, v_pool = paged_cache_append_chunk(
+                k_pool, v_pool, block_tables, start, k, v, n_valid)
+        with jax.named_scope("attend"):
+            o = paged_prefill_attention(q, k_pool, v_pool, block_tables, pos)
+        with jax.named_scope("out"):
+            o = _attn_out(p, o, cfg)
+    return o, k_pool, v_pool
 
 
+@jax.named_scope("ffn")
 def _paged_ffn(p, x, cfg: ArchConfig, ctx: ShardingCtx):
     """The post-attention half every paged block shares (MoE or SwiGLU)."""
     if cfg.moe:
@@ -480,7 +520,8 @@ def prefill_chunk_paged(params, k_pools, v_pools, block_tables, start, batch,
         raise ValueError(f"paged prefill unsupported for family {cfg.family!r} "
                          f"(sliding_window={cfg.sliding_window})")
     dt = jnp.dtype(cfg.dtype)
-    x = embed_lookup(params["embed"], batch["tokens"]).astype(dt)
+    with jax.named_scope("embed"):
+        x = embed_lookup(params["embed"], batch["tokens"]).astype(dt)
 
     def block_fn(lp, x, kp, vp):
         return paged_block_prefill_chunk(lp, x, kp, vp, cfg, ctx,
@@ -489,9 +530,10 @@ def prefill_chunk_paged(params, k_pools, v_pools, block_tables, start, batch,
 
     x, k_pools, v_pools = _run_layers_paged(params["layers"], k_pools, v_pools,
                                             x, cfg, block_fn)
-    last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)  # [1,1,d]
-    last = rms_norm(last, params["final_norm"], cfg.norm_eps)
-    logits = _unembed(params, last, cfg, ctx)[:, 0]
+    with jax.named_scope("unembed"):
+        last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
+        last = rms_norm(last, params["final_norm"], cfg.norm_eps)
+        logits = _unembed(params, last, cfg, ctx)[:, 0]     # [1, V]
     return logits, k_pools, v_pools
 
 
@@ -513,12 +555,14 @@ def decode_step_paged(params, k_pools, v_pools, block_tables, lengths, batch,
         raise ValueError(f"paged decode unsupported for family {cfg.family!r} "
                          f"(sliding_window={cfg.sliding_window})")
     dt = jnp.dtype(cfg.dtype)
-    x = embed_lookup(params["embed"], batch["tokens"]).astype(dt)
+    with jax.named_scope("embed"):
+        x = embed_lookup(params["embed"], batch["tokens"]).astype(dt)
     x, k_pools, v_pools = run_layers_decode_paged(
         params["layers"], k_pools, v_pools, x, cfg, ctx,
         block_tables=block_tables, lengths=lengths)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _unembed(params, x, cfg, ctx)
+    with jax.named_scope("unembed"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = _unembed(params, x, cfg, ctx)
     return logits, k_pools, v_pools
 
 

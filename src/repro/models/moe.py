@@ -108,10 +108,13 @@ def moe_dense(p, x, cfg: ArchConfig):
     """
     b, s, d = x.shape
     xt = x.reshape(b * s, d)
-    gates, experts, aux = router_topk(xt, p["router"], cfg.top_k)
-    outs = _expert_ffn(xt[None], p["w_gate"], p["w_up"], p["w_down"])  # [E,T,d]
-    comb = jax.nn.one_hot(experts, cfg.n_experts, dtype=xt.dtype) * gates[..., None]
-    y = jnp.einsum("tke,etd->td", comb, outs)
+    with jax.named_scope("router"):
+        gates, experts, aux = router_topk(xt, p["router"], cfg.top_k)
+    with jax.named_scope("experts"):
+        outs = _expert_ffn(xt[None], p["w_gate"], p["w_up"], p["w_down"])
+        comb = (jax.nn.one_hot(experts, cfg.n_experts, dtype=xt.dtype)
+                * gates[..., None])
+        y = jnp.einsum("tke,etd->td", comb, outs)     # outs [E,T,d]
     return y.reshape(b, s, d), aux
 
 

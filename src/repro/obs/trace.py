@@ -1,19 +1,29 @@
 """Span tracer: ring-buffered structured tracing with Perfetto-ready export.
 
 Every layer of the stack feeds one process-wide tracer (`get_tracer()`):
-the serving engine emits request-lifecycle spans (admit -> prefix lookup ->
-prefill chunks -> decode rounds -> finish, with instant events for COW
-forks, cache evictions, and preemptions), `core.coro.coro_call` emits one
-span per launched pipeline carrying depth / n_tiles / context-bytes
-attributes, and the dense drive loop in `launch.serve` emits per-step round
-spans. `export(path)` writes Chrome trace-event JSON that opens directly in
-Perfetto (https://ui.perfetto.dev) or chrome://tracing.
+the serving engine emits a span tree per round (admission, decode staging,
+dispatch and wait, token emission, prefill chunks and what follows them;
+`serve.engine` lists it) and request-lifecycle spans, with instant events
+for admissions, cache evictions, and preemptions; `core.coro.coro_call`
+emits one span per launched pipeline carrying depth / n_tiles /
+context-bytes attributes, and the dense drive loop in `launch.serve` emits
+per-step round spans. `export(path)` writes Chrome trace-event JSON that
+opens directly in Perfetto (https://ui.perfetto.dev) or chrome://tracing.
 
 Design constraints (ISSUE-8):
 
-* zero dependencies - events are plain dicts in a `collections.deque` ring
-  (default 65536 events; the oldest fall off, `dropped` counts them), so a
-  long-lived serving process never grows without bound.
+* events are plain dicts in a `collections.deque` ring (default 65536
+  events; the oldest fall off, `dropped` counts them), so a long-lived
+  serving process never grows without bound.
+* one clock with the device - while a span is open it also holds a
+  `jax.profiler.TraceAnnotation` named ``repro/<span name>`` with the span's
+  arguments, so a profiler trace holds every span on the host plane, on the
+  clock of the device planes. The prefix keeps the names apart from any
+  annotation a caller makes around the program (a benchmark's ``round``).
+  With no profiler running an annotation costs about a microsecond.
+* a parent link - every "X" event carries its own id (``sid``) and the id
+  of the span that was open around it (``parent``; None at the top), from
+  the tracer's stack of open spans.
 * true no-op when disabled - ``REPRO_TELEMETRY=0`` (the same switch
   `core.autotune` honours) swaps the module-level singleton for
   `NULL_TRACER`, whose methods do nothing and whose `span()` returns one
@@ -23,13 +33,14 @@ Design constraints (ISSUE-8):
 
 Event vocabulary (Chrome trace-event phases):
 
-  "X" complete span   - span(name, ...) context manager / complete(...)
+  "X" complete span   - span(name, ...) context manager / complete(...);
+                        carries "sid" and "parent" besides the standard keys
   "i" instant event   - instant(name, ...); thread-scoped ("s": "t").
                         The serving failure model (ISSUE-9) emits its own
                         vocabulary here: "shed", "cancel", "stall",
                         "step_fault", "quarantine", "latency_spike", and
                         "run_stalled", alongside the original "admit" /
-                        "cow_fork" / "cache_evict" / "preempt" events.
+                        "cache_evict" / "preempt" events.
   "b"/"e" async pair  - begin_async/end_async(name, id): spans that outlive
                         one call frame (a request's whole lifetime)
 
@@ -44,7 +55,9 @@ import json
 import os
 import time
 from collections import deque
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "NULL_TRACER",
@@ -68,9 +81,11 @@ TID_REQUEST_BASE = 64  # request rid r renders on track TID_REQUEST_BASE + r
 
 
 class _Span:
-    """Context manager emitting one "X" complete event on exit."""
+    """Context manager emitting one "X" complete event on exit; while open
+    it is the parent of what is recorded and holds a profiler annotation."""
 
-    __slots__ = ("_tracer", "_name", "_tid", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_tid", "_args", "_t0", "_sid",
+                 "_parent", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, tid: int,
                  args: Optional[Dict[str, Any]]):
@@ -80,15 +95,26 @@ class _Span:
         self._args = args
 
     def __enter__(self) -> "_Span":
-        self._t0 = self._tracer.now_us()
+        t = self._tracer
+        self._parent = t._open[-1] if t._open else None
+        self._sid = t._new_sid()
+        t._open.append(self._sid)
+        self._ann = TraceAnnotation(f"repro/{self._name}",
+                                    **(self._args or {}))
+        self._ann.__enter__()
+        self._t0 = t.now_us()
         return self
 
     def __exit__(self, *exc) -> None:
         t = self._tracer
+        dur = t.now_us() - self._t0
+        self._ann.__exit__(None, None, None)
+        t._open.pop()
         t._emit({"name": self._name, "cat": "repro", "ph": "X",
-                 "ts": self._t0, "dur": t.now_us() - self._t0,
+                 "ts": self._t0, "dur": dur,
                  "pid": PID, "tid": self._tid,
-                 "args": self._args or {}})
+                 "args": self._args or {},
+                 "sid": self._sid, "parent": self._parent})
 
 
 class Tracer:
@@ -98,6 +124,8 @@ class Tracer:
         self.events: deque = deque(maxlen=capacity)
         self.dropped = 0
         self._epoch_ns = time.perf_counter_ns()
+        self._open: List[int] = []   # ids of the open spans, innermost last
+        self._sids = 0
 
     # ------------------------------------------------------------- clock
 
@@ -106,6 +134,10 @@ class Tracer:
         return (time.perf_counter_ns() - self._epoch_ns) / 1e3
 
     # ------------------------------------------------------------ record
+
+    def _new_sid(self) -> int:
+        self._sids += 1
+        return self._sids
 
     def _emit(self, ev: Dict[str, Any]) -> None:
         if len(self.events) == self.events.maxlen:
@@ -120,13 +152,16 @@ class Tracer:
     def complete(self, name: str, start_us: float, dur_us: float,
                  tid: int = TID_ENGINE, **args) -> None:
         """Emit an "X" span with explicit timing (for already-measured
-        intervals: the pipeline wall clock `coro_call` observed)."""
+        intervals: the pipeline wall clock `coro_call` observed); its parent
+        is the span open now. It holds no profiler annotation."""
         self._emit({"name": name, "cat": "repro", "ph": "X",
                     "ts": start_us, "dur": max(dur_us, 0.0),
-                    "pid": PID, "tid": tid, "args": args})
+                    "pid": PID, "tid": tid, "args": args,
+                    "sid": self._new_sid(),
+                    "parent": self._open[-1] if self._open else None})
 
     def instant(self, name: str, tid: int = TID_ENGINE, **args) -> None:
-        """Thread-scoped instant event (COW fork, eviction, preemption)."""
+        """Thread-scoped instant event (admission, eviction, preemption)."""
         self._emit({"name": name, "cat": "repro", "ph": "i", "s": "t",
                     "ts": self.now_us(), "pid": PID, "tid": tid,
                     "args": args})

@@ -53,15 +53,32 @@ Observability (ISSUE-8, DESIGN.md §2.5): every engine instance owns one
 `obs.metrics` registry — the prefix/COW counters and the token-latency /
 TTFT / TBT histograms live there, and `stats()` is a read-time view over
 it, not a parallel dict. The engine also feeds the process tracer
-(`obs.trace`): per-round / decode-round / prefill-chunk spans, an async
-``request`` span per request lifetime, a ``pipeline:paged_decode`` span per
-decode round (depth / n_tiles / context-bytes attributes), and instant
-events for COW forks and cache evictions (preemptions are emitted by the
-scheduler). Both degrade to module-level null objects under
-``REPRO_TELEMETRY=0`` — no per-call branching in the round loop.
+(`obs.trace`), one span tree per round, each span where its work happens:
+
+    round(n)
+      admit > prefix_lookup            scheduler admission, cache lookups
+      decode_stage > cow_fork          slots, forks, token/table/length arrays
+      decode_round(width, table_width, depth, n_tiles, context_bytes)
+        decode_dispatch                the jitted call, until it returns
+        decode_wait                    device_get of the tokens
+      emit                             token callbacks and finishes
+      cow_fork                         a prefill chunk's first page
+      prefill_chunk(rid, start, n)
+        prefill_dispatch, prefill_wait
+      numerics_scan, prefix_insert, first_token
+
+plus an async ``request`` span per request lifetime and instant events for
+admissions and cache evictions (preemptions are emitted by the scheduler).
+No span adds a device synchronisation: a dispatch span, and `cow_fork`,
+end when the work is enqueued, not when the device has done it.
+`op_scopes()` names the model scope (`models.lm.SCOPES`) of every
+instruction of the compiled programs, for a profiler trace's device ops.
+Both degrade to module-level null objects under ``REPRO_TELEMETRY=0`` — no
+per-call branching in the round loop.
 """
 from __future__ import annotations
 
+import re
 import time
 from typing import Any, Callable, Dict, FrozenSet, List, Optional
 
@@ -74,6 +91,7 @@ from repro.core import autotune, guard
 from repro.core.machine import get_machine
 from repro.kernels.decode_attention.decode_attention import paged_decode_spec
 from repro.models import build_model
+from repro.models.lm import model_scope
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import latency_report  # noqa: F401  (re-export; the
@@ -105,6 +123,23 @@ def decode_round_fn(model):
                 k_pools, v_pools)
 
     return paged_decode_round
+
+
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([^\s=%]+)\s+=\s")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def hlo_op_scopes(hlo_text: str) -> Dict[str, str]:
+    """HLO instruction name -> the model scope in its `op_name` metadata
+    (`models.lm.model_scope`), over every computation of a compiled
+    program's text; `other` where the metadata names none."""
+    out: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m:
+            op = _HLO_OP_NAME.search(line)
+            out[m.group(1)] = model_scope(op.group(1) if op else "")
+    return out
 
 
 class PagedServingEngine:
@@ -166,7 +201,8 @@ class PagedServingEngine:
             self.pager, self.round_width,
             token_budget=self.token_budget, reclaim=self._reclaim,
             faults=self.faults)
-        self.prefiller = ChunkedPrefiller(self.model, block_size)
+        self.tracer = obs_trace.get_tracer()
+        self.prefiller = ChunkedPrefiller(self.model, block_size, self.tracer)
 
         shape = (cfg.n_layers, self.pager.physical_blocks, block_size, kh, hd)
         self.k_pools = jnp.zeros(shape, jnp.dtype(cfg.dtype))
@@ -185,10 +221,9 @@ class PagedServingEngine:
 
         # one registry per engine instance (two engines in one process must
         # not mix counters); `stats()` is a view over it — ISSUE-8. The
-        # tracer is fetched once: the round loop calls through it with no
-        # enabled() branching (null objects under REPRO_TELEMETRY=0).
+        # tracer (fetched once, above) is called through with no enabled()
+        # branching (null objects under REPRO_TELEMETRY=0).
         self.metrics = obs_metrics.new_registry()
-        self.tracer = obs_trace.get_tracer()
         m = self.metrics
         self._c_prefix_hits = m.counter("serve.prefix_hits")
         self._c_prefix_tokens = m.counter("serve.prefix_tokens")
@@ -409,11 +444,13 @@ class PagedServingEngine:
         return freed
 
     def _copy_page(self, src: int, dst: int) -> None:
-        """Materialise a copy-on-write fork in the physical pools."""
-        self.k_pools = self.k_pools.at[:, dst].set(self.k_pools[:, src])
-        self.v_pools = self.v_pools.at[:, dst].set(self.v_pools[:, src])
+        """Materialise a copy-on-write fork in the physical pools. Its
+        `cow_fork` span ends when both copies are enqueued, not when the
+        device has made them."""
+        with self.tracer.span("cow_fork", src=src, dst=dst):
+            self.k_pools = self.k_pools.at[:, dst].set(self.k_pools[:, src])
+            self.v_pools = self.v_pools.at[:, dst].set(self.v_pools[:, src])
         self._c_cow_forks.inc()
-        self.tracer.instant("cow_fork", src=src, dst=dst)
 
     def _make_writable(self, req: Request, pos: int) -> None:
         copy = self.scheduler.make_writable(req, pos)
@@ -424,7 +461,9 @@ class PagedServingEngine:
 
     def _prefill_chunk_step(self, req: Request, n: int) -> None:
         """Run one `n`-token chunk of `req`'s prefill; on the last chunk,
-        emit the first generated token and promote (or finish)."""
+        emit the first generated token and promote (or finish). After the
+        chunk: `numerics_scan`, `prefix_insert` and, on the last chunk,
+        `first_token` (the argmax's sync, the emit and the promotion)."""
         ctxt = req.context
         start = req.prefill_pos
         n = min(n, len(ctxt) - start)
@@ -449,20 +488,23 @@ class PagedServingEngine:
         # the always-on numerics scan (DESIGN.md §2.7): a non-finite chunk
         # raises before prefill_pos advances, so the chunk re-runs on retry
         # (KV rows rewrite idempotently; the pools are already committed)
-        nerr = guard.scan_output("paged_prefill_chunk", logits)
+        with self.tracer.span("numerics_scan"):
+            nerr = guard.scan_output("paged_prefill_chunk", logits)
         if nerr is not None:
             raise nerr
         req.prefill_pos = start + n
         if self.prefix_cache is not None:
-            self.prefix_cache.insert(ctxt[:req.prefill_pos],
-                                     self.pager.block_table(req.rid))
+            with self.tracer.span("prefix_insert"):
+                self.prefix_cache.insert(ctxt[:req.prefill_pos],
+                                         self.pager.block_table(req.rid))
         if req.prefill_pos >= len(ctxt):
-            first = int(jnp.argmax(logits[0]))
-            self._emit(req, first)
-            if req.done:  # max_new_tokens == 1: satisfied by this token
-                self._finish(req)
-            else:
-                self.scheduler.promote(req)
+            with self.tracer.span("first_token", rid=req.rid):
+                first = int(jnp.argmax(logits[0]))
+                self._emit(req, first)
+                if req.done:  # max_new_tokens == 1: satisfied by this token
+                    self._finish(req)
+                else:
+                    self.scheduler.promote(req)
 
     def _emit(self, req: Request, token: int) -> None:
         now = time.perf_counter()
@@ -508,6 +550,35 @@ class PagedServingEngine:
             self._decode_fresh = True
         return fn
 
+    def op_scopes(self) -> Dict[str, Dict[str, str]]:
+        """For each decode and prefill program compiled so far, a map from
+        HLO instruction name to the model scope in its `op_name` metadata
+        (`hlo_op_scopes`): what a profiler trace's device ops belong to.
+        Keyed ``paged_decode_round[tw=<table width>]`` and
+        ``paged_prefill_chunk[c=<chunk bucket>,tw=<table width>]``.
+
+        Each program is lowered and compiled again from the shapes it runs
+        (a persistent compile cache, where one is set, serves it), and its
+        text read once: call this on request, outside any timed window."""
+        width = self.round_width
+        out: Dict[str, Dict[str, str]] = {}
+        for tw, fn in self._decode_fns.items():
+            args = (np.zeros((width, 1), np.int32),
+                    np.zeros((width, tw), np.int32),
+                    np.zeros((width,), np.int32))
+            out[f"paged_decode_round[tw={tw}]"] = self._compiled_scopes(
+                fn, *args)
+        for (padded, tw), fn in self.prefiller._fns.items():
+            args = (np.zeros((1, padded), np.int32),
+                    np.zeros((1, tw), np.int32), np.int32(0), np.int32(1))
+            out[f"paged_prefill_chunk[c={padded},tw={tw}]"] = (
+                self._compiled_scopes(fn, *args))
+        return out
+
+    def _compiled_scopes(self, fn, *args) -> Dict[str, str]:
+        lowered = fn.lower(self.params, self.k_pools, self.v_pools, *args)
+        return hlo_op_scopes(lowered.compile().as_text())
+
     def _table_width(self) -> int:
         """Block-table width: every request's table padded to the worst
         case any **live** request can reach, tracked as a high-water mark
@@ -526,39 +597,50 @@ class PagedServingEngine:
         return self._tw_hw
 
     def _decode_round(self, active: List[Request]) -> int:
-        """Decode one token for every (still-)running request in `active`."""
-        # reserve pool room for each request's next token; reserving may
-        # preempt later-admitted members of this same round, and writing
-        # mid-block may copy-on-write fork a page the prefix cache shares.
-        # Pressure neither reclaim nor preemption can resolve stalls the
-        # request (requeue, bounded retries) instead of crashing the round.
-        writable: List[Request] = []
-        for req in active:
-            if req.state is not RequestState.RUNNING:
-                continue  # preempted by an earlier reservation
-            try:
-                pos = self.scheduler.reserve_decode_slot(req)
-                if req.state is RequestState.RUNNING:
-                    self._make_writable(req, pos)
-            except PoolExhausted as e:
-                self._stall(req, e)
-                continue
-            writable.append(req)
-        writable = [r for r in writable if r.state is RequestState.RUNNING]
-        if not writable:
-            return 0
+        """Decode one token for every (still-)running request in `active`.
+        Spans: `decode_stage`, then `decode_round` holding
+        `decode_dispatch` (ends when the jitted call returns, before the
+        device finishes) and `decode_wait` (the tokens' `device_get`), then
+        `emit`."""
+        tracer = self.tracer
+        with tracer.span("decode_stage"):
+            # reserve pool room for each request's next token; reserving may
+            # preempt later-admitted members of this same round, and writing
+            # mid-block may copy-on-write fork a page the prefix cache
+            # shares. Pressure neither reclaim nor preemption can resolve
+            # stalls the request (requeue, bounded retries) instead of
+            # crashing the round.
+            writable: List[Request] = []
+            for req in active:
+                if req.state is not RequestState.RUNNING:
+                    continue  # preempted by an earlier reservation
+                try:
+                    pos = self.scheduler.reserve_decode_slot(req)
+                    if req.state is RequestState.RUNNING:
+                        self._make_writable(req, pos)
+                except PoolExhausted as e:
+                    self._stall(req, e)
+                    continue
+                writable.append(req)
+            writable = [r for r in writable
+                        if r.state is RequestState.RUNNING]
+            if not writable:
+                return 0
 
-        width = self.round_width
-        tw = self._table_width()
-        tokens = np.zeros((width, 1), np.int32)
-        tables = np.zeros((width, tw), np.int32)   # garbage page 0 padding
-        lengths = np.zeros((width,), np.int32)
-        for i, req in enumerate(writable):
-            tokens[i, 0] = req.generated[-1]
-            tables[i] = self.pager.padded_table(req.rid, tw)
-            # pager length already counts the reserved slot; the model wants
-            # the pre-write count (the new row's position)
-            lengths[i] = self.pager.length(req.rid) - 1
+            width = self.round_width
+            tw = self._table_width()
+            tokens = np.zeros((width, 1), np.int32)
+            tables = np.zeros((width, tw), np.int32)   # garbage page 0 pad
+            lengths = np.zeros((width,), np.int32)
+            for i, req in enumerate(writable):
+                tokens[i, 0] = req.generated[-1]
+                tables[i] = self.pager.padded_table(req.rid, tw)
+                # pager length already counts the reserved slot; the model
+                # wants the pre-write count (the new row's position)
+                lengths[i] = self.pager.length(req.rid) - 1
+            # the KV page-tiles this round attends
+            tiles = sum(self.pager.blocks_for(int(n) + 1)
+                        for n in lengths[:len(writable)])
 
         t0 = time.perf_counter()
         try:
@@ -571,13 +653,17 @@ class PagedServingEngine:
             guard.check_injected("paged_decode_round", self.faults,
                                  round=self.rounds, width=len(writable))
             decode = self._decode(tw)
-            with self.tracer.span("decode_round", width=len(writable),
-                                  table_width=tw):
-                nxt, finite, self.k_pools, self.v_pools = decode(
-                    self.params, self.k_pools, self.v_pools,
-                    jnp.asarray(tokens), jnp.asarray(tables),
-                    jnp.asarray(lengths))
-                nxt, finite = jax.device_get((nxt, finite))
+            with tracer.span("decode_round", width=len(writable),
+                             table_width=tw, depth=self.solved_depth,
+                             n_tiles=tiles,
+                             context_bytes=self._pipeline_ctx_bytes):
+                with tracer.span("decode_dispatch"):
+                    nxt, finite, self.k_pools, self.v_pools = decode(
+                        self.params, self.k_pools, self.v_pools,
+                        jnp.asarray(tokens), jnp.asarray(tables),
+                        jnp.asarray(lengths))
+                with tracer.span("decode_wait"):
+                    nxt, finite = jax.device_get((nxt, finite))
             if not finite[:len(writable)].all():
                 raise guard.numerics_fault("paged_decode_round")
         except Exception as e:
@@ -596,32 +682,22 @@ class PagedServingEngine:
 
         # always-on transfer telemetry (ISSUE-6): every decode round feeds
         # the same (machine, kernel) store the paged kernel's pipeline does —
-        # wall clock over the KV page-tiles this round actually attended.
-        # The same interval is the round's `pipeline:paged_decode` span on
-        # the tracer (ISSUE-8), depth / n_tiles / context-bytes attributes
-        # matching what coro_call stamps on a real kernel launch.
-        tiles = sum(self.pager.blocks_for(int(n) + 1)
-                    for n in (lengths[i] for i in range(len(writable))))
-        end_us = self.tracer.now_us()
-        self.tracer.complete("pipeline:paged_decode", end_us - dt * 1e6,
-                             dt * 1e6, tid=obs_trace.TID_KERNEL,
-                             depth=self.solved_depth, n_tiles=tiles,
-                             context_bytes=self._pipeline_ctx_bytes,
-                             jit_warmup=self._decode_fresh)
+        # wall clock over the KV page-tiles this round actually attended
         if self._decode_fresh:
             self._decode_fresh = False  # round paid jit compile; don't record
         elif autotune.telemetry_enabled() and tiles:
             autotune.record_transfer("paged_decode", dt / tiles)
 
-        for i, req in enumerate(writable):
-            if req.state is not RequestState.RUNNING:
-                continue  # a callback cancelled it mid-round
-            req.fault_count = 0  # a successful step clears shared blame
-            req.kv_len = self.pager.length(req.rid)
-            self._emit(req, int(nxt[i]))
-            self._h_token.observe(dt)
-            if req.done:
-                self._finish(req)
+        with tracer.span("emit"):
+            for i, req in enumerate(writable):
+                if req.state is not RequestState.RUNNING:
+                    continue  # a callback cancelled it mid-round
+                req.fault_count = 0  # a successful step clears shared blame
+                req.kv_len = self.pager.length(req.rid)
+                self._emit(req, int(nxt[i]))
+                self._h_token.observe(dt)
+                if req.done:
+                    self._finish(req)
         return len(writable)
 
     # --------------------------------------------------------------- round
@@ -642,15 +718,16 @@ class PagedServingEngine:
                                     sleep_ms=round(spike * 1e3, 3))
                 time.sleep(spike)
             match = self._match if self.prefix_cache is not None else None
-            for req in self.scheduler.admit(match=match):
-                self.tracer.instant("admit", rid=req.rid,
-                                    matched=req.matched_len,
-                                    context=len(req.context))
-                if req.matched_len > 0:
-                    self._c_prefix_hits.inc()
-                    self._c_prefix_tokens.inc(req.matched_len)
-                    self._c_blocks_shared.inc(
-                        self.pager.blocks_for(req.matched_len))
+            with self.tracer.span("admit"):
+                for req in self.scheduler.admit(match=match):
+                    self.tracer.instant("admit", rid=req.rid,
+                                        matched=req.matched_len,
+                                        context=len(req.context))
+                    if req.matched_len > 0:
+                        self._c_prefix_hits.inc()
+                        self._c_prefix_tokens.inc(req.matched_len)
+                        self._c_blocks_shared.inc(
+                            self.pager.blocks_for(req.matched_len))
 
             decodes, plans = self.scheduler.plan_round(self.prefill_chunk)
             emitted = self._decode_round(decodes)

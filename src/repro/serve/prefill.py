@@ -41,9 +41,10 @@ def bucket_len(n: int, floor: int = 1) -> int:
 class ChunkedPrefiller:
     """Owns the pow2-bucketed jit cache for paged prefill chunk steps."""
 
-    def __init__(self, model, block_size: int):
+    def __init__(self, model, block_size: int, tracer):
         self.model = model
         self.block_size = int(block_size)
+        self.tracer = tracer
         self._fns: Dict[Tuple[int, int], Any] = {}  # (padded_len, table_width)
         self._warm: set = set()  # keys whose jit compile was already paid
         self.chunks_run = 0
@@ -69,7 +70,9 @@ class ChunkedPrefiller:
         """Execute one prefill chunk. `tokens` is the [n_valid] real token
         slice; it is right-padded to its pow2 bucket here. `table` is the
         request's padded block table [table_width]. Returns
-        (last_logits [1, V], k_pools, v_pools, wall_s)."""
+        (last_logits [1, V], k_pools, v_pools, wall_s). Spans:
+        `prefill_dispatch` (ends when the jitted call returns, before the
+        device finishes) and `prefill_wait` (the logits' readiness)."""
         if n_valid < 1:
             raise ValueError(f"chunk needs >= 1 tokens, got {n_valid}")
         padded = bucket_len(n_valid, floor=self.block_size)
@@ -80,10 +83,13 @@ class ChunkedPrefiller:
         fn = self._fn(*key)
         warm = key in self._warm
         t0 = time.perf_counter()
-        logits, k_pools, v_pools = fn(
-            params, k_pools, v_pools, jnp.asarray(buf), jnp.asarray(table),
-            jnp.asarray(start, jnp.int32), jnp.asarray(n_valid, jnp.int32))
-        jax.block_until_ready(logits)
+        with self.tracer.span("prefill_dispatch"):
+            logits, k_pools, v_pools = fn(
+                params, k_pools, v_pools, jnp.asarray(buf),
+                jnp.asarray(table), jnp.asarray(start, jnp.int32),
+                jnp.asarray(n_valid, jnp.int32))
+        with self.tracer.span("prefill_wait"):
+            jax.block_until_ready(logits)
         dt = time.perf_counter() - t0
         self.chunks_run += 1
         self._warm.add(key)

@@ -2,6 +2,7 @@
 end-to-end paged-serve run, the REPRO_TELEMETRY=0 null path, the shared
 percentile/histogram/registry machinery, and the Fig. 14-style stall
 breakdown's sum-to-wall-time invariant."""
+import collections
 import json
 
 import numpy as np
@@ -12,6 +13,7 @@ from repro.configs import get_config
 from repro.core import autotune
 from repro.core.schedule import TileProfile
 from repro.obs import breakdown, metrics, trace
+from repro.models.lm import model_scope
 from repro.serve import PagedServingEngine
 
 
@@ -82,8 +84,8 @@ def _validate_chrome_trace(doc):
 
 def test_trace_schema_and_lifecycle_events(tmp_path):
     """Acceptance: a prefix-cache paged run emits valid Chrome trace JSON
-    with request-lifecycle spans, pipeline spans carrying depth/n_tiles
-    attributes, and COW/evict/preempt instant events."""
+    with request-lifecycle spans, decode rounds carrying depth/n_tiles
+    attributes, COW fork spans and evict/preempt instant events."""
     eng, stats = _pressured_prefix_run()
     path = tmp_path / "trace.json"
     trace.get_tracer().export(str(path))
@@ -93,12 +95,16 @@ def test_trace_schema_and_lifecycle_events(tmp_path):
     assert {"round", "decode_round", "prefill_chunk", "prefix_lookup",
             "admit"} <= names
 
-    # the workload really did fork/evict/preempt (else the instants can't
-    # be there) — and the instants are there
+    # the workload really did fork/evict/preempt (else the events can't
+    # be there) — and the events are there, one fork span per counted fork
     assert stats["cow_forks"] >= 1 and stats["preemptions"] >= 1
     assert stats["cache_evictions"] >= 1
     instants = {ev["name"] for ev in events if ev["ph"] == "i"}
-    assert {"cow_fork", "cache_evict", "preempt"} <= instants
+    assert {"cache_evict", "preempt"} <= instants
+    forks = [ev for ev in events
+             if ev["ph"] == "X" and ev["name"] == "cow_fork"]
+    assert len(forks) == stats["cow_forks"]
+    assert all(ev["args"]["src"] != ev["args"]["dst"] for ev in forks)
 
     # request lifecycle: every submitted rid opens and closes an async span
     begins = {ev["id"] for ev in events
@@ -107,13 +113,19 @@ def test_trace_schema_and_lifecycle_events(tmp_path):
             if ev["ph"] == "e" and ev["name"] == "request"}
     assert begins == ends == {0, 1, 2}
 
-    # pipeline spans carry the §2.5 attributes
-    pipes = [ev for ev in events if ev["name"] == "pipeline:paged_decode"]
-    assert pipes
-    for ev in pipes:
+    # decode rounds carry the §2.5 pipeline attributes, and hold their
+    # dispatch and wait
+    rounds = [ev for ev in events if ev["name"] == "decode_round"]
+    assert rounds
+    by_sid = {ev["sid"]: ev for ev in events if ev["ph"] == "X"}
+    for ev in rounds:
         assert ev["args"]["depth"] == stats["solved_depth"]
-        assert ev["args"]["n_tiles"] >= 0
+        assert ev["args"]["n_tiles"] >= ev["args"]["width"] >= 1
         assert ev["args"]["context_bytes"] > 0
+        kids = sorted(c["name"] for c in by_sid.values()
+                      if c["parent"] == ev["sid"])
+        assert kids == ["decode_dispatch", "decode_wait"]
+    assert "pipeline:paged_decode" not in names
 
 
 def test_coro_call_pipeline_span_attributes():
@@ -151,7 +163,133 @@ def test_trace_export_via_launch_serve_flag(tmp_path):
     assert path.stat().st_size > 0
     events = _validate_chrome_trace(json.loads(path.read_text()))
     names = {ev["name"] for ev in events}
-    assert "round" in names and "pipeline:paged_decode" in names
+    assert {"round", "decode_round", "decode_dispatch",
+            "decode_wait"} <= names
+
+
+# ------------------------------------------------------- round span tree
+
+# each engine span's parent in the round's tree (serve/engine.py); a
+# copy-on-write fork is staged for a decode row or for a prefill chunk
+ROUND_TREE = {
+    "round": None, "admit": "round", "prefix_lookup": "admit",
+    "decode_stage": "round", "decode_round": "round",
+    "decode_dispatch": "decode_round", "decode_wait": "decode_round",
+    "emit": "round", "prefill_chunk": "round",
+    "prefill_dispatch": "prefill_chunk", "prefill_wait": "prefill_chunk",
+    "numerics_scan": "round", "prefix_insert": "round",
+    "first_token": "round",
+}
+
+
+def test_span_parent_links_follow_the_open_spans():
+    tr = trace.Tracer()
+    with tr.span("a"):
+        with tr.span("b"):
+            tr.complete("c", 0.0, 1.0)
+        with pytest.raises(KeyError):
+            with tr.span("d"):
+                raise KeyError("the stack unwinds on an exception")
+    with tr.span("e"):
+        pass
+    ev = {e["name"]: e for e in tr.events}
+    assert ev["a"]["parent"] is None and ev["e"]["parent"] is None
+    assert ev["b"]["parent"] == ev["d"]["parent"] == ev["a"]["sid"]
+    assert ev["c"]["parent"] == ev["b"]["sid"]
+    assert len({e["sid"] for e in tr.events}) == 5
+    assert tr._open == []
+
+
+def test_round_span_tree_and_parent_links():
+    """Each engine span has the parent the round's tree gives it and lies
+    inside it; `decode_round` and `prefill_chunk` keep their arguments."""
+    eng, stats = _pressured_prefix_run()
+    spans = [ev for ev in trace.get_tracer().events if ev["ph"] == "X"]
+    by_sid = {ev["sid"]: ev for ev in spans}
+    assert len(by_sid) == len(spans)
+    for ev in spans:
+        up = by_sid.get(ev["parent"])
+        if ev["name"] == "cow_fork":
+            assert up["name"] in ("decode_stage", "round"), ev
+        else:
+            assert (up and up["name"]) == ROUND_TREE[ev["name"]], ev
+        if up is not None:
+            assert up["ts"] <= ev["ts"]
+            assert ev["ts"] + ev["dur"] <= up["ts"] + up["dur"] + 1e-3
+    assert {ev["name"] for ev in spans} == set(ROUND_TREE) | {"cow_fork"}
+    for ev in spans:
+        if ev["name"] == "decode_round":
+            assert set(ev["args"]) == {"width", "table_width", "depth",
+                                       "n_tiles", "context_bytes"}
+        elif ev["name"] == "prefill_chunk":
+            assert set(ev["args"]) == {"rid", "start", "n"}
+    assert sorted(ev["args"]["n"] for ev in spans
+                  if ev["name"] == "round") == list(range(stats["rounds"]))
+
+
+def test_engine_spans_reach_the_profiler_trace(tmp_path):
+    """Under a profiler every engine span is also a host event named
+    `repro/<span>`, with its arguments, on the profiler's clock; none is
+    named plain `round`, the name a benchmark gives its own annotation."""
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _eng, stats = _pressured_prefix_run()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    host = [ev for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+    names = collections.Counter(ev.name for ev in host)
+    spans = collections.Counter(ev["name"] for ev in trace.get_tracer().events
+                                if ev["ph"] == "X")
+    assert set(spans) == set(ROUND_TREE) | {"cow_fork"}
+    for name, n in spans.items():
+        assert names[f"repro/{name}"] == n, name
+    assert "round" not in names
+    assert sorted(int(dict(ev.stats)["n"]) for ev in host
+                  if ev.name == "repro/round") == list(range(stats["rounds"]))
+
+
+@pytest.mark.parametrize("op_name,scope", [
+    ("jit(paged_decode_round)/while/body/closed_call/attention/kv_append/"
+     "scatter", "attention/kv_append"),
+    ("jit(paged_decode_round)/while/body/closed_call/ffn/experts/dot_general",
+     "ffn/experts"),
+    ("jit(paged_prefill_chunk)/while/body/closed_call/ffn/add", "ffn"),
+    ("jit(paged_decode_round)/unembed/dot_general", "unembed"),
+    ("jit(paged_decode_round)/while/body/dynamic_slice", "other"),
+    ("", "other"),
+])
+def test_model_scope_reads_the_op_name_path(op_name, scope):
+    assert model_scope(op_name) == scope
+
+
+def test_op_scopes_name_the_model_parts_of_each_program():
+    """`op_scopes()` maps the instructions of a tiny MoE engine's compiled
+    decode and prefill programs to attention and ffn and their parts."""
+    cfg = get_config("granite-moe-1b-a400m").reduced().replace(
+        dtype="float32", param_dtype="float32")
+    eng = PagedServingEngine(cfg, block_size=4, num_blocks=16,
+                             prefill_chunk=8)
+    eng.submit(list(range(1, 11)), max_new_tokens=3)
+    eng.run()
+    scopes = eng.op_scopes()
+    assert set(scopes) == {"paged_decode_round[tw=4]",
+                           "paged_prefill_chunk[c=8,tw=4]",
+                           "paged_prefill_chunk[c=4,tw=4]"}
+    for prog in scopes.values():
+        assert {"embed", "unembed", "ffn", "other", "attention/qkv",
+                "attention/kv_append", "attention/attend", "attention/out",
+                "ffn/router", "ffn/experts"} <= set(prog.values())
+    fusions = {k: v for k, v in scopes["paged_decode_round[tw=4]"].items()
+               if "fusion" in k or k.startswith("dot")}
+    assert {v.split("/")[0] for v in fusions.values()} >= {"attention", "ffn"}
 
 
 # ------------------------------------------------------------- null path

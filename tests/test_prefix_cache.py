@@ -286,8 +286,13 @@ def _f32_cfg():
 
 def test_chunked_prefill_does_not_starve_decodes():
     """Satellite 3: a long prompt admitted mid-stream stalls in-flight
-    decode gaps far less when it trickles through chunks than when it lands
-    as one monolithic prefill (same engine path, huge chunk)."""
+    decodes far less when it trickles through chunks than when it lands as
+    one monolithic prefill (same engine path, huge chunk). Read from the
+    engine's spans, not the wall clock: between two consecutive tokens of
+    the short request, chunked prefill runs at most one chunk's tokens,
+    while the monolithic engine runs the whole prompt."""
+    from repro.obs import trace
+
     cfg = _f32_cfg()
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -295,24 +300,32 @@ def test_chunked_prefill_does_not_starve_decodes():
     short = rng.integers(0, cfg.vocab, 4)
     long = rng.integers(0, cfg.vocab, 96)
 
-    def run(chunk):
+    def most_prefill_between_tokens(chunk):
+        tracer = trace.get_tracer()
+        t0 = tracer.now_us()
+        token_us = []
+
+        def on_token(req, _tok):
+            if req.rid == rid:
+                token_us.append(tracer.now_us())
+
         eng = PagedServingEngine(cfg, block_size=4, num_blocks=64,
                                  params=params, max_in_flight=2,
-                                 prefill_chunk=chunk, prefix_cache=False)
-        # warm every jit bucket this workload will touch, then measure
-        eng.submit(short, max_new_tokens=24)
-        eng.submit(long, max_new_tokens=2)
-        eng.run()
-        eng.tbt_s.clear()
-        eng.submit(short, max_new_tokens=24)
+                                 prefill_chunk=chunk, prefix_cache=False,
+                                 on_token=on_token)
+        rid = eng.submit(short, max_new_tokens=24)
         eng.step_round()  # the short request starts decoding alone...
         eng.submit(long, max_new_tokens=2)  # ...then the long prompt lands
         eng.run()
-        return max(eng.tbt_s)
+        chunks = [(ev["ts"], ev["args"]["n"]) for ev in tracer.events
+                  if ev["name"] == "prefill_chunk" and ev["ts"] > t0
+                  and ev["args"]["rid"] != rid]
+        assert sum(n for _, n in chunks) == len(long)
+        return max(sum(n for ts, n in chunks if a < ts < b)
+                   for a, b in zip(token_us, token_us[1:]))
 
-    chunked = run(8)
-    monolithic = run(512)
-    assert chunked <= monolithic
+    assert most_prefill_between_tokens(8) == 8   # one chunk, never more
+    assert most_prefill_between_tokens(512) == len(long)
 
 
 def test_chunked_prefill_feeds_pipeline_telemetry():
