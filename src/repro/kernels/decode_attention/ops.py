@@ -52,12 +52,13 @@ def _paged_decode_twin(spec, bt_flat, lengths, q, k_pool, v_pool):
     # models.common.paged_decode_attention is the traceable masked twin the
     # serving engine already trusts; the seed paged_decode_attention_ref is
     # a host loop (int(lengths[r])) and cannot police traced calls.
+    # It reads a stack of lane-folded pools, the layout the kernel streams:
+    # this one pool is the stack's only layer.
     from repro.models.common import paged_decode_attention as paged_twin
-    b, _, d = q.shape
+    b = q.shape[0]
     m = bt_flat.shape[0] // b
-    out = paged_twin(q[:, None], _heads_unfolded(k_pool, d),
-                     _heads_unfolded(v_pool, d), bt_flat.reshape(b, m),
-                     lengths)
+    out = paged_twin(q[:, None], k_pool[None], v_pool[None], 0,
+                     bt_flat.reshape(b, m), lengths)
     return out[:, 0].astype(q.dtype)
 
 

@@ -312,42 +312,65 @@ def decode_attention(ctx: ShardingCtx, q, k_cache, v_cache, new_k, new_v, pos,
 
 
 # --------------------------------------------------------- paged KV (serving)
+#
+# The serving engine keeps every layer's KV blocks in one stacked pool per
+# tensor, [L, NB, blk, KH*D]: heads folded into the lane axis, so each
+# (layer, block, offset) row is one contiguous KH*D vector. The helpers take
+# the whole stack and a layer index, scatter new rows at [layer, block,
+# offset] and gather [layer, block_tables], so a layer loop that carries the
+# stack updates it in place and never slices a layer's pool out.
 
 
-def paged_cache_append(k_pool, v_pool, block_tables, lengths, new_k, new_v):
-    """Write one new KV row per request into its paged block.
+def _gather_layer_pages(k_pools, v_pools, layer, block_tables, d):
+    """Gather `block_tables`' pages of one layer and unfold the heads:
+    [B, M] block ids -> k, v [B, M*blk, KH, D]."""
+    b, m = block_tables.shape
+    blk, lanes = k_pools.shape[2], k_pools.shape[3]
+    shape = (b, m * blk, lanes // d, d)
+    return (k_pools[layer, block_tables].reshape(shape),
+            v_pools[layer, block_tables].reshape(shape))
 
-    k_pool/v_pool: [NB, blk, KH, D] one layer's block pool; block_tables:
-    [B, M] int32 block ids; lengths: [B] int32 tokens already stored — row b
-    lands in block `block_tables[b, lengths[b] // blk]` at offset
-    `lengths[b] % blk`. new_k/new_v: [B, 1, KH, D]. Requests own disjoint
-    blocks (serve.kv_pager invariant) so the scatter indices never collide,
+
+def paged_cache_append(k_pools, v_pools, layer, block_tables, lengths, new_k,
+                       new_v):
+    """Write one new KV row per request into its paged block of one layer.
+
+    k_pools/v_pools: [L, NB, blk, KH*D] the stacked, lane-folded pools;
+    layer: [] int32 (or a Python int) layer index; block_tables: [B, M]
+    int32 block ids; lengths: [B] int32 tokens already stored — row b
+    lands at `[layer, block_tables[b, lengths[b] // blk], lengths[b] %
+    blk]`. new_k/new_v: [B, 1, KH, D]. Requests own disjoint blocks
+    (serve.kv_pager invariant) so the scatter indices never collide,
     except padding rows which all target the reserved garbage block 0.
+    Returns the updated (k_pools, v_pools).
     """
     b = lengths.shape[0]
-    blk = k_pool.shape[1]
+    blk = k_pools.shape[2]
     bids = block_tables[jnp.arange(b), lengths // blk]
     offs = lengths % blk
-    k_pool = k_pool.at[bids, offs].set(new_k[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[bids, offs].set(new_v[:, 0].astype(v_pool.dtype))
-    return k_pool, v_pool
+    rows = (b, k_pools.shape[3])
+    k_pools = k_pools.at[layer, bids, offs].set(
+        new_k.reshape(rows).astype(k_pools.dtype))
+    v_pools = v_pools.at[layer, bids, offs].set(
+        new_v.reshape(rows).astype(v_pools.dtype))
+    return k_pools, v_pools
 
 
-def paged_cache_append_chunk(k_pool, v_pool, block_tables, start, new_k, new_v,
-                             n_valid):
-    """Write a chunk of consecutive KV rows into paged blocks.
+def paged_cache_append_chunk(k_pools, v_pools, layer, block_tables, start,
+                             new_k, new_v, n_valid):
+    """Write a chunk of consecutive KV rows into one layer's paged blocks.
 
     The chunked-prefill analogue of `paged_cache_append`: rows
     ``i < n_valid`` of the (right-padded) chunk land at logical positions
     ``start + i`` through the block table; padding rows are redirected to
     the reserved garbage block 0 so they never clobber real pages.
 
-    k_pool/v_pool: [NB, blk, KH, D]; block_tables: [B, M] int32; start/
-    n_valid: [] int32 (one request per call — chunks are per-request);
-    new_k/new_v: [B, C, KH, D].
+    k_pools/v_pools: [L, NB, blk, KH*D]; layer: [] int32 (or a Python int);
+    block_tables: [B, M] int32; start/n_valid: [] int32 (one request per
+    call — chunks are per-request); new_k/new_v: [B, C, KH, D].
     """
     b, c = new_k.shape[0], new_k.shape[1]
-    blk = k_pool.shape[1]
+    blk = k_pools.shape[2]
     m = block_tables.shape[1]
     idx = jnp.arange(c, dtype=jnp.int32)
     pos = start + idx                                   # [C] absolute positions
@@ -356,54 +379,55 @@ def paged_cache_append_chunk(k_pool, v_pool, block_tables, start, new_k, new_v,
     valid = (idx < n_valid) & (pos // blk < m)
     bids = jnp.where(valid[None, :], bids, 0)           # padding -> garbage
     offs = jnp.broadcast_to(pos % blk, (b, c))
-    k_pool = k_pool.at[bids, offs].set(new_k.astype(k_pool.dtype))
-    v_pool = v_pool.at[bids, offs].set(new_v.astype(v_pool.dtype))
-    return k_pool, v_pool
+    rows = (b, c, k_pools.shape[3])
+    k_pools = k_pools.at[layer, bids, offs].set(
+        new_k.reshape(rows).astype(k_pools.dtype))
+    v_pools = v_pools.at[layer, bids, offs].set(
+        new_v.reshape(rows).astype(v_pools.dtype))
+    return k_pools, v_pools
 
 
-def paged_prefill_attention(q, k_pool, v_pool, block_tables, q_pos):
-    """Causal chunk attention against a paged KV cache (chunked prefill).
+def paged_prefill_attention(q, k_pools, v_pools, layer, block_tables, q_pos):
+    """Causal chunk attention against one layer of a paged KV cache
+    (chunked prefill).
 
     q: [B, C, H, D] — a chunk of prompt queries at absolute positions
-    `q_pos` [B, C]; k_pool/v_pool: [NB, blk, KH, D]; block_tables: [B, M]
-    int32 (padded with the garbage block 0). Query i attends every pool
-    position <= q_pos[b, i] — its own chunk's rows were appended first
-    (`paged_cache_append_chunk`), earlier rows hold the already-prefilled
-    (or prefix-cache-shared) prefix. Returns [B, C, H, D]; padded query
-    rows produce garbage the caller discards.
+    `q_pos` [B, C]; k_pools/v_pools: [L, NB, blk, KH*D]; layer: [] int32
+    (or a Python int); block_tables: [B, M] int32 (padded with the garbage
+    block 0). Query i attends every pool position <= q_pos[b, i] — its own
+    chunk's rows were appended first (`paged_cache_append_chunk`), earlier
+    rows hold the already-prefilled (or prefix-cache-shared) prefix.
+    Returns [B, C, H, D]; padded query rows produce garbage the caller
+    discards.
     """
     b, c, h, d = q.shape
-    blk, kh = k_pool.shape[1], k_pool.shape[2]
-    m = block_tables.shape[1]
-    k = k_pool[block_tables].reshape(b, m * blk, kh, d)
-    v = v_pool[block_tables].reshape(b, m * blk, kh, d)
+    k, v = _gather_layer_pages(k_pools, v_pools, layer, block_tables, d)
+    s_len, kh = k.shape[1], k.shape[2]
     qg = _group(q, kh) * (d ** -0.5)                   # [B,C,KH,G,D]
     s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k, preferred_element_type=jnp.float32)
-    valid = jnp.arange(m * blk)[None, None, :] <= q_pos[:, :, None]  # [B,C,S]
+    valid = jnp.arange(s_len)[None, None, :] <= q_pos[:, :, None]  # [B,C,S]
     s = jnp.where(valid[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     o = jnp.einsum("bkgqs,bskd->bqkgd", p, v)
     return o.reshape(b, c, h, d)
 
 
-def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths):
-    """Decode attention over a paged KV cache (jnp twin of the Pallas
-    `kernels/decode_attention.paged_flash_decode`).
+def paged_decode_attention(q, k_pools, v_pools, layer, block_tables, lengths):
+    """Decode attention over one layer of a paged KV cache (jnp twin of the
+    Pallas `kernels/decode_attention.paged_flash_decode`).
 
-    q: [B, 1, H, D]; k_pool/v_pool: [NB, blk, KH, D]; block_tables: [B, M]
-    int32 (padded with the garbage block 0); lengths: [B] int32 — request b
-    attends key positions < lengths[b]. Returns [B, 1, H, D]. Rows with
-    lengths == 0 (padding slots in a round) produce garbage the caller
-    discards.
+    q: [B, 1, H, D]; k_pools/v_pools: [L, NB, blk, KH*D]; layer: [] int32
+    (or a Python int); block_tables: [B, M] int32 (padded with the garbage
+    block 0); lengths: [B] int32 — request b attends key positions <
+    lengths[b]. Returns [B, 1, H, D]. Rows with lengths == 0 (padding
+    slots in a round) produce garbage the caller discards.
     """
     b, _, h, d = q.shape
-    blk, kh = k_pool.shape[1], k_pool.shape[2]
-    m = block_tables.shape[1]
-    k = k_pool[block_tables].reshape(b, m * blk, kh, d)
-    v = v_pool[block_tables].reshape(b, m * blk, kh, d)
+    k, v = _gather_layer_pages(k_pools, v_pools, layer, block_tables, d)
+    s_len, kh = k.shape[1], k.shape[2]
     qg = _group(q, kh)[:, 0] * (d ** -0.5)  # [B,KH,G,D]
     s = jnp.einsum("bkgd,bskd->bkgs", qg, k, preferred_element_type=jnp.float32)
-    valid = jnp.arange(m * blk)[None, None, None, :] < lengths[:, None, None, None]
+    valid = jnp.arange(s_len)[None, None, None, :] < lengths[:, None, None, None]
     s = jnp.where(valid, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgs,bskd->bkgd", p.astype(q.dtype), v)

@@ -347,12 +347,13 @@ def block_decode(p, x, layer_cache, cfg: ArchConfig, ctx: ShardingCtx, *, pos):
 # ------------------------------------------------------------ paged decode
 #
 # The serving engine (repro.serve) replaces the dense [B, max_len] KV caches
-# with per-layer block pools: requests own disjoint fixed-size pages, a block
-# table maps each request's logical positions onto pool blocks, and every
-# request in a round decodes at its OWN position (ragged lengths — the dense
-# path's single scalar `pos` becomes a [B] vector). Supported families:
-# attention (+ MoE FFN); SSM/hybrid recurrent state, cross-attention, and
-# ring (sliding-window) caches keep the dense path.
+# with block pools, one stack per tensor [L, NB, blk, KH*D] that the layer
+# loop carries and updates in place: requests own disjoint fixed-size
+# pages, a block table maps each request's logical positions onto pool
+# blocks, and every request in a round decodes at its OWN position (ragged
+# lengths — the dense path's single scalar `pos` becomes a [B] vector).
+# Supported families: attention (+ MoE FFN); SSM/hybrid recurrent state,
+# cross-attention, and ring (sliding-window) caches keep the dense path.
 #
 # The paged paths name their parts with `jax.named_scope`, so each HLO
 # instruction's `op_name` says which part of the model emitted it:
@@ -380,13 +381,13 @@ def model_scope(op_name: str) -> str:
     return "other"
 
 
-def paged_attn_decode(p, x, k_pool, v_pool, cfg: ArchConfig, *, block_tables,
-                      lengths):
-    """One-token attention against one layer's paged KV pool.
+def paged_attn_decode(p, x, k_pools, v_pools, layer, cfg: ArchConfig, *,
+                      block_tables, lengths):
+    """One-token attention against layer `layer` of the paged KV pools.
 
-    x: [B, 1, d]; k_pool/v_pool: [NB, blk, KH, D]; lengths: [B] int32 tokens
-    already stored per request (the new KV row is written at that position).
-    Returns (attn_out, k_pool, v_pool).
+    x: [B, 1, d]; k_pools/v_pools: [L, NB, blk, KH*D]; lengths: [B] int32
+    tokens already stored per request (the new KV row is written at that
+    position). Returns (attn_out, k_pools, v_pools).
     """
     with jax.named_scope("attention"):
         with jax.named_scope("qkv"):
@@ -396,47 +397,49 @@ def paged_attn_decode(p, x, k_pool, v_pool, cfg: ArchConfig, *, block_tables,
             q = apply_rope(q, posv, cfg.rope_theta)
             k = apply_rope(k, posv, cfg.rope_theta)
         with jax.named_scope("kv_append"):
-            k_pool, v_pool = paged_cache_append(k_pool, v_pool, block_tables,
-                                                lengths, k, v)
+            k_pools, v_pools = paged_cache_append(
+                k_pools, v_pools, layer, block_tables, lengths, k, v)
         with jax.named_scope("attend"):
-            o = paged_decode_attention(q, k_pool, v_pool, block_tables,
-                                       lengths + 1)
+            o = paged_decode_attention(q, k_pools, v_pools, layer,
+                                       block_tables, lengths + 1)
         with jax.named_scope("out"):
             o = _attn_out(p, o, cfg)
-    return o, k_pool, v_pool
+    return o, k_pools, v_pools
 
 
-def paged_block_decode(p, x, k_pool, v_pool, cfg: ArchConfig, ctx: ShardingCtx,
-                       *, block_tables, lengths):
-    """One layer, one token per request, paged KV. Returns (x, k_pool, v_pool)."""
-    attn_o, k_pool, v_pool = paged_attn_decode(
-        p["attn"], x, k_pool, v_pool, cfg,
+def paged_block_decode(p, x, k_pools, v_pools, layer, cfg: ArchConfig,
+                       ctx: ShardingCtx, *, block_tables, lengths):
+    """Layer `layer`, one token per request, paged KV. Returns
+    (x, k_pools, v_pools)."""
+    attn_o, k_pools, v_pools = paged_attn_decode(
+        p["attn"], x, k_pools, v_pools, layer, cfg,
         block_tables=block_tables, lengths=lengths)
     x = _paged_ffn(p, x + attn_o, cfg, ctx)
-    return x, k_pool, v_pool
+    return x, k_pools, v_pools
 
 
 def run_layers_decode_paged(layers, k_pools, v_pools, x, cfg: ArchConfig,
                             ctx: ShardingCtx, *, block_tables, lengths):
-    """All layers over per-layer pools [L, NB, blk, KH, D]. Returns
+    """All layers over the stacked pools [L, NB, blk, KH*D]. Returns
     (x, k_pools, v_pools)."""
 
-    def block_fn(lp, x, kp, vp):
-        return paged_block_decode(lp, x, kp, vp, cfg, ctx,
+    def block_fn(lp, x, kp, vp, layer):
+        return paged_block_decode(lp, x, kp, vp, layer, cfg, ctx,
                                   block_tables=block_tables, lengths=lengths)
 
     return _run_layers_paged(layers, k_pools, v_pools, x, cfg, block_fn)
 
 
-def paged_attn_prefill_chunk(p, x, k_pool, v_pool, cfg: ArchConfig, *,
-                             block_tables, start, n_valid):
-    """Chunk attention against one layer's paged KV pool (chunked prefill).
+def paged_attn_prefill_chunk(p, x, k_pools, v_pools, layer, cfg: ArchConfig,
+                             *, block_tables, start, n_valid):
+    """Chunk attention against layer `layer` of the paged KV pools
+    [L, NB, blk, KH*D] (chunked prefill).
 
     x: [1, C, d] — a chunk of prompt hidden states at absolute positions
     ``start + i``; the chunk's KV rows are appended into the pool first
     (padding rows masked to the garbage page), then every query attends
     causally over all pool positions <= its own. Returns
-    (attn_out, k_pool, v_pool).
+    (attn_out, k_pools, v_pools).
     """
     with jax.named_scope("attention"):
         with jax.named_scope("qkv"):
@@ -447,13 +450,14 @@ def paged_attn_prefill_chunk(p, x, k_pool, v_pool, cfg: ArchConfig, *,
             q = apply_rope(q, pos, cfg.rope_theta)
             k = apply_rope(k, pos, cfg.rope_theta)
         with jax.named_scope("kv_append"):
-            k_pool, v_pool = paged_cache_append_chunk(
-                k_pool, v_pool, block_tables, start, k, v, n_valid)
+            k_pools, v_pools = paged_cache_append_chunk(
+                k_pools, v_pools, layer, block_tables, start, k, v, n_valid)
         with jax.named_scope("attend"):
-            o = paged_prefill_attention(q, k_pool, v_pool, block_tables, pos)
+            o = paged_prefill_attention(q, k_pools, v_pools, layer,
+                                        block_tables, pos)
         with jax.named_scope("out"):
             o = _attn_out(p, o, cfg)
-    return o, k_pool, v_pool
+    return o, k_pools, v_pools
 
 
 @jax.named_scope("ffn")
@@ -471,36 +475,37 @@ def _paged_ffn(p, x, cfg: ArchConfig, ctx: ShardingCtx):
     return x
 
 
-def paged_block_prefill_chunk(p, x, k_pool, v_pool, cfg: ArchConfig,
+def paged_block_prefill_chunk(p, x, k_pools, v_pools, layer, cfg: ArchConfig,
                               ctx: ShardingCtx, *, block_tables, start, n_valid):
-    """One layer, one prefill chunk, paged KV. Returns (x, k_pool, v_pool)."""
-    attn_o, k_pool, v_pool = paged_attn_prefill_chunk(
-        p["attn"], x, k_pool, v_pool, cfg,
+    """Layer `layer`, one prefill chunk, paged KV. Returns
+    (x, k_pools, v_pools)."""
+    attn_o, k_pools, v_pools = paged_attn_prefill_chunk(
+        p["attn"], x, k_pools, v_pools, layer, cfg,
         block_tables=block_tables, start=start, n_valid=n_valid)
     x = _paged_ffn(p, x + attn_o, cfg, ctx)
-    return x, k_pool, v_pool
+    return x, k_pools, v_pools
 
 
 def _run_layers_paged(layers, k_pools, v_pools, x, cfg: ArchConfig, block_fn):
-    """Scan (or unroll) `block_fn` over layers + per-layer pools."""
-
-    def body(x, inp):
-        lp, kp, vp = inp
-        y, kp, vp = block_fn(lp, x, kp, vp)
-        return y, (kp, vp)
-
+    """Scan (or unroll) `block_fn(lp, x, k_pools, v_pools, layer)` over the
+    layers. The whole pool stacks ride in the carry and each layer writes
+    and reads its own index of them, so the donated pools are updated in
+    place: no layer's pool is sliced out or stacked back."""
+    n = jax.tree.leaves(layers)[0].shape[0]
     if cfg.scan_layers:
-        x, (k_pools, v_pools) = jax.lax.scan(body, x, (layers, k_pools, v_pools))
+        def body(carry, inp):
+            lp, layer = inp
+            return block_fn(lp, *carry, layer), None
+
+        (x, k_pools, v_pools), _ = jax.lax.scan(
+            body, (x, k_pools, v_pools),
+            (layers, jnp.arange(n, dtype=jnp.int32)))
         return x, k_pools, v_pools
 
-    n = jax.tree.leaves(layers)[0].shape[0]
-    kps, vps = [], []
     for i in range(n):
         lp = jax.tree.map(lambda a: a[i], layers)
-        x, (kp, vp) = body(x, (lp, k_pools[i], v_pools[i]))
-        kps.append(kp)
-        vps.append(vp)
-    return x, jnp.stack(kps), jnp.stack(vps)
+        x, k_pools, v_pools = block_fn(lp, x, k_pools, v_pools, i)
+    return x, k_pools, v_pools
 
 
 def prefill_chunk_paged(params, k_pools, v_pools, block_tables, start, batch,
@@ -523,8 +528,8 @@ def prefill_chunk_paged(params, k_pools, v_pools, block_tables, start, batch,
     with jax.named_scope("embed"):
         x = embed_lookup(params["embed"], batch["tokens"]).astype(dt)
 
-    def block_fn(lp, x, kp, vp):
-        return paged_block_prefill_chunk(lp, x, kp, vp, cfg, ctx,
+    def block_fn(lp, x, kp, vp, layer):
+        return paged_block_prefill_chunk(lp, x, kp, vp, layer, cfg, ctx,
                                          block_tables=block_tables,
                                          start=start, n_valid=n_valid)
 
@@ -547,9 +552,11 @@ def decode_step_paged(params, k_pools, v_pools, block_tables, lengths, batch,
                       cfg: ArchConfig, ctx: ShardingCtx = NULL_CTX):
     """One decode step for a round of ragged requests over paged KV pools.
 
-    batch["tokens"]: [B, 1]; lengths: [B] int32 — each request's stored token
-    count (its new KV row is written there, then it attends to lengths+1
-    positions). Returns (logits [B, 1, V], k_pools, v_pools).
+    k_pools/v_pools: [L, NB, blk, KH*D] (`serve.engine.kv_pool_shape`),
+    updated in place when donated. batch["tokens"]: [B, 1]; lengths: [B]
+    int32 — each request's stored token count (its new KV row is written
+    there, then it attends to lengths+1 positions). Returns
+    (logits [B, 1, V], k_pools, v_pools).
     """
     if not supports_paged_decode(cfg):
         raise ValueError(f"paged decode unsupported for family {cfg.family!r} "

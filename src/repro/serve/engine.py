@@ -80,7 +80,7 @@ from __future__ import annotations
 
 import re
 import time
-from typing import Any, Callable, Dict, FrozenSet, List, Optional
+from typing import Any, Callable, Dict, FrozenSet, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -123,6 +123,16 @@ def decode_round_fn(model):
                 k_pools, v_pools)
 
     return paged_decode_round
+
+
+def kv_pool_shape(cfg: ArchConfig, physical_blocks: int,
+                  block_size: int) -> Tuple[int, int, int, int]:
+    """Shape of each stacked KV pool: [n_layers, physical_blocks,
+    block_size, kv_heads * head_dim]. Every layer's blocks sit in one
+    array, heads folded into the lane axis, so the step programs update
+    the donated pools in place (`models.lm._run_layers_paged`)."""
+    return (cfg.n_layers, int(physical_blocks), int(block_size),
+            cfg.kv_heads * cfg.resolved_head_dim)
 
 
 _HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([^\s=%]+)\s+=\s")
@@ -204,7 +214,7 @@ class PagedServingEngine:
         self.tracer = obs_trace.get_tracer()
         self.prefiller = ChunkedPrefiller(self.model, block_size, self.tracer)
 
-        shape = (cfg.n_layers, self.pager.physical_blocks, block_size, kh, hd)
+        shape = kv_pool_shape(cfg, self.pager.physical_blocks, block_size)
         self.k_pools = jnp.zeros(shape, jnp.dtype(cfg.dtype))
         self.v_pools = jnp.zeros(shape, jnp.dtype(cfg.dtype))
 

@@ -10,6 +10,7 @@ from repro.kernels.decode_attention.ops import paged_decode_attention
 from repro.kernels.decode_attention.ref import paged_decode_attention_ref
 from repro.models import build_model
 from repro.models import common as mc
+from repro.models import lm
 from repro.serve import (
     ContinuousBatchingScheduler,
     KVPager,
@@ -296,10 +297,19 @@ def test_paged_decode_matches_dense_on_ragged_lengths(rng):
                                rtol=2e-5, atol=2e-5)
 
 
+def _as_stacked_layer(rng, pool):
+    """One layer's [NB, blk, KH, D] pool as layer 1 of a lane-folded
+    [2, NB, blk, KH*D] stack whose layer 0 is noise."""
+    folded = pool.reshape(*pool.shape[:2], -1)
+    noise = jnp.asarray(rng.randn(*folded.shape), folded.dtype)
+    return jnp.stack([noise, folded])
+
+
 def test_paged_jnp_twin_matches_dense_on_ragged_lengths(rng):
     lengths = [4, 27, 64]
     q, kp, vp, bt = _paged_problem(rng, lengths, h=4, kh=4, d=8, blk=8)
-    out = mc.paged_decode_attention(q[:, None], kp, vp, bt,
+    out = mc.paged_decode_attention(q[:, None], _as_stacked_layer(rng, kp),
+                                    _as_stacked_layer(rng, vp), 1, bt,
                                     jnp.asarray(lengths, jnp.int32))[:, 0]
     ref = _dense_ref_rows(q, kp, vp, bt, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -336,6 +346,129 @@ def test_paged_decode_ragged_sweep(kh, h, blk, seed):
     ref = _dense_ref_rows(q, kp, vp, bt, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------------- stacked pools, layer by layer
+
+
+def _per_layer_reference(params, cfg, k_pools, v_pools, tables, tokens, pos,
+                         valid):
+    """The paged step one layer at a time over unstacked [NB, blk, KH, D]
+    pools: slice layer l out, append each row with a Python loop (rows not
+    `valid` to the garbage page 0), attend each row over its gathered pages
+    with a dense masked softmax, write layer l back. tokens/pos/valid:
+    [B, C]. Returns (hidden states after the last layer [B, C, d], k_pools,
+    v_pools), both pools [L, NB, blk, KH, D]."""
+    blk, kh, d = k_pools.shape[2], k_pools.shape[3], k_pools.shape[4]
+    g = cfg.n_heads // kh
+    x = mc.embed_lookup(params["embed"], jnp.asarray(tokens)).astype(
+        jnp.dtype(cfg.dtype))
+    posj = jnp.asarray(pos)
+    for layer in range(cfg.n_layers):
+        lp = jax.tree.map(lambda a: a[layer], params["layers"])
+        kp, vp = k_pools[layer], v_pools[layer]
+        p = lp["attn"]
+        h = mc.rms_norm(x, p["ln"], cfg.norm_eps)
+        q, k, v = lm._project_qkv(p, h, cfg)
+        q = mc.apply_rope(q, posj, cfg.rope_theta)
+        k = mc.apply_rope(k, posj, cfg.rope_theta)
+        for b in range(tokens.shape[0]):
+            for i in range(tokens.shape[1]):
+                bid = tables[b, pos[b, i] // blk] if valid[b, i] else 0
+                kp = kp.at[bid, pos[b, i] % blk].set(k[b, i])
+                vp = vp.at[bid, pos[b, i] % blk].set(v[b, i])
+        rows = []
+        for b in range(tokens.shape[0]):
+            keys = jnp.repeat(kp[tables[b]].reshape(-1, kh, d), g, axis=1)
+            vals = jnp.repeat(vp[tables[b]].reshape(-1, kh, d), g, axis=1)
+            sc = jnp.einsum("ihd,shd->ihs", q[b], keys) * (d ** -0.5)
+            seen = np.arange(keys.shape[0])[None, None, :] <= pos[b][:, None,
+                                                                     None]
+            w = jax.nn.softmax(jnp.where(seen, sc, mc.NEG_INF), axis=-1)
+            rows.append(jnp.einsum("ihs,shd->ihd", w, vals))
+        x = lm._paged_ffn(lp, x + lm._attn_out(p, jnp.stack(rows), cfg), cfg,
+                          NULL_CTX)
+        k_pools = k_pools.at[layer].set(kp)
+        v_pools = v_pools.at[layer].set(vp)
+    return x, k_pools, v_pools
+
+
+def _stacked_problem(arch, scan_layers, seed):
+    cfg = get_config(arch).reduced().replace(
+        dtype="float32", param_dtype="float32", scan_layers=scan_layers)
+    params = build_model(cfg).init(jax.random.PRNGKey(seed))
+    rng = np.random.RandomState(seed)
+    shape = (cfg.n_layers, 12, 4, cfg.kv_heads, cfg.resolved_head_dim)
+    k5 = jnp.asarray(rng.randn(*shape), jnp.float32)
+    v5 = jnp.asarray(rng.randn(*shape), jnp.float32)
+    return cfg, params, rng, k5, v5
+
+
+def _folded(pools):
+    return pools.reshape(*pools.shape[:3], -1)
+
+
+def _assert_pools_match(got, want, tol):
+    """Every page but the garbage page 0, where padding rows collide."""
+    np.testing.assert_allclose(np.asarray(got)[:, 1:],
+                               np.asarray(_folded(want))[:, 1:], **tol)
+
+
+_PARITY_TOL = dict(rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("scan_layers", [True, False], ids=["scan", "unroll"])
+@pytest.mark.parametrize("arch", ["granite-moe-1b-a400m", "yi-6b"])
+def test_stacked_decode_matches_per_layer_reference(arch, scan_layers):
+    """A ragged decode round through the stacked, lane-folded pools updated
+    in place gives the per-layer reference's logits and pools; two padding
+    rows (length 0, table all page 0) write only the garbage page."""
+    cfg, params, rng, k5, v5 = _stacked_problem(arch, scan_layers, 3)
+    tables = np.array([[3, 7, 1], [5, 0, 0], [0, 0, 0], [9, 2, 0],
+                       [0, 0, 0]], np.int32)
+    lengths = np.array([9, 4, 0, 6, 0], np.int32)
+    tokens = rng.randint(1, cfg.vocab, size=(5, 1)).astype(np.int32)
+    real = lengths > 0
+    logits, kp, vp = lm.decode_step_paged(
+        params, _folded(k5), _folded(v5), jnp.asarray(tables),
+        jnp.asarray(lengths), {"tokens": jnp.asarray(tokens)}, cfg)
+    x, k_ref, v_ref = _per_layer_reference(
+        params, cfg, k5, v5, tables, tokens, lengths[:, None],
+        np.ones((5, 1), bool))
+    x = mc.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    want = lm._unembed(params, x, cfg, NULL_CTX)
+    np.testing.assert_allclose(np.asarray(logits)[real],
+                               np.asarray(want)[real], **_PARITY_TOL)
+    _assert_pools_match(kp, k_ref, _PARITY_TOL)
+    _assert_pools_match(vp, v_ref, _PARITY_TOL)
+    assert not np.array_equal(np.asarray(kp)[:, 0], np.asarray(_folded(k5))[:, 0])
+
+
+@pytest.mark.parametrize("scan_layers", [True, False], ids=["scan", "unroll"])
+@pytest.mark.parametrize("arch", ["granite-moe-1b-a400m", "yi-6b"])
+def test_stacked_prefill_chunk_matches_per_layer_reference(arch, scan_layers):
+    """A prefill chunk of 5 real tokens padded to 8, from position 6 across
+    the boundary of blocks of 4, through the stacked pools: the per-layer
+    reference's last logits and pools; its 3 padding rows write page 0."""
+    cfg, params, rng, k5, v5 = _stacked_problem(arch, scan_layers, 4)
+    table = np.array([[6, 10, 3, 0]], np.int32)
+    start, n_valid, chunk = 6, 5, 8
+    tokens = rng.randint(1, cfg.vocab, size=(1, chunk)).astype(np.int32)
+    logits, kp, vp = lm.prefill_chunk_paged(
+        params, _folded(k5), _folded(v5), jnp.asarray(table),
+        jnp.int32(start), {"tokens": jnp.asarray(tokens)}, jnp.int32(n_valid),
+        cfg)
+    pos = start + np.arange(chunk)[None]
+    x, k_ref, v_ref = _per_layer_reference(
+        params, cfg, k5, v5, table, tokens, pos, np.arange(chunk)[None] < n_valid)
+    last = mc.rms_norm(x[:, n_valid - 1:n_valid], params["final_norm"],
+                       cfg.norm_eps)
+    want = lm._unembed(params, last, cfg, NULL_CTX)[:, 0]
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
+                               **_PARITY_TOL)
+    _assert_pools_match(kp, k_ref, _PARITY_TOL)
+    _assert_pools_match(vp, v_ref, _PARITY_TOL)
+    assert not np.array_equal(np.asarray(kp)[:, 0], np.asarray(_folded(k5))[:, 0])
 
 
 # ------------------------------------------------------------------ engine

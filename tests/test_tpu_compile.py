@@ -5,7 +5,8 @@ compiled by the TPU compiler against a described ``v5e:2x2`` topology, at
 the widths it serves: the decode kernels at head_dim 64 (granite-3-2b) and
 128 (yi-6b), ``ssd_scan`` at mamba2-130m, ``moe_gmm`` at
 granite-moe-1b-a400m and ``stream_triad`` at its solved depth. The
-granite-3-2b paged decode step is compiled whole. Nothing runs, so these
+granite-3-2b paged decode step is compiled whole, and granite-moe-1b-a400m's
+decode and prefill programs at the benchmark's pool size. Nothing runs, so these
 tests say nothing about results or speed; they catch what Mosaic or XLA
 would refuse on the chip (misaligned tiles, missing lowerings, VMEM or HBM
 overcommit).
@@ -14,6 +15,7 @@ The topology is described inside a fixture: only one process may load the
 TPU library at a time, so it must never happen while modules are imported.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -125,18 +127,80 @@ def test_granite_3_2b_paged_decode_step_fits_one_chip(one_chip, shape):
     from repro.configs import get_config
     from repro.models import build_model
     from repro.runtime.layouts import serving_config_overrides
-    from repro.serve.engine import decode_round_fn
+    from repro.serve.engine import decode_round_fn, kv_pool_shape
 
     cfg = get_config("granite-3-2b").replace(**serving_config_overrides())
     model = build_model(cfg)
     params = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
         model.abstract_params())
-    pool = shape((cfg.n_layers, 1025, 16, cfg.kv_heads, cfg.resolved_head_dim),
-                 jnp.dtype(cfg.dtype))
+    pool = shape(kv_pool_shape(cfg, 1025, 16), jnp.dtype(cfg.dtype))
     compiled = jax.jit(decode_round_fn(model), donate_argnums=(1, 2)).lower(
         params, pool, pool, shape((8, 1), jnp.int32),
         shape((8, 64), jnp.int32), shape((8,), jnp.int32)).compile()
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 0 < total < HBM_BYTES
+
+
+_HLO_RESULT = re.compile(r"^\s*(?:ROOT\s+)?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+_POOL_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
+
+
+def _pool_sized_moves(hlo_text: str, layer_elems: int):
+    """Instructions that copy or slice at least one layer's pool: a `copy`,
+    `dynamic-slice` or `dynamic-update-slice` (or a fusion named for one)
+    whose result holds ``layer_elems`` elements or more."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = _HLO_RESULT.match(line)
+        if not m:
+            continue
+        name, dims, opcode = m.groups()
+        n = 1
+        for dim in filter(None, dims.split(",")):
+            n *= int(dim)
+        if n >= layer_elems and (opcode in _POOL_MOVES or any(
+                f"{op}_fusion" in name for op in _POOL_MOVES[1:])):
+            out.append(line.strip()[:160])
+    return out
+
+
+@pytest.mark.parametrize("program", ["paged_decode_round",
+                                     "paged_prefill_chunk"])
+def test_granite_moe_step_programs_update_the_pools_in_place(one_chip, shape,
+                                                             program):
+    """granite-moe-1b-a400m's step programs at the benchmark's sizes (7000
+    blocks of 16 and the garbage page; a decode round of 20 rows or a
+    prefill chunk of 32, table width 192) update the donated pools in
+    place: both pools alias outputs, temporaries stay under 1 GB, and no
+    instruction copies or slices a pool-sized array."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.runtime.layouts import serving_config_overrides
+    from repro.serve.engine import decode_round_fn, kv_pool_shape
+    from repro.serve.prefill import ChunkedPrefiller
+
+    cfg = get_config("granite-moe-1b-a400m").replace(
+        dtype="bfloat16", **serving_config_overrides())
+    model = build_model(cfg)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        model.abstract_params())
+    dims = kv_pool_shape(cfg, 7001, 16)
+    pool = shape(dims, jnp.bfloat16)
+    i32 = jnp.int32
+    if program == "paged_decode_round":
+        fn = jax.jit(decode_round_fn(model), donate_argnums=(1, 2))
+        args = (shape((20, 1), i32), shape((20, 192), i32), shape((20,), i32))
+    else:
+        fn = ChunkedPrefiller(model, 16, tracer=None)._fn(32, 192)
+        args = (shape((1, 32), i32), shape((1, 192), i32), shape((), i32),
+                shape((), i32))
+    compiled = fn.lower(params, pool, pool, *args).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = pool.size * pool.dtype.itemsize
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < 10**9
+    layer_elems = pool.size // dims[0]
+    assert _pool_sized_moves(compiled.as_text(), layer_elems) == []
