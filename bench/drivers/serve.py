@@ -1,8 +1,9 @@
 """Window driver of the serving cells.
 
 It builds the program's paged server (`repro.serve.PagedServingEngine`)
-from a configuration file, with weights the benchmark draws from the seed,
-and drives it through `submit` and `step_round` only:
+from a configuration file, with weights the benchmark draws from the seed
+as the configuration's block family declares them, and drives it through
+`submit` and `step_round` only:
 
 1. set-up: weights and engine, then warm-up requests that compile every
    program the window runs (both prefill chunk buckets, the decode round,
@@ -72,26 +73,29 @@ def program_config(cfg: dict):
     holds the configuration as it is run."""
     from repro.configs import get_config
 
-    m = W.dims(cfg)
-    moe = dict(moe=True, n_experts=m["E"], top_k=m["k"], d_ff_expert=m["f"]) \
-        if m["E"] else {}
+    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
+    f = cfg["intermediate_size"]
+    moe = dict(moe=True, n_experts=cfg["num_local_experts"],
+               top_k=cfg["num_experts_per_tok"], d_ff_expert=f) \
+        if cfg.get("num_local_experts") else {}
     return get_config(cfg["program_arch"]).replace(
-        n_layers=m["L"], d_model=m["d"], n_heads=m["h"], kv_heads=m["kh"],
-        head_dim=m["hd"], d_ff=m["f"], vocab=m["V"], norm_eps=m["eps"],
-        rope_theta=m["theta"], tie_embeddings=True, dtype="bfloat16",
-        param_dtype="bfloat16", cache_update="row", **moe)
+        n_layers=cfg["num_hidden_layers"], d_model=d, n_heads=h,
+        kv_heads=cfg["num_key_value_heads"], head_dim=cfg.get("head_dim") or d // h,
+        d_ff=f, vocab=cfg["vocab_size"], norm_eps=cfg["rms_norm_eps"],
+        rope_theta=cfg["rope_theta"], tie_embeddings=cfg["tie_word_embeddings"],
+        dtype="bfloat16", param_dtype="bfloat16", cache_update="row", **moe)
 
 
-def program_params(canon: Dict, model):
+def program_params(spec: W.Leaves, canon: Dict, model):
     """The benchmark's weights in the program's parameter tree, checked
-    leaf by leaf against the tree the program declares."""
+    leaf by leaf against the tree the program declares: a global leaf at
+    the top, a per-layer ``group.leaf`` under ``layers``."""
     import jax
 
-    tree = {"embed": canon["embed"], "final_norm": canon["final_norm"],
-            "layers": {}}
-    for name, arr in canon.items():
-        if name in tree:
-            continue
+    tree = {name: canon[name] for name in spec.glob}
+    tree["layers"] = {}
+    for name in spec.per_layer:
+        arr = canon[name]
         group, _, leaf = name.partition(".")
         if leaf:
             tree["layers"].setdefault(group, {})[leaf] = arr
@@ -108,9 +112,11 @@ def program_params(canon: Dict, model):
 
 
 class ServeDriver:
-    def __init__(self, cfg: dict, mix: dict, seed: int, seconds: float, *,
+    def __init__(self, cfg: dict, family, mix: dict, seed: int, seconds: float, *,
                  trace_s: float = 0.0):
-        self.cfg, self.mix, self.seed = cfg, mix, int(seed)
+        """`family` is the configuration's block family module
+        (`bench.run.load_family`), which declares the weights' leaves."""
+        self.cfg, self.family, self.mix, self.seed = cfg, family, mix, int(seed)
         self.seconds = float(seconds)
         self.trace_s = float(trace_s)
         self.cap = mix["context_cap"]
@@ -136,7 +142,8 @@ class ServeDriver:
         guard.set_strict(True)
         serve = self.cfg["serve"]
         pcfg = program_config(self.cfg)
-        params = program_params(W.make_params(self.cfg, self.seed),
+        spec = self.family.leaves(self.cfg)
+        params = program_params(spec, W.make_params(spec, self.seed),
                                 build_model(pcfg))
         jax.block_until_ready(params)
         self.engine = PagedServingEngine(
@@ -144,7 +151,7 @@ class ServeDriver:
             num_blocks=serve["num_blocks"], params=params,
             on_token=self._on_token, on_finish=self._on_finish)
         self.memory_after_build = jax.devices()[0].memory_stats() or {}
-        self.traffic = T.build(self.mix, self.seed, W.dims(self.cfg)["V"],
+        self.traffic = T.build(self.mix, self.seed, self.cfg["vocab_size"],
                                self._horizon())
 
     def _horizon(self) -> float:
@@ -304,7 +311,7 @@ class ServeDriver:
     def warm_up(self) -> None:
         """Compile every program the window runs, outside the window."""
         rng = np.random.default_rng([self.seed, 1])
-        V = W.dims(self.cfg)["V"]
+        V = self.cfg["vocab_size"]
         base = rng.integers(0, V, 56).tolist()
         prompts = [base[:40],                 # chunks of 32 and of 8 tokens
                    base,                      # 32 and 24

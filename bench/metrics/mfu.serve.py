@@ -14,7 +14,7 @@ def read(run):
     wall = sum(r.end - r.start for r in rounds)
     if not wall:
         return None
-    flops = sum(work.decode_flops(run.cfg, r.decode_ctx) for r in rounds)
-    flops += sum(work.prefill_flops(run.cfg, s.args["start"], s.args["n"])
+    flops = sum(work.decode_flops(run.sizes, r.decode_ctx) for r in rounds)
+    flops += sum(work.prefill_flops(run.sizes, s.args["start"], s.args["n"])
                  for s in run.spans if s.name == "prefill_chunk")
     return 100.0 * flops / (wall * run.peaks["bf16_flops_per_s"])

@@ -19,7 +19,7 @@ def read(run):
         ctx = run.driver.rounds[n].decode_ctx
         if not ctx or ns <= 0:
             continue
-        need += work.least_seconds(work.decode_flops(run.cfg, ctx),
-                                   work.decode_bytes(run.cfg, ctx), run.peaks)
+        need += work.least_seconds(work.decode_flops(run.sizes, ctx),
+                                   work.decode_bytes(run.sizes, ctx), run.peaks)
         dev += ns / 1e9
     return 100.0 * need / dev if dev else None

@@ -1,12 +1,19 @@
-"""Plain float32 reference of the granite blocks the benchmark serves.
+"""The granite block family: its plain float32 reference, the leaves its
+weights are drawn as, and the sizes its work is counted from.
+
+A configuration file names its family (`"reference": "granite"`), and the
+benchmark loads `bench/reference/<family>.py` by that name. A family module
+gives `leaves` (for `bench/weights.py`), `sizes` (for `bench/work.py`) and
+`logits` (for `bench/check.py`).
 
 The equations of granite-3.0 (dense) and granite-3.0 MoE, written out in
 `jax.numpy` under `default_matmul_precision("highest")`, with no cache, no
 paging and no batching across requests: RMSNorm, rotary position
 embeddings (rotate-half), grouped-query attention with a causal mask, a
 SwiGLU feed-forward or a top-k softmax router over SwiGLU experts, and the
-tied output head. It imports nothing of the program; the weights come from
-`bench/weights.py`, drawn again from the seed.
+output head tied to the embedding, as both published files tie it. It
+imports nothing of the program; the weights come from `bench/weights.py`,
+drawn again from the seed.
 
 Departures from the published models, which the served program shares:
 granite's scalar multipliers (embedding 12, attention 1/64, residual 0.22,
@@ -26,6 +33,7 @@ so a 4096-token sequence of granite-3-2b fits beside nothing else on one
 from __future__ import annotations
 
 import functools
+import math
 from typing import List, Optional, Sequence
 
 import jax
@@ -33,10 +41,85 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import weights as W
+from bench import work
 
 Q_BLOCK = 512
 HEAD_ROWS = 256
 FP8_MAX = 448.0
+EMBED_STD = 0.02
+
+
+def dims(cfg: dict) -> dict:
+    """The sizes a granite block needs, read from a configuration file."""
+    d = cfg["hidden_size"]
+    h = cfg["num_attention_heads"]
+    return dict(
+        d=d, h=h, kh=cfg["num_key_value_heads"], hd=cfg.get("head_dim") or d // h,
+        L=cfg["num_hidden_layers"], V=cfg["vocab_size"],
+        f=cfg["intermediate_size"], E=cfg.get("num_local_experts", 0),
+        k=cfg.get("num_experts_per_tok", 0), eps=cfg["rms_norm_eps"],
+        theta=cfg["rope_theta"])
+
+
+def _fan_in(name: str, shape) -> int:
+    # the contracted dimensions of each projection
+    if name in ("attn.wo",):
+        return shape[0] * shape[1]
+    if name.startswith("moe.w_"):
+        return shape[1]
+    return shape[0]
+
+
+def leaves(cfg: dict) -> W.Leaves:
+    """Every leaf of the served model: per layer, attention and a SwiGLU
+    feed-forward or a router over SwiGLU experts; the embedding (which is
+    also the head) and the final norm."""
+    m = dims(cfg)
+    d, h, kh, hd, f, E = m["d"], m["h"], m["kh"], m["hd"], m["f"], m["E"]
+    s = {
+        "attn.ln": ((d,), "gain"),
+        "attn.wq": ((d, h, hd), "fan_in"),
+        "attn.wk": ((d, kh, hd), "fan_in"),
+        "attn.wv": ((d, kh, hd), "fan_in"),
+        "attn.wo": ((h, hd, d), "fan_in"),
+    }
+    if E:
+        s.update({
+            "moe_ln": ((d,), "gain"),
+            "moe.router": ((d, E), "fan_in"),
+            "moe.w_gate": ((E, d, f), "fan_in"),
+            "moe.w_up": ((E, d, f), "fan_in"),
+            "moe.w_down": ((E, f, d), "fan_in"),
+        })
+    else:
+        s.update({
+            "mlp.ln": ((d,), "gain"),
+            "mlp.w_gate": ((d, f), "fan_in"),
+            "mlp.w_up": ((d, f), "fan_in"),
+            "mlp.w_down": ((f, d), "fan_in"),
+        })
+    per_layer = {name: (shape, init, 1.0 / math.sqrt(_fan_in(name, shape)))
+                 for name, (shape, init) in s.items()}
+    return W.Leaves(m["L"], per_layer, {
+        "embed": (1000, (m["V"], d), "fan_in", EMBED_STD),
+        "final_norm": (1001, (d,), "gain", 0.0)})
+
+
+def sizes(cfg: dict) -> work.Sizes:
+    """What `bench/work.py` counts: every layer alike, the head tied."""
+    m = dims(cfg)
+    d, h, kh, hd, f, E, k = m["d"], m["h"], m["kh"], m["hd"], m["f"], m["E"], m["k"]
+    attn = d * h * hd + 2 * d * kh * hd + h * hd * d + d
+    kv = 2 * kh * hd * work.BF16
+    if E:
+        params = attn + d + d * E + E * 3 * d * f
+        layer = work.Layer(params, params - (E - k) * 3 * d * f, kv, 4 * h * hd,
+                           experts=E, top_k=k, expert_params=3 * d * f)
+    else:
+        params = attn + d + 3 * d * f
+        layer = work.Layer(params, params, kv, 4 * h * hd)
+    return work.Sizes((layer,) * m["L"], head_params=m["V"] * d + d,
+                      unembed_params=m["V"] * d)
 
 
 def _q(x, quant: Optional[str]):
@@ -140,20 +223,25 @@ def _layer(p, x, *, m, quant):
 
 
 @functools.partial(jax.jit, static_argnames=("m", "quant"))
-def _head(g, x, *, m, quant):
+def tied_head(g, x, *, m, quant):
+    """The output head: the final norm, then the embedding's rows."""
     hn = rms_norm(x, g["final_norm"], dict(m)["eps"])
     return _q(hn, quant) @ _wq(g["embed"], quant, (1,)).T
 
 
-def logits(cfg: dict, seed: int, seqs: Sequence[Sequence[int]],
+def hidden(cfg: dict, spec: W.Leaves, seed: int, seqs: Sequence[Sequence[int]],
            positions: Sequence[Sequence[int]], *, pad_to: int,
-           quant: Optional[str] = None) -> List[np.ndarray]:
-    """Logits [len(positions[i]), vocab] of each sequence at the given
-    positions (the next-token distribution after each), from a full causal
-    forward pass. Sequences are right-padded to `pad_to`, a multiple of the
-    query block; padding sits after every position read, so it changes
-    nothing that is returned."""
-    m = W.dims(cfg)
+           quant: Optional[str] = None):
+    """(global leaves, rows, key): granite's blocks run over each sequence,
+    and the last block's output at every position read, in order, padded
+    to a multiple of `HEAD_ROWS` rows; `key` is the static form of `dims`
+    that a jitted head takes as `m`. Sequences are right-padded to
+    `pad_to`, a multiple of the query block; padding sits after every
+    position read, so it changes nothing that is returned. Call it under
+    `default_matmul_precision("highest")`, as `logits` does. A family with
+    granite's block and leaves of its own (`spec`) puts its head on these
+    rows, and `split` cuts them back into sequences."""
+    m = dims(cfg)
     if pad_to % Q_BLOCK:
         pad_to = -(-pad_to // Q_BLOCK) * Q_BLOCK
     toks = np.zeros((len(seqs), pad_to), np.int32)
@@ -162,24 +250,39 @@ def logits(cfg: dict, seed: int, seqs: Sequence[Sequence[int]],
             raise ValueError(f"sequence of {len(s)} tokens > pad_to {pad_to}")
         toks[i, :len(s)] = s
     key = tuple(sorted(m.items()))
-    with jax.default_matmul_precision("highest"):
-        g = W.global_f32(cfg, seed)
-        x = g["embed"][jnp.asarray(toks)]
-        for layer in range(m["L"]):
-            p = W.layer_f32(cfg, seed, layer)
-            x = _layer(p, x, m=key, quant=quant)
-            del p
-        # one head call over every position read, padded to a fixed count
-        at = [(i, p) for i, pos in enumerate(positions) for p in pos]
-        n = -(-max(len(at), 1) // HEAD_ROWS) * HEAD_ROWS
-        si = np.zeros(n, np.int32)
-        pi = np.zeros(n, np.int32)
-        si[:len(at)] = [i for i, _ in at]
-        pi[:len(at)] = [p for _, p in at]
-        rows = x[jnp.asarray(si), jnp.asarray(pi)]
-        flat = np.asarray(_head(g, rows, m=key, quant=quant))[:len(at)]
+    g = W.global_f32(spec, seed)
+    x = g["embed"][jnp.asarray(toks)]
+    for layer in range(m["L"]):
+        p = W.layer_f32(spec, seed, layer)
+        x = _layer(p, x, m=key, quant=quant)
+        del p
+    # one head call over every position read, padded to a fixed count
+    at = [(i, p) for i, pos in enumerate(positions) for p in pos]
+    n = -(-max(len(at), 1) // HEAD_ROWS) * HEAD_ROWS
+    si = np.zeros(n, np.int32)
+    pi = np.zeros(n, np.int32)
+    si[:len(at)] = [i for i, _ in at]
+    pi[:len(at)] = [p for _, p in at]
+    return g, x[jnp.asarray(si), jnp.asarray(pi)], key
+
+
+def split(flat, positions: Sequence[Sequence[int]]) -> List[np.ndarray]:
+    """The head's rows, cut into one array per sequence (padding dropped)."""
+    flat = np.asarray(flat)
     out, o = [], 0
     for pos in positions:
         out.append(flat[o:o + len(pos)])
         o += len(pos)
     return out
+
+
+def logits(cfg: dict, seed: int, seqs: Sequence[Sequence[int]],
+           positions: Sequence[Sequence[int]], *, pad_to: int,
+           quant: Optional[str] = None) -> List[np.ndarray]:
+    """Logits [len(positions[i]), vocab] of each sequence at the given
+    positions (the next-token distribution after each), from a full causal
+    forward pass (`hidden`) and the tied head."""
+    with jax.default_matmul_precision("highest"):
+        g, rows, key = hidden(cfg, leaves(cfg), seed, seqs, positions,
+                              pad_to=pad_to, quant=quant)
+        return split(tied_head(g, rows, m=key, quant=quant), positions)

@@ -5,9 +5,13 @@
 
 The cell (`BENCHMARK.json` -> `workloads`) names a configuration
 (`bench/configs/<config>.json`) and a traffic mix (`bench/traffic/<mix>.json`);
-the configuration names its driver (`bench/drivers/<driver>.py`), and each
-per-layer metric has a reader (`bench/metrics/<metric>.py`). All are found
-by name, so a new cell, mix, configuration or metric is a new file.
+the configuration names its driver (`bench/drivers/<driver>.py`) and its
+block family (`bench/reference/<reference>.py`), and each per-layer metric
+has a reader (`bench/metrics/<metric>.py`). All are found by name, so a new
+cell, mix, configuration or metric is a new file. A configuration of a
+block the benchmark has not served before needs three: its file, its family
+module (the plain reference's `logits`, the weight `leaves`, the work
+`sizes`), and a mix if none fits.
 
 With ``--trace 0`` the result carries the cell's end-to-end metrics; with
 ``--trace 1`` its per-layer metrics, read from the program's spans and
@@ -55,6 +59,7 @@ class Span:
 class Run:
     """What a per-layer reader reads."""
     cfg: dict
+    sizes: object        # the family's `bench.work.Sizes`
     peaks: dict
     driver: object
     spans: list
@@ -62,11 +67,22 @@ class Run:
 
 
 def load_module(path: Path, name: str):
+    mod = sys.modules.get(name)
+    if mod is not None and getattr(mod, "__file__", None) == str(path):
+        return mod
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_family(cfg: dict, root: Path = ROOT):
+    """The block family the configuration names: its reference, leaves and
+    sizes (`bench/reference/<reference>.py` under `root`)."""
+    name = cfg["reference"]
+    return load_module(root / "bench" / "reference" / f"{name}.py",
+                       f"bench_reference_{name}")
 
 
 def cell_spec(workload: str, root: Path = ROOT):
@@ -124,6 +140,7 @@ def run_cell(args, *, root: Path = ROOT, require_chip: bool = True,
     mix = traffic.load_mix(cell["traffic"], root / "bench" / "traffic")
 
     jax = configure_jax()
+    family = load_family(cfg, root)
     from bench import chip
     if require_chip:
         devices, peaks = chip.check(cell["chips"])
@@ -136,7 +153,7 @@ def run_cell(args, *, root: Path = ROOT, require_chip: bool = True,
     compile_log = CompileLog()
     drivers = load_module(BENCH / "drivers" / f"{cfg['serve']['driver']}.py",
                           f"bench_driver_{cfg['serve']['driver']}")
-    drv = drivers.ServeDriver(cfg, mix, args.seed, args.seconds,
+    drv = drivers.ServeDriver(cfg, family, mix, args.seed, args.seconds,
                               trace_s=TRACE_SECONDS if args.trace else 0.0)
     drv.run(t_process, compile_log)
     mem = drv.memory_after_build
@@ -155,7 +172,7 @@ def run_cell(args, *, root: Path = ROOT, require_chip: bool = True,
     if args.trace:
         from bench import trace_reduce
         spans = window_spans(drv)
-        run = Run(cfg, peaks, drv, spans)
+        run = Run(cfg, family.sizes(cfg), peaks, drv, spans)
         if drv.profile_dir:
             pd = trace_reduce.load(drv.profile_dir)
             for line in trace_reduce.describe(pd)[:24]:
@@ -191,8 +208,10 @@ def run_cell(args, *, root: Path = ROOT, require_chip: bool = True,
 
     drv.free()
     sample = check.sample(drv.finished(), args.seed, cfg["check"]["requests"])
-    comp = check.compare(cfg, args.seed, sample, pad_to=mix["context_cap"])
+    comp = check.compare(family, cfg, args.seed, sample, pad_to=mix["context_cap"])
     correct, checks = check.judge(cfg, comp, failed)
+    shown = {k: comp[k] for k in check.NUMBERS if k not in checks}
+    print(f"[bench] read and not compared: {json.dumps(shown)}", file=sys.stderr)
     for k, v in checks.items():
         rel = ">=" if k == "tokens_compared" else "<="
         print(f"[check] {k} {v['value']} (limit {rel} {v['limit']})", file=sys.stderr)

@@ -193,6 +193,7 @@ def main(argv=None) -> int:
     from bench import traffic
     mix = traffic.load_mix(cell["traffic"], bench_run.BENCH / "traffic")
     bench_run.configure_jax()
+    family = bench_run.load_family(cfg)
     from bench import chip
     from bench.stats import CompileLog
     try:
@@ -203,7 +204,7 @@ def main(argv=None) -> int:
     name = cfg["serve"]["driver"]
     drivers = bench_run.load_module(
         bench_run.BENCH / "drivers" / f"{name}.py", f"bench_driver_{name}")
-    drv = drivers.ServeDriver(cfg, mix, args.seed, args.seconds,
+    drv = drivers.ServeDriver(cfg, family, mix, args.seed, args.seconds,
                               trace_s=bench_run.TRACE_SECONDS)
     drv.run(T_PROCESS, CompileLog())
     from repro.obs import trace as obs_trace
@@ -215,7 +216,7 @@ def main(argv=None) -> int:
     drv._tmp.cleanup()
     out.update({
         "output_tok_s": drv.end_to_end().get("output_tok_s"),
-        "round_host_ms": reader.read(bench_run.Run(cfg, peaks, drv, spans)),
+        "round_host_ms": reader.read(bench_run.Run(cfg, family.sizes(cfg), peaks, drv, spans)),
         "tracer_dropped": obs_trace.get_tracer().dropped,
         "compiles_in_window": [n for _, n, _ in drv.compiles],
     })

@@ -73,7 +73,7 @@ def drive(mix, seconds=0.4, seed=3):
                          "num_attention_heads": 1, "num_key_value_heads": 1,
                          "num_hidden_layers": 1, "intermediate_size": 8,
                          "rms_norm_eps": 1e-5, "rope_theta": 1e4},
-                        mix, seed, seconds)
+                        None, mix, seed, seconds)
 
     def build():
         drv.engine = FakeEngine(drv._on_token, drv._on_finish)
@@ -150,7 +150,7 @@ def test_a_rate_is_all_tokens_over_the_whole_window():
 
 
 def test_a_tail_is_taken_over_every_gap():
-    drv = S.ServeDriver({}, {"context_cap": 10}, 0, 1.0)
+    drv = S.ServeDriver({}, None, {"context_cap": 10}, 0, 1.0)
     drv.open, drv.close, drv.setup_s, drv.drained = 0.0, 10.0, 1.0, 10.0
     drv.traffic = SimpleNamespace(process="poisson")
     # one request with 99 gaps of 10 ms, one with a single gap of 1 s

@@ -17,7 +17,7 @@ def _reader():
 
 
 def _run(spans):
-    return Run(cfg={}, peaks={}, driver=None,
+    return Run(cfg={}, sizes=None, peaks={}, driver=None,
                spans=[Span(n, a, b, {}) for n, a, b in spans])
 
 

@@ -1,4 +1,4 @@
-"""Seeded random weights of a granite block stack, made by the benchmark.
+"""Seeded random weights of a block stack, made by the benchmark.
 
 The benchmark, not the program, makes the weights: the served model gets
 them in the dtype it serves (bfloat16), built on the device in one jitted
@@ -6,71 +6,34 @@ call, and the plain reference makes the same values again, layer by layer,
 in float32. Both go through `leaf`, so a value is the bfloat16 rounding of
 one uniform draw, whichever side asks for it.
 
-Names and shapes follow the layer equations (``attn.wq`` is
-``[d, heads, head_dim]``); `bench/drivers/serve.py` maps them onto the
-program's parameter tree and checks that the shapes agree.
+Which leaves there are is the family's to say (`bench/reference/<family>.py`,
+`leaves`): their names, shapes, inits and scales. Names follow the program's
+parameter tree (``attn.wq`` is ``layers.attn.wq``), and shapes the layer
+equations (``attn.wq`` is ``[d, heads, head_dim]``); `bench/drivers/serve.py`
+maps them onto that tree and checks that the shapes agree.
 """
 from __future__ import annotations
 
 import math
-from typing import Dict, Tuple
+from typing import Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
 SERVED_DTYPE = jnp.bfloat16
 
-
-def dims(cfg: dict) -> dict:
-    """The sizes a granite block needs, read from a configuration file."""
-    d = cfg["hidden_size"]
-    h = cfg["num_attention_heads"]
-    return dict(
-        d=d, h=h, kh=cfg["num_key_value_heads"], hd=cfg.get("head_dim") or d // h,
-        L=cfg["num_hidden_layers"], V=cfg["vocab_size"],
-        f=cfg["intermediate_size"], E=cfg.get("num_local_experts", 0),
-        k=cfg.get("num_experts_per_tok", 0), eps=cfg["rms_norm_eps"],
-        theta=cfg["rope_theta"])
+Shape = Tuple[int, ...]
 
 
-def layer_shapes(cfg: dict) -> Dict[str, Tuple[Tuple[int, ...], str]]:
-    """name -> (shape of one layer's leaf, init) for every per-layer leaf.
-    init is ``gain`` (norm weights near 1) or ``fan_in`` (uniform with
-    variance 1/fan_in over the contracted dimension)."""
-    m = dims(cfg)
-    d, h, kh, hd, f, E = m["d"], m["h"], m["kh"], m["hd"], m["f"], m["E"]
-    s = {
-        "attn.ln": ((d,), "gain"),
-        "attn.wq": ((d, h, hd), "fan_in"),
-        "attn.wk": ((d, kh, hd), "fan_in"),
-        "attn.wv": ((d, kh, hd), "fan_in"),
-        "attn.wo": ((h, hd, d), "fan_in"),
-    }
-    if E:
-        s.update({
-            "moe_ln": ((d,), "gain"),
-            "moe.router": ((d, E), "fan_in"),
-            "moe.w_gate": ((E, d, f), "fan_in"),
-            "moe.w_up": ((E, d, f), "fan_in"),
-            "moe.w_down": ((E, f, d), "fan_in"),
-        })
-    else:
-        s.update({
-            "mlp.ln": ((d,), "gain"),
-            "mlp.w_gate": ((d, f), "fan_in"),
-            "mlp.w_up": ((d, f), "fan_in"),
-            "mlp.w_down": ((f, d), "fan_in"),
-        })
-    return s
-
-
-def _fan_in(name: str, shape) -> int:
-    # the contracted dimensions of each projection
-    if name in ("attn.wo",):
-        return shape[0] * shape[1]
-    if name.startswith("moe.w_"):
-        return shape[1]
-    return shape[0]
+class Leaves(NamedTuple):
+    """The leaves a family draws. A per-layer leaf is stacked over `layers`
+    and drawn with its place in the sorted names as its index; a global leaf
+    has an index of its own, past the per-layer ones. `init` is ``gain``
+    (norm weights near 1) or ``fan_in`` (uniform with standard deviation
+    `scale`)."""
+    layers: int
+    per_layer: Dict[str, Tuple[Shape, str, float]]       # name -> (shape, init, scale)
+    glob: Dict[str, Tuple[int, Shape, str, float]]       # name -> (index, shape, init, scale)
 
 
 def base_key(seed: int):
@@ -90,53 +53,36 @@ def leaf(key, index: int, layer: int, shape, init: str, scale: float):
     return jax.random.uniform(k, shape, jnp.float32, -a, a).astype(SERVED_DTYPE)
 
 
-EMBED_STD = 0.02
-
-
-def _global_leaves(cfg: dict):
-    m = dims(cfg)
-    # (name, index, shape, init, scale); indices past the layer leaves
-    return (("embed", 1000, (m["V"], m["d"]), "fan_in", EMBED_STD),
-            ("final_norm", 1001, (m["d"],), "gain", 0.0))
-
-
-def make_params(cfg: dict, seed: int) -> Dict[str, jax.Array]:
+def make_params(spec: Leaves, seed: int) -> Dict[str, jax.Array]:
     """Every leaf, stacked over layers, in bfloat16, on the default device.
     One jitted call; each layer is drawn inside a `lax.map`, so the float32
     draw of one layer is the largest temporary."""
-    L = dims(cfg)["L"]
-    shapes = layer_shapes(cfg)
 
     def build(key):
         out = {}
-        for i, (name, (shape, init)) in enumerate(sorted(shapes.items())):
-            scale = 1.0 / math.sqrt(_fan_in(name, shape))
+        for i, (name, (shape, init, scale)) in enumerate(sorted(spec.per_layer.items())):
             out[name] = jax.lax.map(
                 lambda l, i=i, shape=shape, init=init, scale=scale:
                     leaf(key, i, l, shape, init, scale),
-                jnp.arange(L))
-        for name, i, shape, init, scale in _global_leaves(cfg):
+                jnp.arange(spec.layers))
+        for name, (i, shape, init, scale) in spec.glob.items():
             out[name] = leaf(key, i, 0, shape, init, scale)
         return out
 
     return jax.jit(build)(base_key(seed))
 
 
-def layer_f32(cfg: dict, seed: int, layer: int) -> Dict[str, jax.Array]:
+def layer_f32(spec: Leaves, seed: int, layer: int) -> Dict[str, jax.Array]:
     """One layer's leaves as float32 (the bfloat16 values, widened)."""
-    shapes = layer_shapes(cfg)
     key = base_key(seed)
-    out = {}
-    for i, (name, (shape, init)) in enumerate(sorted(shapes.items())):
-        scale = 1.0 / math.sqrt(_fan_in(name, shape))
-        out[name] = _leaf_jit(key, i, layer, shape, init, scale).astype(jnp.float32)
-    return out
+    return {name: _leaf_jit(key, i, layer, shape, init, scale).astype(jnp.float32)
+            for i, (name, (shape, init, scale)) in enumerate(sorted(spec.per_layer.items()))}
 
 
-def global_f32(cfg: dict, seed: int) -> Dict[str, jax.Array]:
+def global_f32(spec: Leaves, seed: int) -> Dict[str, jax.Array]:
     key = base_key(seed)
     return {name: _leaf_jit(key, i, 0, shape, init, scale).astype(jnp.float32)
-            for name, i, shape, init, scale in _global_leaves(cfg)}
+            for name, (i, shape, init, scale) in spec.glob.items()}
 
 
 _leaf_jit = jax.jit(leaf, static_argnums=(1, 3, 4, 5))
