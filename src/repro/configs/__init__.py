@@ -21,6 +21,7 @@ from repro.configs.mamba2_130m import CONFIG as _mamba2_130m
 from repro.configs.hymba_1_5b import CONFIG as _hymba_1_5b
 from repro.configs.whisper_medium import CONFIG as _whisper_medium
 from repro.configs.paligemma_3b import CONFIG as _paligemma_3b
+from repro.configs.trinity_mini import CONFIG as _trinity_mini
 
 REGISTRY = {
     c.name: c
@@ -35,6 +36,7 @@ REGISTRY = {
         _hymba_1_5b,
         _whisper_medium,
         _paligemma_3b,
+        _trinity_mini,
     )
 }
 

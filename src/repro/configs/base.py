@@ -31,6 +31,12 @@ class ArchConfig:
     rope_theta: float = 10_000.0
     norm_eps: float = 1e-5
     sliding_window: int = 0    # 0 = full attention
+    full_attn_every: int = 0   # with a window: every n-th layer attends in full
+    rope_full: bool = True     # False: full layers use no positional encoding
+    qk_norm: bool = False      # RMSNorm of q and k over head_dim (one gain)
+    attn_gate: bool = False    # attention output times sigmoid(x W_gate)
+    sandwich_norm: bool = False  # RMSNorm of each branch's output as well
+    embed_scale: float = 1.0   # embedding multiplier (muP: sqrt(d_model))
 
     # MoE ------------------------------------------------------------------
     moe: bool = False
@@ -38,6 +44,11 @@ class ArchConfig:
     top_k: int = 0
     d_ff_expert: int = 0
     capacity_factor: float = 1.25
+    n_dense_layers: int = 0    # leading SwiGLU layers (width d_ff) of an MoE stack
+    n_shared_experts: int = 0  # SwiGLU experts every token passes through
+    router_score: str = "softmax"  # softmax | sigmoid (chosen by score + bias)
+    route_scale: float = 1.0   # routing weights' multiplier, after normalising
+    experts_held: int = 0      # routed experts held here, ids [0, n); 0 = all
     router_aux_coef: float = 0.01
 
     # SSM (Mamba-2 SSD) ------------------------------------------------------
@@ -79,6 +90,28 @@ class ArchConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // max(self.n_heads, 1))
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
+    def layer_windows(self) -> tuple:
+        """Keys each layer attends: its window, or 0 for all of them."""
+        every = self.full_attn_every
+        return tuple(0 if not self.sliding_window or (every and (i + 1) % every == 0)
+                     else self.sliding_window for i in range(self.n_layers))
+
+    @property
+    def paged_only(self) -> bool:
+        """The afmoe block's parts (leading dense layers, windowed and full
+        layers side by side, NoPE, q/k norm, gated attention, sandwich
+        norms, a scaled embedding, a shared expert, sigmoid routing, a
+        share of the experts), which only the paged serving paths run."""
+        return bool(self.n_dense_layers or self.full_attn_every or not self.rope_full
+                    or self.qk_norm or self.attn_gate or self.sandwich_norm
+                    or self.embed_scale != 1.0 or self.n_shared_experts
+                    or self.router_score != "softmax" or self.experts_held)
 
     @property
     def d_inner(self) -> int:
@@ -150,7 +183,6 @@ class ArchConfig:
         """Small same-family config for CPU smoke tests."""
         return self.replace(
             name=self.name + "-smoke",
-            n_layers=2,
             n_enc_layers=2 if self.enc_dec else 0,
             d_model=64,
             n_heads=4,
@@ -166,6 +198,9 @@ class ArchConfig:
             ssm_chunk=16,
             prefix_len=8 if self.vlm else 0,
             sliding_window=32 if self.sliding_window else 0,
+            n_layers=2 + self.full_attn_every + min(self.n_dense_layers, 1),
+            n_dense_layers=min(self.n_dense_layers, 1),
+            experts_held=2 if self.experts_held else 0,
             attn_chunk=32,
             scan_layers=True,
         )

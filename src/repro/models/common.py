@@ -321,6 +321,26 @@ def decode_attention(ctx: ShardingCtx, q, k_cache, v_cache, new_k, new_v, pos,
 # stack updates it in place and never slices a layer's pool out.
 
 
+def window_blocks(window: int, block_size: int, table_width: int) -> int:
+    """Blocks a windowed layer's decode gathers per row: the fewest that
+    always hold the last `window` positions, ceil(window / blk) + 1 (a
+    window starting mid-block spans one more), or the whole table where
+    that is narrower."""
+    return min(-(-window // block_size) + 1, table_width)
+
+
+def window_tables(block_tables, lengths, window: int, block_size: int):
+    """Each row's blocks that hold its last `window` of `lengths` positions:
+    ([B, window_blocks] block ids, [B] logical index of the first one).
+    The slice starts at the block of position lengths - window (or 0) and
+    is clamped to end inside the table."""
+    m = block_tables.shape[1]
+    n = window_blocks(window, block_size, m)
+    first = jnp.clip((lengths - window) // block_size, 0, m - n)
+    cols = first[:, None] + jnp.arange(n, dtype=first.dtype)
+    return jnp.take_along_axis(block_tables, cols, axis=1), first
+
+
 def _gather_layer_pages(k_pools, v_pools, layer, block_tables, d):
     """Gather `block_tables`' pages of one layer and unfold the heads:
     [B, M] block ids -> k, v [B, M*blk, KH, D]."""
@@ -387,7 +407,8 @@ def paged_cache_append_chunk(k_pools, v_pools, layer, block_tables, start,
     return k_pools, v_pools
 
 
-def paged_prefill_attention(q, k_pools, v_pools, layer, block_tables, q_pos):
+def paged_prefill_attention(q, k_pools, v_pools, layer, block_tables, q_pos,
+                            window: int = 0):
     """Causal chunk attention against one layer of a paged KV cache
     (chunked prefill).
 
@@ -396,7 +417,9 @@ def paged_prefill_attention(q, k_pools, v_pools, layer, block_tables, q_pos):
     (or a Python int); block_tables: [B, M] int32 (padded with the garbage
     block 0). Query i attends every pool position <= q_pos[b, i] — its own
     chunk's rows were appended first (`paged_cache_append_chunk`), earlier
-    rows hold the already-prefilled (or prefix-cache-shared) prefix.
+    rows hold the already-prefilled (or prefix-cache-shared) prefix. A
+    windowed layer (`window` > 0) keeps only the keys of the last
+    `window` positions, q_pos - window < j <= q_pos.
     Returns [B, C, H, D]; padded query rows produce garbage the caller
     discards.
     """
@@ -405,30 +428,44 @@ def paged_prefill_attention(q, k_pools, v_pools, layer, block_tables, q_pos):
     s_len, kh = k.shape[1], k.shape[2]
     qg = _group(q, kh) * (d ** -0.5)                   # [B,C,KH,G,D]
     s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k, preferred_element_type=jnp.float32)
-    valid = jnp.arange(s_len)[None, None, :] <= q_pos[:, :, None]  # [B,C,S]
+    k_pos = jnp.arange(s_len)[None, None, :]
+    valid = k_pos <= q_pos[:, :, None]                 # [B,C,S]
+    if window:
+        valid &= k_pos > q_pos[:, :, None] - window
     s = jnp.where(valid[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     o = jnp.einsum("bkgqs,bskd->bqkgd", p, v)
     return o.reshape(b, c, h, d)
 
 
-def paged_decode_attention(q, k_pools, v_pools, layer, block_tables, lengths):
+def paged_decode_attention(q, k_pools, v_pools, layer, block_tables, lengths,
+                           window: int = 0):
     """Decode attention over one layer of a paged KV cache (jnp twin of the
     Pallas `kernels/decode_attention.paged_flash_decode`).
 
     q: [B, 1, H, D]; k_pools/v_pools: [L, NB, blk, KH*D]; layer: [] int32
     (or a Python int); block_tables: [B, M] int32 (padded with the garbage
     block 0); lengths: [B] int32 — request b attends key positions <
-    lengths[b]. Returns [B, 1, H, D]. Rows with lengths == 0 (padding
-    slots in a round) produce garbage the caller discards.
+    lengths[b]. A windowed layer (`window` > 0) attends only positions
+    >= lengths[b] - window, and gathers only the `window_blocks` that hold
+    them, from each row's own place in its table. Returns [B, 1, H, D].
+    Rows with lengths == 0 (padding slots in a round) produce garbage the
+    caller discards.
     """
     b, _, h, d = q.shape
+    k_pos = jnp.arange(block_tables.shape[1] * k_pools.shape[2])[None]   # [1,S]
+    if window:
+        blk = k_pools.shape[2]
+        block_tables, first = window_tables(block_tables, lengths, window, blk)
+        k_pos = first[:, None] * blk + jnp.arange(block_tables.shape[1] * blk)
     k, v = _gather_layer_pages(k_pools, v_pools, layer, block_tables, d)
-    s_len, kh = k.shape[1], k.shape[2]
+    kh = k.shape[2]
     qg = _group(q, kh)[:, 0] * (d ** -0.5)  # [B,KH,G,D]
     s = jnp.einsum("bkgd,bskd->bkgs", qg, k, preferred_element_type=jnp.float32)
-    valid = jnp.arange(s_len)[None, None, None, :] < lengths[:, None, None, None]
-    s = jnp.where(valid, s, NEG_INF)
+    valid = k_pos < lengths[:, None]
+    if window:
+        valid &= k_pos >= lengths[:, None] - window
+    s = jnp.where(valid[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgs,bskd->bkgd", p.astype(q.dtype), v)
     return o.reshape(b, 1, h, d)

@@ -12,6 +12,7 @@ dry-runs (`cfg.scan_layers=False`) so XLA cost analysis counts every layer
 from __future__ import annotations
 
 import functools
+import re
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -56,6 +57,13 @@ def _attn_specs(cfg: ArchConfig) -> Dict[str, ParamSpec]:
         "wv": ParamSpec((d, kh, hd), ("embed", "kv_heads", "head_dim"), init="fan_in"),
         "wo": ParamSpec((h, hd, d), ("heads", "head_dim", "embed"), init="fan_in"),
     }
+    if cfg.qk_norm:
+        s["q_norm"] = ParamSpec((hd,), ("head_dim",), init="ones")
+        s["k_norm"] = ParamSpec((hd,), ("head_dim",), init="ones")
+    if cfg.attn_gate:
+        s["w_gate"] = ParamSpec((d, h, hd), ("embed", "heads", "head_dim"), init="fan_in")
+    if cfg.sandwich_norm:
+        s["post_ln"] = ParamSpec((d,), ("embed",), init="ones")
     if cfg.use_bias:
         s["bq"] = ParamSpec((h, hd), ("heads", "head_dim"), init="zeros")
         s["bk"] = ParamSpec((kh, hd), ("kv_heads", "head_dim"), init="zeros")
@@ -66,15 +74,21 @@ def _attn_specs(cfg: ArchConfig) -> Dict[str, ParamSpec]:
 
 def _mlp_specs(cfg: ArchConfig) -> Dict[str, ParamSpec]:
     d, f = cfg.d_model, cfg.d_ff
-    return {
+    s = {
         "ln": ParamSpec((d,), ("embed",), init="ones"),
         "w_gate": ParamSpec((d, f), ("embed", "mlp"), init="fan_in"),
         "w_up": ParamSpec((d, f), ("embed", "mlp"), init="fan_in"),
         "w_down": ParamSpec((f, d), ("mlp", "embed"), init="fan_in"),
     }
+    if cfg.sandwich_norm:
+        s["post_ln"] = ParamSpec((d,), ("embed",), init="ones")
+    return s
 
 
-def _layer_specs(cfg: ArchConfig, *, cross: bool = False, encoder: bool = False) -> Dict[str, Any]:
+def _layer_specs(cfg: ArchConfig, *, cross: bool = False, encoder: bool = False,
+                 dense: bool = False) -> Dict[str, Any]:
+    """One layer's parameters; `dense` is a leading SwiGLU layer of an MoE
+    stack (`cfg.n_dense_layers`)."""
     s: Dict[str, Any] = {}
     if cfg.family == "ssm":
         s["ssm_ln"] = ParamSpec((cfg.d_model,), ("embed",), init="ones")
@@ -85,9 +99,11 @@ def _layer_specs(cfg: ArchConfig, *, cross: bool = False, encoder: bool = False)
         s["ssm"] = ssm_param_specs(cfg)
     if cross:
         s["cross"] = _attn_specs(cfg)
-    if cfg.moe:
+    if cfg.moe and not dense:
         s["moe"] = moe_param_specs(cfg)
         s["moe_ln"] = ParamSpec((cfg.d_model,), ("embed",), init="ones")
+        if cfg.sandwich_norm:
+            s["moe_post_ln"] = ParamSpec((cfg.d_model,), ("embed",), init="ones")
     else:
         s["mlp"] = _mlp_specs(cfg)
     return s
@@ -106,8 +122,12 @@ def param_specs(cfg: ArchConfig) -> Dict[str, Any]:
     specs: Dict[str, Any] = {
         "embed": ParamSpec((cfg.vocab, d), ("vocab", "embed")),
         "final_norm": ParamSpec((d,), ("embed",), init="ones"),
-        "layers": _stack(_layer_specs(cfg, cross=cfg.enc_dec), cfg.n_layers),
+        "layers": _stack(_layer_specs(cfg, cross=cfg.enc_dec),
+                         cfg.n_layers - cfg.n_dense_layers),
     }
+    if cfg.n_dense_layers:
+        specs["dense_layers"] = _stack(_layer_specs(cfg, dense=True),
+                                       cfg.n_dense_layers)
     if not cfg.tie_embeddings:
         specs["lm_head"] = ParamSpec((d, cfg.vocab), ("embed", "vocab"))
     if cfg.enc_dec:
@@ -352,8 +372,9 @@ def block_decode(p, x, layer_cache, cfg: ArchConfig, ctx: ShardingCtx, *, pos):
 # pages, a block table maps each request's logical positions onto pool
 # blocks, and every request in a round decodes at its OWN position (ragged
 # lengths — the dense path's single scalar `pos` becomes a [B] vector).
-# Supported families: attention (+ MoE FFN); SSM/hybrid recurrent state,
-# cross-attention, and ring (sliding-window) caches keep the dense path.
+# Supported families: attention (+ MoE FFN), windowed layers among them
+# (a mask, and a decode gather bounded to the window's blocks); SSM/hybrid
+# recurrent state and cross-attention keep the dense path.
 #
 # The paged paths name their parts with `jax.named_scope`, so each HLO
 # instruction's `op_name` says which part of the model emitted it:
@@ -361,19 +382,25 @@ def block_decode(p, x, layer_cache, cfg: ArchConfig, ctx: ShardingCtx, *, pos):
 # metadata changes no generated code; `model_scope` reads it back.
 
 SCOPES = ("embed", "attention", "ffn", "unembed")
-SUBSCOPES = ("qkv", "kv_append", "attend", "out",    # attention
-             "router", "experts")                    # ffn (MoE)
+SUBSCOPES = ("qkv", "kv_append", "attend", "attend_window", "out",  # attention
+             "router", "experts", "shared_expert")                  # ffn (MoE)
+
+
+_COND_PART = re.compile(r"cond|branch_\d+_fun")
 
 
 def model_scope(op_name: str) -> str:
     """The model scope an HLO `op_name` path names: its first component in
-    `SCOPES` and the `SUBSCOPES` that follow it (``attention/kv_append``),
-    or ``other`` where it names none (layer-stack slicing, layout copies)."""
+    `SCOPES` and the `SUBSCOPES` that follow it (``attention/kv_append``;
+    a `lax.cond`'s own components between them skipped), or ``other``
+    where it names none (layer-stack slicing, layout copies)."""
     parts = op_name.split("/")
     for i, part in enumerate(parts):
         if part in SCOPES:
             path = [part]
             for sub in parts[i + 1:]:
+                if _COND_PART.fullmatch(sub):
+                    continue    # `lax.cond`'s own components
                 if sub not in SUBSCOPES:
                     break
                 path.append(sub)
@@ -381,131 +408,219 @@ def model_scope(op_name: str) -> str:
     return "other"
 
 
+def _paged_qkv(p, x, cfg: ArchConfig, pos, window: int, full=None):
+    """The normed input and q, k, v at positions `pos` [B, S]: q/k norm
+    where the layer has it, RoPE unless a full layer goes without
+    (`cfg.rope_full`). `full`, where given, is a traced flag: the layer
+    is full, not windowed."""
+    h = rms_norm(x, p["ln"], cfg.norm_eps)
+    q, k, v = _project_qkv(p, h, cfg)
+    if "q_norm" in p:
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+    if cfg.rope_full or (window and full is None):
+        q = apply_rope(q, pos, cfg.rope_theta)
+        k = apply_rope(k, pos, cfg.rope_theta)
+    elif window:
+        q = jnp.where(full, q, apply_rope(q, pos, cfg.rope_theta))
+        k = jnp.where(full, k, apply_rope(k, pos, cfg.rope_theta))
+    return h, q, k, v
+
+
+def _attend(attend, window: int, full=None):
+    """`attend(window)` under its scope (`attend_window` or `attend`); with
+    a traced `full` flag, the full layer's or the windowed one's."""
+    def run(w):
+        with jax.named_scope("attend_window" if w else "attend"):
+            return attend(w)
+
+    if full is None:
+        return run(window)
+    return jax.lax.cond(full, lambda: run(0), lambda: run(window))
+
+
+def _paged_out(p, h, o, cfg: ArchConfig):
+    """The attention output [B, S, H, D] gated by sigmoid(h W_gate) where
+    the layer has a gate, projected, and normed where the layer norms its
+    branch (`cfg.sandwich_norm`)."""
+    if "w_gate" in p:
+        o = o * jax.nn.sigmoid(
+            jnp.einsum("bsd,dhk->bshk", h, p["w_gate"].astype(h.dtype)))
+    out = _attn_out(p, o, cfg)
+    if "post_ln" in p:
+        out = rms_norm(out, p["post_ln"], cfg.norm_eps)
+    return out
+
+
 def paged_attn_decode(p, x, k_pools, v_pools, layer, cfg: ArchConfig, *,
-                      block_tables, lengths):
+                      block_tables, lengths, window: int = 0, full=None):
     """One-token attention against layer `layer` of the paged KV pools.
 
     x: [B, 1, d]; k_pools/v_pools: [L, NB, blk, KH*D]; lengths: [B] int32
     tokens already stored per request (the new KV row is written at that
-    position). Returns (attn_out, k_pools, v_pools).
+    position); `window` > 0: the layer attends only the last `window`
+    positions and gathers only their blocks, unless the traced flag `full`
+    says it is a full layer. Returns (attn_out, k_pools, v_pools).
     """
     with jax.named_scope("attention"):
         with jax.named_scope("qkv"):
-            h = rms_norm(x, p["ln"], cfg.norm_eps)
-            q, k, v = _project_qkv(p, h, cfg)
-            posv = lengths[:, None]  # [B, 1] per-request decode position
-            q = apply_rope(q, posv, cfg.rope_theta)
-            k = apply_rope(k, posv, cfg.rope_theta)
+            # [B, 1] per-request decode position
+            h, q, k, v = _paged_qkv(p, x, cfg, lengths[:, None], window, full)
         with jax.named_scope("kv_append"):
             k_pools, v_pools = paged_cache_append(
                 k_pools, v_pools, layer, block_tables, lengths, k, v)
-        with jax.named_scope("attend"):
-            o = paged_decode_attention(q, k_pools, v_pools, layer,
-                                       block_tables, lengths + 1)
+        o = _attend(lambda w: paged_decode_attention(
+            q, k_pools, v_pools, layer, block_tables, lengths + 1, w), window, full)
         with jax.named_scope("out"):
-            o = _attn_out(p, o, cfg)
+            o = _paged_out(p, h, o, cfg)
     return o, k_pools, v_pools
 
 
 def paged_block_decode(p, x, k_pools, v_pools, layer, cfg: ArchConfig,
-                       ctx: ShardingCtx, *, block_tables, lengths):
+                       ctx: ShardingCtx, *, block_tables, lengths, window: int = 0,
+                       full=None):
     """Layer `layer`, one token per request, paged KV. Returns
     (x, k_pools, v_pools)."""
     attn_o, k_pools, v_pools = paged_attn_decode(
         p["attn"], x, k_pools, v_pools, layer, cfg,
-        block_tables=block_tables, lengths=lengths)
+        block_tables=block_tables, lengths=lengths, window=window, full=full)
     x = _paged_ffn(p, x + attn_o, cfg, ctx)
     return x, k_pools, v_pools
 
 
-def run_layers_decode_paged(layers, k_pools, v_pools, x, cfg: ArchConfig,
+def run_layers_decode_paged(params, k_pools, v_pools, x, cfg: ArchConfig,
                             ctx: ShardingCtx, *, block_tables, lengths):
     """All layers over the stacked pools [L, NB, blk, KH*D]. Returns
     (x, k_pools, v_pools)."""
 
-    def block_fn(lp, x, kp, vp, layer):
+    def block_fn(lp, x, kp, vp, layer, window, full=None):
         return paged_block_decode(lp, x, kp, vp, layer, cfg, ctx,
-                                  block_tables=block_tables, lengths=lengths)
+                                  block_tables=block_tables, lengths=lengths,
+                                  window=window, full=full)
 
-    return _run_layers_paged(layers, k_pools, v_pools, x, cfg, block_fn)
+    return _run_layers_paged(params, k_pools, v_pools, x, cfg, block_fn)
 
 
 def paged_attn_prefill_chunk(p, x, k_pools, v_pools, layer, cfg: ArchConfig,
-                             *, block_tables, start, n_valid):
+                             *, block_tables, start, n_valid, window: int = 0,
+                             full=None):
     """Chunk attention against layer `layer` of the paged KV pools
     [L, NB, blk, KH*D] (chunked prefill).
 
     x: [1, C, d] — a chunk of prompt hidden states at absolute positions
     ``start + i``; the chunk's KV rows are appended into the pool first
     (padding rows masked to the garbage page), then every query attends
-    causally over all pool positions <= its own. Returns
-    (attn_out, k_pools, v_pools).
+    causally over all pool positions <= its own (the last `window` of
+    them in a windowed layer, unless the traced flag `full` says it is a
+    full layer). Returns (attn_out, k_pools, v_pools).
     """
     with jax.named_scope("attention"):
         with jax.named_scope("qkv"):
-            h = rms_norm(x, p["ln"], cfg.norm_eps)
-            q, k, v = _project_qkv(p, h, cfg)
             c = x.shape[1]
             pos = (start + jnp.arange(c, dtype=jnp.int32))[None]  # [1, C]
-            q = apply_rope(q, pos, cfg.rope_theta)
-            k = apply_rope(k, pos, cfg.rope_theta)
+            h, q, k, v = _paged_qkv(p, x, cfg, pos, window, full)
         with jax.named_scope("kv_append"):
             k_pools, v_pools = paged_cache_append_chunk(
                 k_pools, v_pools, layer, block_tables, start, k, v, n_valid)
-        with jax.named_scope("attend"):
-            o = paged_prefill_attention(q, k_pools, v_pools, layer,
-                                        block_tables, pos)
+        o = _attend(lambda w: paged_prefill_attention(
+            q, k_pools, v_pools, layer, block_tables, pos, w), window, full)
         with jax.named_scope("out"):
-            o = _attn_out(p, o, cfg)
+            o = _paged_out(p, h, o, cfg)
     return o, k_pools, v_pools
 
 
 @jax.named_scope("ffn")
 def _paged_ffn(p, x, cfg: ArchConfig, ctx: ShardingCtx):
-    """The post-attention half every paged block shares (MoE or SwiGLU)."""
-    if cfg.moe:
+    """The post-attention half every paged block shares (MoE or SwiGLU),
+    its output normed where the layer norms its branch."""
+    if "moe" in p:
         h = rms_norm(x, p["moe_ln"], cfg.norm_eps)
-        moe_o, _ = moe_layer(p["moe"], h, cfg, ctx)
-        return x + moe_o
-    if "mlp" in p:
+        y, _ = moe_layer(p["moe"], h, cfg, ctx)
+        post = p.get("moe_post_ln")
+    elif "mlp" in p:
         m = p["mlp"]
         h = rms_norm(x, m["ln"], cfg.norm_eps)
-        x = x + swiglu(h, m["w_gate"].astype(h.dtype), m["w_up"].astype(h.dtype),
-                       m["w_down"].astype(h.dtype))
-    return x
+        y = swiglu(h, m["w_gate"].astype(h.dtype), m["w_up"].astype(h.dtype),
+                   m["w_down"].astype(h.dtype))
+        post = m.get("post_ln")
+    else:
+        return x
+    if post is not None:
+        y = rms_norm(y, post, cfg.norm_eps)
+    return x + y
 
 
 def paged_block_prefill_chunk(p, x, k_pools, v_pools, layer, cfg: ArchConfig,
-                              ctx: ShardingCtx, *, block_tables, start, n_valid):
+                              ctx: ShardingCtx, *, block_tables, start, n_valid,
+                              window: int = 0, full=None):
     """Layer `layer`, one prefill chunk, paged KV. Returns
     (x, k_pools, v_pools)."""
     attn_o, k_pools, v_pools = paged_attn_prefill_chunk(
         p["attn"], x, k_pools, v_pools, layer, cfg,
-        block_tables=block_tables, start=start, n_valid=n_valid)
+        block_tables=block_tables, start=start, n_valid=n_valid, window=window,
+        full=full)
     x = _paged_ffn(p, x + attn_o, cfg, ctx)
     return x, k_pools, v_pools
 
 
-def _run_layers_paged(layers, k_pools, v_pools, x, cfg: ArchConfig, block_fn):
-    """Scan (or unroll) `block_fn(lp, x, k_pools, v_pools, layer)` over the
-    layers. The whole pool stacks ride in the carry and each layer writes
-    and reads its own index of them, so the donated pools are updated in
-    place: no layer's pool is sliced out or stacked back."""
+def _layer_groups(params, cfg: ArchConfig):
+    """Each stack of alike layers, with the index of its first layer: the
+    leading dense layers (`dense_layers`), then `layers`."""
+    groups = [(params["layers"], cfg.n_dense_layers)]
+    if "dense_layers" in params:
+        groups.insert(0, (params["dense_layers"], 0))
+    return groups
+
+
+def _run_layers_paged(params, k_pools, v_pools, x, cfg: ArchConfig, block_fn):
+    """Run `block_fn(lp, x, k_pools, v_pools, layer, window[, full])` over every
+    layer in order. The whole pool stacks ride in the carry and each layer
+    writes and reads its own index of them, so the donated pools are
+    updated in place: no layer's pool is sliced out or stacked back."""
+    for layers, first in _layer_groups(params, cfg):
+        x, k_pools, v_pools = _run_stack_paged(layers, first, (x, k_pools, v_pools),
+                                               cfg, block_fn)
+    return x, k_pools, v_pools
+
+
+def _run_stack_paged(layers, first: int, carry, cfg: ArchConfig, block_fn):
+    """One stack, its first layer `first`: a scan over its layers (or an
+    unroll). Where windowed and full layers sit side by side
+    (`cfg.layer_windows`), each scanned layer is told its window and, as a
+    traced flag, whether it is full instead (`block_fn`'s `full`), so that
+    both kinds run in one scan over the stack as stored."""
     n = jax.tree.leaves(layers)[0].shape[0]
-    if cfg.scan_layers:
+    windows = cfg.layer_windows[first:first + n]
+    if not cfg.scan_layers:
+        for i in range(n):
+            lp = jax.tree.map(lambda a: a[i], layers)
+            carry = block_fn(lp, *carry, first + i, windows[i])
+        return carry
+    idx = jnp.arange(first, first + n, dtype=jnp.int32)
+    if len(set(windows)) == 1:
         def body(carry, inp):
             lp, layer = inp
-            return block_fn(lp, *carry, layer), None
+            return block_fn(lp, *carry, layer, windows[0]), None
 
-        (x, k_pools, v_pools), _ = jax.lax.scan(
-            body, (x, k_pools, v_pools),
-            (layers, jnp.arange(n, dtype=jnp.int32)))
-        return x, k_pools, v_pools
+        carry, _ = jax.lax.scan(body, carry, (layers, idx))
+        return carry
 
-    for i in range(n):
-        lp = jax.tree.map(lambda a: a[i], layers)
-        x, k_pools, v_pools = block_fn(lp, x, k_pools, v_pools, i)
-    return x, k_pools, v_pools
+    window = max(windows)
+    full = jnp.asarray([w == 0 for w in windows])
+
+    def mixed(carry, inp):
+        lp, layer, is_full = inp
+        return block_fn(lp, *carry, layer, window, is_full), None
+
+    carry, _ = jax.lax.scan(mixed, carry, (layers, idx, full))
+    return carry
+
+
+def _paged_embed(params, tokens, cfg: ArchConfig):
+    x = embed_lookup(params["embed"], tokens)
+    if cfg.embed_scale != 1.0:
+        x = x.astype(jnp.float32) * cfg.embed_scale
+    return x.astype(jnp.dtype(cfg.dtype))
 
 
 def prefill_chunk_paged(params, k_pools, v_pools, block_tables, start, batch,
@@ -522,19 +637,18 @@ def prefill_chunk_paged(params, k_pools, v_pools, block_tables, start, batch,
     token, k_pools, v_pools).
     """
     if not supports_paged_decode(cfg):
-        raise ValueError(f"paged prefill unsupported for family {cfg.family!r} "
-                         f"(sliding_window={cfg.sliding_window})")
-    dt = jnp.dtype(cfg.dtype)
+        raise ValueError(f"paged prefill unsupported for family {cfg.family!r}")
     with jax.named_scope("embed"):
-        x = embed_lookup(params["embed"], batch["tokens"]).astype(dt)
+        x = _paged_embed(params, batch["tokens"], cfg)
 
-    def block_fn(lp, x, kp, vp, layer):
+    def block_fn(lp, x, kp, vp, layer, window, full=None):
         return paged_block_prefill_chunk(lp, x, kp, vp, layer, cfg, ctx,
                                          block_tables=block_tables,
-                                         start=start, n_valid=n_valid)
+                                         start=start, n_valid=n_valid,
+                                         window=window, full=full)
 
-    x, k_pools, v_pools = _run_layers_paged(params["layers"], k_pools, v_pools,
-                                            x, cfg, block_fn)
+    x, k_pools, v_pools = _run_layers_paged(params, k_pools, v_pools, x, cfg,
+                                            block_fn)
     with jax.named_scope("unembed"):
         last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
         last = rms_norm(last, params["final_norm"], cfg.norm_eps)
@@ -545,7 +659,7 @@ def prefill_chunk_paged(params, k_pools, v_pools, block_tables, start, batch,
 def supports_paged_decode(cfg: ArchConfig) -> bool:
     """Families the paged serving engine can drive (attention KV only)."""
     return (cfg.has_attention and not cfg.hybrid and not cfg.enc_dec
-            and not cfg.vlm and not cfg.sliding_window)
+            and not cfg.vlm)
 
 
 def decode_step_paged(params, k_pools, v_pools, block_tables, lengths, batch,
@@ -555,17 +669,15 @@ def decode_step_paged(params, k_pools, v_pools, block_tables, lengths, batch,
     k_pools/v_pools: [L, NB, blk, KH*D] (`serve.engine.kv_pool_shape`),
     updated in place when donated. batch["tokens"]: [B, 1]; lengths: [B]
     int32 — each request's stored token count (its new KV row is written
-    there, then it attends to lengths+1 positions). Returns
-    (logits [B, 1, V], k_pools, v_pools).
+    there, then it attends to lengths+1 positions, or the last `window` of
+    them in a windowed layer). Returns (logits [B, 1, V], k_pools, v_pools).
     """
     if not supports_paged_decode(cfg):
-        raise ValueError(f"paged decode unsupported for family {cfg.family!r} "
-                         f"(sliding_window={cfg.sliding_window})")
-    dt = jnp.dtype(cfg.dtype)
+        raise ValueError(f"paged decode unsupported for family {cfg.family!r}")
     with jax.named_scope("embed"):
-        x = embed_lookup(params["embed"], batch["tokens"]).astype(dt)
+        x = _paged_embed(params, batch["tokens"], cfg)
     x, k_pools, v_pools = run_layers_decode_paged(
-        params["layers"], k_pools, v_pools, x, cfg, ctx,
+        params, k_pools, v_pools, x, cfg, ctx,
         block_tables=block_tables, lengths=lengths)
     with jax.named_scope("unembed"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -676,8 +788,16 @@ def _xent(logits, targets):
 # ------------------------------------------------------------------- top API
 
 
+def _dense_path(cfg: ArchConfig) -> None:
+    if cfg.paged_only:
+        raise NotImplementedError(
+            f"arch {cfg.name!r} runs through the paged serving paths only "
+            "(prefill_chunk_paged, decode_step_paged)")
+
+
 def loss_fn(params, batch, cfg: ArchConfig, ctx: ShardingCtx = NULL_CTX):
     """Next-token CE loss (+ MoE aux). Returns (loss, metrics)."""
+    _dense_path(cfg)
     dt = jnp.dtype(cfg.dtype)
     if cfg.enc_dec:
         frames = batch["frames"].astype(dt)
@@ -730,6 +850,7 @@ def _pad_cache_seq(layer_caches, cfg: ArchConfig, pad_to: Optional[int]):
 def prefill(params, batch, cfg: ArchConfig, ctx: ShardingCtx = NULL_CTX,
             pad_to: Optional[int] = None):
     """Build decode state from a full prompt. Returns (cache, last_logits)."""
+    _dense_path(cfg)
     dt = jnp.dtype(cfg.dtype)
     cache: Dict[str, Any] = {}
     if cfg.enc_dec:
@@ -765,6 +886,7 @@ def prefill(params, batch, cfg: ArchConfig, ctx: ShardingCtx = NULL_CTX,
 
 def decode_step(params, cache, batch, cfg: ArchConfig, ctx: ShardingCtx = NULL_CTX):
     """One decode step for the whole batch. Returns (logits, new_cache)."""
+    _dense_path(cfg)
     dt = jnp.dtype(cfg.dtype)
     tokens = batch["tokens"]  # [B, 1]
     pos = cache["pos"]

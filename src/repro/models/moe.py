@@ -26,21 +26,46 @@ from repro.sharding import ShardingCtx
 
 
 def moe_param_specs(cfg: ArchConfig) -> Dict[str, ParamSpec]:
-    e, dm, dff = cfg.n_experts, cfg.d_model, cfg.d_ff_expert
-    return {
-        "router": ParamSpec((dm, e), ("embed", "experts"), init="fan_in"),
+    """The router spans every routed expert (`n_experts`); the expert
+    weights are those held here (`n_experts_held`)."""
+    e, dm, dff = cfg.n_experts_held, cfg.d_model, cfg.d_ff_expert
+    s = {
+        "router": ParamSpec((dm, cfg.n_experts), ("embed", "experts"), init="fan_in"),
         "w_gate": ParamSpec((e, dm, dff), ("experts", "embed", "mlp"), init="fan_in"),
         "w_up": ParamSpec((e, dm, dff), ("experts", "embed", "mlp"), init="fan_in"),
         "w_down": ParamSpec((e, dff, dm), ("experts", "mlp", "embed"), init="fan_in"),
     }
+    if cfg.router_score == "sigmoid":
+        # a selection bias (load balancing): it moves which experts are
+        # chosen, never their weights
+        s["expert_bias"] = ParamSpec((cfg.n_experts,), ("experts",), init="zeros")
+    if cfg.n_shared_experts:
+        f = cfg.n_shared_experts * dff
+        s["shared"] = {
+            "w_gate": ParamSpec((dm, f), ("embed", "mlp"), init="fan_in"),
+            "w_up": ParamSpec((dm, f), ("embed", "mlp"), init="fan_in"),
+            "w_down": ParamSpec((f, dm), ("mlp", "embed"), init="fan_in"),
+        }
+    return s
 
 
-def router_topk(x, w_router, top_k: int):
-    """x:[T,d] -> (gates [T,k], experts [T,k] int32, aux_loss scalar)."""
+def router_topk(x, w_router, top_k: int, *, bias=None, scale: float = 1.0):
+    """x:[T,d] -> (gates [T,k], experts [T,k] int32, aux_loss scalar).
+
+    Softmax routing without `bias`. With `bias` (sigmoid routing): scores
+    s = sigmoid(x W_r) in float32, experts chosen by top-k of s + bias,
+    weighted by their s normalised over the k chosen, times `scale`."""
     logits = (x.astype(jnp.float32) @ w_router.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, experts = jax.lax.top_k(probs, top_k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if bias is not None:
+        scores = jax.nn.sigmoid(logits)
+        _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
+        gates = scale * gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+        probs = scores / jnp.maximum(scores.sum(-1, keepdims=True), 1e-9)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, experts = jax.lax.top_k(probs, top_k)
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
     # load-balancing aux loss (Switch-style)
     e = w_router.shape[1]
     me = probs.mean(0)
@@ -101,20 +126,37 @@ def _expert_ffn(xs, wg, wu, wd, *, use_gmm: bool | None = None):
     return jnp.einsum("...tf,...fd->...td", h, wd.astype(xs.dtype))
 
 
-def moe_dense(p, x, cfg: ArchConfig):
-    """Oracle/dense backend: computes every expert for every token via masks.
+def shared_expert(p, x):
+    """The shared experts' SwiGLU, which every token passes through."""
+    h = jax.nn.silu(x @ p["w_gate"].astype(x.dtype)) * (x @ p["w_up"].astype(x.dtype))
+    return h @ p["w_down"].astype(x.dtype)
 
-    x: [B,S,d]. Exact dropless combine; O(T * E * d * dff) flops.
+
+def moe_dense(p, x, cfg: ArchConfig, *, first: int = 0):
+    """Oracle/dense backend: computes every held expert for every token via
+    masks.
+
+    x: [B,S,d]. Routes over all `cfg.n_experts` and combines, dropless,
+    the part of the result the held experts (ids `first` onwards, as many
+    as `p["w_gate"]` holds) give, plus the shared experts' where the layer
+    has them. O(T * E_held * d * dff) flops.
     """
     b, s, d = x.shape
     xt = x.reshape(b * s, d)
     with jax.named_scope("router"):
-        gates, experts, aux = router_topk(xt, p["router"], cfg.top_k)
+        gates, experts, aux = router_topk(xt, p["router"], cfg.top_k,
+                                          bias=p.get("expert_bias"),
+                                          scale=cfg.route_scale)
     with jax.named_scope("experts"):
         outs = _expert_ffn(xt[None], p["w_gate"], p["w_up"], p["w_down"])
-        comb = (jax.nn.one_hot(experts, cfg.n_experts, dtype=xt.dtype)
+        # experts not held here one-hot to a zero row
+        held = experts - first if first else experts
+        comb = (jax.nn.one_hot(held, p["w_gate"].shape[0], dtype=xt.dtype)
                 * gates[..., None])
         y = jnp.einsum("tke,etd->td", comb, outs)     # outs [E,T,d]
+    if "shared" in p:
+        with jax.named_scope("shared_expert"):
+            y = y + shared_expert(p["shared"], xt)
     return y.reshape(b, s, d), aux
 
 

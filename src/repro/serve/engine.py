@@ -58,17 +58,22 @@ it, not a parallel dict. The engine also feeds the process tracer
     round(n)
       admit > prefix_lookup            scheduler admission, cache lookups
       decode_stage > cow_fork          slots, forks, token/table/length arrays
-      decode_round(width, table_width, depth, n_tiles, context_bytes)
+      decode_round(width, table_width, window_blocks, kv_bytes, depth,
+                   n_tiles, context_bytes)
         decode_dispatch                the jitted call, until it returns
         decode_wait                    device_get of the tokens
       emit                             token callbacks and finishes
       cow_fork                         a prefill chunk's first page
-      prefill_chunk(rid, start, n)
+      prefill_chunk(rid, start, n, window_blocks)
         prefill_dispatch, prefill_wait
       numerics_scan, prefix_insert, first_token
 
 plus an async ``request`` span per request lifetime and instant events for
 admissions and cache evictions (preemptions are emitted by the scheduler).
+`window_blocks` is the blocks per row a windowed layer's attention gathers
+(0 in a model without windowed layers); `kv_bytes` the K+V bytes the
+decode program gathers over all layers at the round's padded width, also
+summed in the `serve.kv_bytes_read` counter.
 No span adds a device synchronisation: a dispatch span, and `cow_fork`,
 end when the work is enqueued, not when the device has done it.
 `op_scopes()` names the model scope (`models.lm.SCOPES`) of every
@@ -91,6 +96,7 @@ from repro.core import autotune, guard
 from repro.core.machine import get_machine
 from repro.kernels.decode_attention.decode_attention import paged_decode_spec
 from repro.models import build_model
+from repro.models.common import window_blocks
 from repro.models.lm import model_scope
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -135,6 +141,25 @@ def kv_pool_shape(cfg: ArchConfig, physical_blocks: int,
             cfg.kv_heads * cfg.resolved_head_dim)
 
 
+def round_window_blocks(cfg: ArchConfig, block_size: int, table_width: int) -> int:
+    """Blocks per row a windowed layer's decode attention gathers at
+    `table_width` (`models.common.window_blocks`); 0 without windowed
+    layers."""
+    w = max(cfg.layer_windows, default=0)
+    return window_blocks(w, block_size, table_width) if w else 0
+
+
+def decode_kv_bytes(cfg: ArchConfig, rows: int, table_width: int,
+                    block_size: int) -> int:
+    """K+V bytes one decode round of `rows` rows gathers over all layers:
+    a windowed layer its window's blocks, a full layer the whole table."""
+    wb = round_window_blocks(cfg, block_size, table_width)
+    blocks = sum(wb if w else table_width for w in cfg.layer_windows)
+    per_block = (2 * block_size * cfg.kv_heads * cfg.resolved_head_dim
+                 * jnp.dtype(cfg.dtype).itemsize)
+    return rows * blocks * per_block
+
+
 _HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([^\s=%]+)\s+=\s")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
 
@@ -176,9 +201,8 @@ class PagedServingEngine:
         self.model = build_model(cfg, ctx)
         if not self.model.supports_paged_decode():
             raise ValueError(
-                f"arch {cfg.name!r} (family={cfg.family}, sliding_window="
-                f"{cfg.sliding_window}) needs the dense/ring/recurrent cache "
-                "path; the paged engine serves plain-attention archs")
+                f"arch {cfg.name!r} (family={cfg.family}) needs the dense/"
+                "recurrent cache path; the paged engine serves attention archs")
         self.params = (params if params is not None
                        else self.model.init(jax.random.PRNGKey(seed)))
         self.faults = faults if faults is not None else NULL_INJECTOR
@@ -241,6 +265,7 @@ class PagedServingEngine:
         self._c_cow_forks = m.counter("serve.cow_forks")
         self._c_prefill_s = m.counter("serve.prefill_s")
         self._c_decode_s = m.counter("serve.decode_s")
+        self._c_kv_bytes = m.counter("serve.kv_bytes_read")
         self._h_token = m.histogram("serve.token_latency_s")
         self._h_tbt = m.histogram("serve.tbt_s")   # inter-token gaps
         self._h_ttft = m.histogram("serve.ttft_s")
@@ -490,7 +515,10 @@ class PagedServingEngine:
         tw = self._table_width()
         table = self.pager.padded_table(req.rid, tw)
         t0 = time.perf_counter()
-        with self.tracer.span("prefill_chunk", rid=req.rid, start=start, n=n):
+        # a prefill chunk's windowed layers mask, and gather the whole table
+        wb = tw if any(self.cfg.layer_windows) else 0
+        with self.tracer.span("prefill_chunk", rid=req.rid, start=start, n=n,
+                              window_blocks=wb):
             logits, self.k_pools, self.v_pools, _ = self.prefiller.run_chunk(
                 self.params, self.k_pools, self.v_pools,
                 ctxt[start:start + n], table, start, n)
@@ -663,8 +691,13 @@ class PagedServingEngine:
             guard.check_injected("paged_decode_round", self.faults,
                                  round=self.rounds, width=len(writable))
             decode = self._decode(tw)
+            blk = self.pager.block_size
+            # the program gathers for every row of the round's width
+            kv_bytes = decode_kv_bytes(self.cfg, self.round_width, tw, blk)
             with tracer.span("decode_round", width=len(writable),
-                             table_width=tw, depth=self.solved_depth,
+                             table_width=tw,
+                             window_blocks=round_window_blocks(self.cfg, blk, tw),
+                             kv_bytes=kv_bytes, depth=self.solved_depth,
                              n_tiles=tiles,
                              context_bytes=self._pipeline_ctx_bytes):
                 with tracer.span("decode_dispatch"):
@@ -689,6 +722,7 @@ class PagedServingEngine:
             return 0
         dt = time.perf_counter() - t0
         self._c_decode_s.inc(dt)
+        self._c_kv_bytes.inc(kv_bytes)
 
         # always-on transfer telemetry (ISSUE-6): every decode round feeds
         # the same (machine, kernel) store the paged kernel's pipeline does —

@@ -13,6 +13,10 @@ from repro.models import build_model
 from repro.optim import AdamWConfig, init_state
 from repro.runtime.steps import make_train_step
 
+# the afmoe block (trinity-mini) runs through the paged serving paths only
+DENSE_ARCHS = tuple(a for a in ALL_ARCH_NAMES if not REGISTRY[a].paged_only)
+PAGED_ONLY = tuple(a for a in ALL_ARCH_NAMES if REGISTRY[a].paged_only)
+
 
 def _batch(cfg, b, s, rng):
     front, text = token_split(cfg, s)
@@ -28,7 +32,7 @@ def _batch(cfg, b, s, rng):
     return batch, text
 
 
-@pytest.mark.parametrize("arch", ALL_ARCH_NAMES)
+@pytest.mark.parametrize("arch", DENSE_ARCHS)
 def test_arch_train_step(arch, rng):
     cfg = REGISTRY[arch].reduced()
     model = build_model(cfg)
@@ -44,7 +48,7 @@ def test_arch_train_step(arch, rng):
     assert delta > 0.0
 
 
-@pytest.mark.parametrize("arch", ALL_ARCH_NAMES)
+@pytest.mark.parametrize("arch", DENSE_ARCHS)
 def test_arch_prefill_decode_shapes(arch, rng):
     cfg = REGISTRY[arch].reduced()
     model = build_model(cfg)
@@ -80,6 +84,29 @@ def test_decode_matches_full_forward(arch, rng):
     logits_dec, _ = model.decode_step(params, cache, dbatch)
     rel = float(jnp.abs(logits_full - logits_dec).max()) / float(jnp.abs(logits_full).max())
     assert rel < 2e-4, f"{arch}: decode/full mismatch rel={rel}"
+
+
+@pytest.mark.parametrize("arch", PAGED_ONLY)
+def test_paged_only_arch_serves_and_refuses_the_dense_path(arch, rng):
+    """A reduced paged-only config (windowed and full layers, a leading
+    dense layer, the expert share) serves through the paged engine; the
+    unpaged entry points refuse it rather than compute another model."""
+    from repro.serve import PagedServingEngine
+
+    cfg = REGISTRY[arch].reduced()
+    assert set(cfg.layer_windows) == {0, cfg.sliding_window}
+    eng = PagedServingEngine(cfg, block_size=8, num_blocks=64, max_in_flight=2)
+    for n in (40, 9):
+        eng.submit(rng.randint(0, cfg.vocab, n).tolist(), max_new_tokens=6)
+    stats = eng.run()
+    assert stats["completed"] == 2 and stats["failed"] == 0
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    batch, _ = _batch(cfg, 2, 32, rng)
+    with pytest.raises(NotImplementedError, match="paged"):
+        model.loss(params, batch)
+    with pytest.raises(NotImplementedError, match="paged"):
+        model.prefill(params, batch)
 
 
 def test_mamba2_split_proj_trains(rng):

@@ -219,10 +219,11 @@ def test_round_span_tree_and_parent_links():
     assert {ev["name"] for ev in spans} == set(ROUND_TREE) | {"cow_fork"}
     for ev in spans:
         if ev["name"] == "decode_round":
-            assert set(ev["args"]) == {"width", "table_width", "depth",
-                                       "n_tiles", "context_bytes"}
+            assert set(ev["args"]) == {"width", "table_width", "window_blocks",
+                                       "kv_bytes", "depth", "n_tiles",
+                                       "context_bytes"}
         elif ev["name"] == "prefill_chunk":
-            assert set(ev["args"]) == {"rid", "start", "n"}
+            assert set(ev["args"]) == {"rid", "start", "n", "window_blocks"}
     assert sorted(ev["args"]["n"] for ev in spans
                   if ev["name"] == "round") == list(range(stats["rounds"]))
 

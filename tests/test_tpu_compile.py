@@ -6,7 +6,7 @@ the widths it serves: the decode kernels at head_dim 64 (granite-3-2b) and
 128 (yi-6b), ``ssd_scan`` at mamba2-130m, ``moe_gmm`` at
 granite-moe-1b-a400m and ``stream_triad`` at its solved depth. The
 granite-3-2b paged decode step is compiled whole, and granite-moe-1b-a400m's
-decode and prefill programs at the benchmark's pool size. Nothing runs, so these
+and trinity-mini's decode and prefill programs at the benchmark's pool sizes. Nothing runs, so these
 tests say nothing about results or speed; they catch what Mosaic or XLA
 would refuse on the chip (misaligned tiles, missing lowerings, VMEM or HBM
 overcommit).
@@ -204,3 +204,47 @@ def test_granite_moe_step_programs_update_the_pools_in_place(one_chip, shape,
     assert mem.temp_size_in_bytes < 10**9
     layer_elems = pool.size // dims[0]
     assert _pool_sized_moves(compiled.as_text(), layer_elems) == []
+
+
+@pytest.mark.parametrize("program", ["paged_decode_round",
+                                     "paged_prefill_chunk"])
+def test_trinity_mini_step_programs_update_the_pools_in_place(one_chip, shape,
+                                                              program):
+    """trinity-mini as the benchmark serves it (16 layers, 12 windowed and 4
+    full; 2 dense then 14 expert layers holding 16 of 128 experts; 6144
+    blocks of 16 and the garbage page; a decode round of 20 rows or a
+    prefill chunk of 32, table width 512): both pools alias outputs,
+    temporaries stay under 1 GB, and no instruction copies or slices an
+    array of a pool's shape or of one layer of it. (The relayout of a full
+    layer's gathered pages, [20, 8192, 4, 128], outnumbers one layer's
+    pool at this width, so the check goes by shape, not by size.)"""
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.runtime.layouts import serving_config_overrides
+    from repro.serve.engine import decode_round_fn, kv_pool_shape
+    from repro.serve.prefill import ChunkedPrefiller
+
+    cfg = get_config("trinity-mini").replace(
+        n_layers=16, experts_held=16, dtype="bfloat16",
+        **serving_config_overrides())
+    model = build_model(cfg)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        model.abstract_params())
+    dims = kv_pool_shape(cfg, 6145, 16)
+    pool = shape(dims, jnp.bfloat16)
+    i32 = jnp.int32
+    if program == "paged_decode_round":
+        fn = jax.jit(decode_round_fn(model), donate_argnums=(1, 2))
+        args = (shape((20, 1), i32), shape((20, 512), i32), shape((20,), i32))
+    else:
+        fn = ChunkedPrefiller(model, 16, tracer=None)._fn(32, 512)
+        args = (shape((1, 32), i32), shape((1, 512), i32), shape((), i32),
+                shape((), i32))
+    compiled = fn.lower(params, pool, pool, *args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool.size * pool.dtype.itemsize
+    assert mem.temp_size_in_bytes < 10**9
+    pool_shapes = {",".join(map(str, dims)), ",".join(map(str, dims[1:]))}
+    moves = _pool_sized_moves(compiled.as_text(), pool.size // dims[0])
+    assert [m for m in moves if _HLO_RESULT.match(m).group(2) in pool_shapes] == []
