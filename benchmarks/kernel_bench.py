@@ -32,6 +32,7 @@ from repro.kernels.coro_scatter_add.coro_scatter_add import scatter_add_spec
 from repro.kernels.decode_attention.decode_attention import (
     decode_spec,
     paged_decode_spec,
+    tile_pages,
 )
 from repro.kernels.decode_attention.ops import (
     decode_attention,
@@ -162,20 +163,22 @@ def paged_decode_rows():
         m = s // blk
         nb = bsz * m + 1  # + garbage page 0
         ids = rng.permutation(np.arange(1, nb)).reshape(bsz, m)
-        kp = jnp.zeros((nb, blk, kh, d), jnp.float32)
-        vp = jnp.zeros((nb, blk, kh, d), jnp.float32)
-        kp = kp.at[ids.reshape(-1)].set(k.reshape(bsz * m, blk, kh, d))
-        vp = vp.at[ids.reshape(-1)].set(v.reshape(bsz * m, blk, kh, d))
+        # one layer of the stacked, lane-folded pools the engine serves
+        kp = jnp.zeros((1, nb, blk, kh * d), jnp.float32)
+        vp = jnp.zeros((1, nb, blk, kh * d), jnp.float32)
+        kp = kp.at[0, ids.reshape(-1)].set(k.reshape(bsz * m, blk, kh * d))
+        vp = vp.at[0, ids.reshape(-1)].set(v.reshape(bsz * m, blk, kh * d))
         bt = jnp.asarray(ids, jnp.int32)
         lens = jnp.full((bsz,), s, jnp.int32)
 
         _, us_dense = timed(decode_attention, q, k, v, s - 1, blk=blk, repeats=1)
-        res, us_paged = timed(paged_decode_attention, q, kp, vp, bt, lens,
+        res, us_paged = timed(paged_decode_attention, q, kp, vp, 0, bt, lens,
                               repeats=1)
         ref = decode_attention(q, k, v, s - 1, blk=blk)
         assert bool(jnp.allclose(res, ref, rtol=2e-5, atol=2e-5))
         g = h // kh
-        spec = paged_decode_spec(blk, kh, g, d, jnp.float32, m)
+        spec = paged_decode_spec(blk, kh, g, d, jnp.float32, m,
+                                 tile_pages(blk, m))
         depth = autotune.last_choice("paged_decode")
         out.append(["paged_decode", f"{bsz}x{s}x{kh}x{d}/blk{blk}",
                     spec.context_bytes(depth), depth,

@@ -14,7 +14,8 @@ Serve phase: ``repro.launch.serve.serve(engine="paged", layout="serving",
 strict=True)`` on granite-3-2b at its published widths, with random bf16
 weights from seed 0: 8 requests with prompts up to 512 tokens, 32 new
 tokens each. Every request must complete, with no step fault, some
-prefix-cache hits and finite logits.
+prefix-cache hits and finite logits, and the compiled decode step must hold
+a ``tpu_custom_call`` (its attention is the paged kernel).
 
 Four chips: a few steps of granite-moe-1b-a400m (published widths, cut to
 4 layers) through ``launch/train.py --mesh mini`` on a 2x2 mesh; the large
@@ -118,17 +119,22 @@ def kernel_cases():
     bf16 = jnp.bfloat16
     # decode attention at granite-3-2b (32/8 heads x 64) and yi-6b (32/4 x 128)
     for arch, kh, d in (("granite-3-2b", 8, 64), ("yi-6b", 4, 128)):
-        h, blk, nb, b, m = 32, 16, 512, 8, 32
+        # the stacked, lane-folded pools the engine serves; layer 2 is read
+        h, blk, nb, b, m, n_layers, layer = 32, 16, 512, 8, 32, 3, 2
         q = normal((b, h, d), bf16)
-        kp, vp = normal((nb, blk, kh, d), bf16), normal((nb, blk, kh, d), bf16)
+        kp = normal((n_layers, nb, blk, kh * d), bf16)
+        vp = normal((n_layers, nb, blk, kh * d), bf16)
         tables = (rng.permutation(nb - 1)[:b * m] + 1).reshape(b, m)
         lens = rng.integers(1, m * blk + 1, b)
         cases.append((
-            f"paged_decode_attention {arch} q{tuple(q.shape)} pool{tuple(kp.shape)}",
-            paged_decode_attention,
+            f"paged_decode_attention {arch} q{tuple(q.shape)} pools{tuple(kp.shape)}",
+            lambda q, k, v, t, n, layer=layer:
+                paged_decode_attention(q, k, v, layer, t, n),
             (q, kp, vp, jnp.asarray(tables, jnp.int32), jnp.asarray(lens, jnp.int32)),
-            lambda q=q, kp=kp, vp=vp, t=tables, n=lens:
-                paged_decode_attention_ref(q, kp, vp, t, n)))
+            lambda q=q, kp=kp, vp=vp, t=tables, n=lens, layer=layer, kh=kh, d=d:
+                paged_decode_attention_ref(
+                    q, kp[layer].reshape(nb, blk, kh, d),
+                    vp[layer].reshape(nb, blk, kh, d), t, n)))
         kc, vc = normal((4, 1024, kh, d), bf16), normal((4, 1024, kh, d), bf16)
         qd = normal((4, h, d), bf16)
         cases.append((
@@ -239,7 +245,8 @@ def serve_phase(log: CompileLog) -> None:
         say(f"compiled program {name}: pallas_kernel={programs[name]} "
             f"compile_s={compile_s.get(name, 0.0)}")
     check("paged_decode_round" in programs, "no compiled paged decode step")
-    say(f"decode step holds a Pallas kernel: {programs['paged_decode_round']}")
+    check(programs["paged_decode_round"],
+          f"the decode step holds no {PALLAS_CALL}: its attention is not the kernel")
     peak = jax.devices()[0].memory_stats() or {}
     say(f"serve (one smoke run, not a benchmark): wall_s={wall_s} "
         f"compile_s={log.seconds(mark)} "

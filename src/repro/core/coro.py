@@ -43,6 +43,7 @@ Layering:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import time
 from typing import Any, Callable, Optional, Sequence, Tuple
@@ -77,6 +78,10 @@ class LoadStream:
     a single slice (coarse-grained span request, §III-C case 1) or a list of
     `group` slices (an aset group — e.g. one DMA per gathered row), copied
     into consecutive `tile[0] // group`-row chunks of the slot buffer.
+
+    `live(ctx, tile, j)`, where given, gates copy `j` of a tile (issue and
+    wait alike): a ragged tile fetches only its live chunks, and the rest of
+    the slot keeps what an earlier tile left there, for the body to mask.
     """
 
     name: str
@@ -84,6 +89,7 @@ class LoadStream:
     dtype: Any
     src: Callable[..., Any]
     group: int = 1
+    live: Optional[Callable[..., Any]] = None
 
     def __post_init__(self):
         _check_group(self)
@@ -259,9 +265,12 @@ def coro_loop(
     consume_fn(tile, slot, carry) - the coroutine body after resumption;
                                     returns updated carry
 
-    `n_tiles`/`depth` are Python ints (grid is static); `tile`/`slot` are
-    traced int32 inside the steady-state loop. `depth <= 0` is a no-op that
-    returns `carry_init` (spec-level entry points reject it earlier).
+    `depth` is a Python int; `tile`/`slot` are traced int32 inside the
+    steady-state loop. `n_tiles` is a Python int, or in fori mode a traced
+    per-invocation count: `depth` must then already be clamped to its
+    static bound, and each warm-up issue is guarded by `tile < n_tiles`.
+    `depth <= 0` is a no-op that returns `carry_init` (spec-level entry
+    points reject it earlier).
 
     Two drive modes share the one rotation (warmup / wait / consume /
     recycle) so no kernel re-implements the schedule:
@@ -275,14 +284,21 @@ def coro_loop(
       Warmup runs once under `pl.when(grid_step == 0)`
       (coro_gather, coro_scatter_add, stream_copy).
     """
-    depth = min(depth, n_tiles)
+    static = isinstance(n_tiles, int)
+    if static:
+        depth = min(depth, n_tiles)
+    elif grid_step is not None:
+        raise ValueError("a traced tile count needs fori mode")
     if depth <= 0:
         return carry_init
 
     def warmup():
         # launch the initial coroutine batch (paper's Init Block)
         for t in range(depth):
-            issue_fn(t, t)
+            if static:
+                issue_fn(t, t)
+            else:
+                pl.when(t < n_tiles)(functools.partial(issue_fn, t, t))
 
     def step(t, carry):
         slot = jax.lax.rem(t, depth)
@@ -324,25 +340,41 @@ def _chunk(buf, slot, j: int, tile: Tuple[int, ...], group: int):
     return buf.at[slot, pl.ds(j * rows, rows)]
 
 
+def _when_live(stream, ctx, t, j: int, fn) -> None:
+    """Run `fn` for copy `j` of tile `t`, only where the stream's `live`
+    predicate holds (always, for a stream without one)."""
+    live = getattr(stream, "live", None)
+    if live is None:
+        fn()
+    else:
+        pl.when(live(ctx, t, j))(fn)
+
+
 def _start_loads(stream: LoadStream, buf, sem, ctx, t, slot):
     srcs = _as_group(stream.src(ctx, t), stream.group)
     if stream.group == 1:
-        pltpu.make_async_copy(srcs[0], buf.at[slot], sem.at[slot]).start()
+        copy = pltpu.make_async_copy(srcs[0], buf.at[slot], sem.at[slot])
+        _when_live(stream, ctx, t, 0, copy.start)
         return
     for j, src in enumerate(srcs):
-        pltpu.make_async_copy(src, _chunk(buf, slot, j, stream.tile,
-                                          stream.group), sem.at[slot]).start()
+        copy = pltpu.make_async_copy(src, _chunk(buf, slot, j, stream.tile,
+                                                 stream.group), sem.at[slot])
+        _when_live(stream, ctx, t, j, copy.start)
 
 
-def _wait_group(stream, buf, sem, slot):
+def _wait_group(stream, buf, sem, slot, ctx=None, t=None):
     """Wait out a slot's outstanding copies (self-copy shaped waits): the
-    arrival wait for a LoadStream, the drain for a StoreStream."""
+    arrival wait for a LoadStream (tile `t`'s live copies), the drain for a
+    StoreStream."""
     if stream.group == 1:
-        pltpu.make_async_copy(buf.at[slot], buf.at[slot], sem.at[slot]).wait()
+        c = buf.at[slot]
+        _when_live(stream, ctx, t, 0,
+                   pltpu.make_async_copy(c, c, sem.at[slot]).wait)
         return
     for j in range(stream.group):
         c = _chunk(buf, slot, j, stream.tile, stream.group)
-        pltpu.make_async_copy(c, c, sem.at[slot]).wait()
+        _when_live(stream, ctx, t, j,
+                   pltpu.make_async_copy(c, c, sem.at[slot]).wait)
 
 
 def _start_stores(stream: StoreStream, buf, sem, ctx, t, slot):
@@ -373,6 +405,7 @@ def coro_pipeline(
     epilogue: Optional[Callable] = None,
     carry_init: Any = 0,
     grid_step: Any = None,
+    live_tiles: Optional[Callable] = None,
 ):
     """Drive a `CoroSpec` inside a Pallas kernel.
 
@@ -382,6 +415,9 @@ def coro_pipeline(
         reset (accumulators, recurrent state) before warmup.
     epilogue(ctx, carry)                   - fori mode only: after the final
         store drain (normalize, write residual outputs).
+    live_tiles(ctx) -> traced int          - fori mode, loads only: the tiles
+        this invocation walks (at most `n_tiles`, the static bound the
+        depth is clamped to), read after the prologue.
 
     Store semantics (the RMW pipeline shared by coro_scatter_add and
     stream_copy): a slot's previous write-back is drained before the body
@@ -394,6 +430,8 @@ def coro_pipeline(
     depth = min(depth, n_tiles)
     if grid_step is not None and (prologue or epilogue):
         raise ValueError("prologue/epilogue require fori mode (grid_step=None)")
+    if live_tiles is not None and spec.stores:
+        raise ValueError("a traced tile count drives load-only pipelines")
 
     def issue(t, slot):
         for s, buf in zip(spec.loads, load_bufs):
@@ -401,7 +439,7 @@ def coro_pipeline(
 
     def wait(t, slot):
         for s, buf in zip(spec.loads, load_bufs):
-            _wait_group(s, buf, load_sem, slot)
+            _wait_group(s, buf, load_sem, slot, ctx, t)
 
     def consume(t, slot, carry):
         if spec.stores:
@@ -420,7 +458,8 @@ def coro_pipeline(
     if prologue is not None:
         carry_init = prologue(ctx)
 
-    carry = coro_loop(n_tiles, depth, issue, consume, wait, carry_init,
+    n_live = n_tiles if live_tiles is None else live_tiles(ctx)
+    carry = coro_loop(n_live, depth, issue, consume, wait, carry_init,
                       grid_step=grid_step)
 
     if spec.stores:
@@ -464,6 +503,7 @@ def coro_call(
     num_scalar_prefetch: int = 0,
     input_output_aliases=None,
     interpret: bool = False,
+    live_tiles: Optional[Callable] = None,
 ):
     """Build and run the Pallas call for a `CoroSpec` kernel.
 
@@ -471,7 +511,8 @@ def coro_call(
     prefetch args, then inputs, then outputs); spec callbacks see them as
     `ctx.<name>`. `drive_axis` selects grid mode (that grid axis supplies
     the tile loop) vs fori mode (None: the pipeline runs inside each kernel
-    invocation).
+    invocation). In fori mode `live_tiles(ctx)` may bound each invocation
+    to a traced tile count of its own; `n_tiles` is then the static bound.
 
     With `depth=None` the pipeline depth is solved by
     `core.autotune.choose_depth` from the spec's tile profile, the VMEM cap
@@ -538,7 +579,8 @@ def coro_call(
             coro_pipeline(spec, ctx, load_bufs, store_bufs, load_sem,
                           store_sem, n_tiles=n_tiles, depth=run_depth,
                           body=body, prologue=prologue, epilogue=epilogue,
-                          carry_init=carry_init, grid_step=grid_step)
+                          carry_init=carry_init, grid_step=grid_step,
+                          live_tiles=live_tiles)
 
         # Mosaic compiles the kernel under the same VMEM budget the depth
         # solver capped its context bytes at (autotune.choose_depth reads

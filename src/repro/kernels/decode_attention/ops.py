@@ -21,15 +21,16 @@ def decode_attention(q, k_cache, v_cache, pos, *, blk: int = 128,
                         interpret=interpret)
 
 
-def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
-                           depth: int | None = None,
+def paged_decode_attention(q, k_pools, v_pools, layer, block_tables, lengths,
+                           *, window: int = 0, depth: int | None = None,
                            interpret: bool | None = None):
-    """Ragged-batch decode over a paged KV block pool (see
+    """Ragged-batch decode over one layer of the stacked paged KV pools (see
     `decode_attention.paged_flash_decode`). ``depth=None`` solves the
     pipeline depth from the page-tile `CoroSpec` via core.autotune."""
     interpret = default_interpret() if interpret is None else interpret
-    return paged_flash_decode(q, k_pool, v_pool, block_tables, lengths,
-                              depth=depth, interpret=interpret)
+    return paged_flash_decode(q, k_pools, v_pools, layer, block_tables,
+                              lengths, window=window, depth=depth,
+                              interpret=interpret)
 
 
 # -------- fallback twins (core.guard degradation path, ISSUE-10) --------
@@ -48,17 +49,16 @@ def _flash_decode_twin(spec, pos, q, k_cache, v_cache):
                                 _heads_unfolded(v_cache, d), pos[0])
 
 
-def _paged_decode_twin(spec, bt_flat, lengths, q, k_pool, v_pool):
-    # models.common.paged_decode_attention is the traceable masked twin the
-    # serving engine already trusts; the seed paged_decode_attention_ref is
-    # a host loop (int(lengths[r])) and cannot police traced calls.
-    # It reads a stack of lane-folded pools, the layout the kernel streams:
-    # this one pool is the stack's only layer.
-    from repro.models.common import paged_decode_attention as paged_twin
+def _paged_decode_twin(spec, layer, bt_flat, lengths, lows, q, k_pools,
+                       v_pools):
+    # models.common.paged_decode_twin is the traceable masked twin the
+    # serving engine runs off a TPU; the seed paged_decode_attention_ref is
+    # a host loop (int(lengths[r])) and cannot police traced calls. The
+    # kernel's window comes as each row's first attended position, `lows`.
+    from repro.models.common import paged_decode_twin
     b = q.shape[0]
-    m = bt_flat.shape[0] // b
-    out = paged_twin(q[:, None], k_pool[None], v_pool[None], 0,
-                     bt_flat.reshape(b, m), lengths)
+    out = paged_decode_twin(q[:, None], k_pools, v_pools, layer[0],
+                            bt_flat.reshape(b, -1), lengths, lo=lows)
     return out[:, 0].astype(q.dtype)
 
 

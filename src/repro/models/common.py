@@ -19,6 +19,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.core import machine
+from repro.kernels.decode_attention.decode_attention import (
+    paged_flash_decode,
+    window_blocks,
+)
 from repro.sharding import ShardingCtx
 
 # --------------------------------------------------------------------- basics
@@ -321,14 +326,6 @@ def decode_attention(ctx: ShardingCtx, q, k_cache, v_cache, new_k, new_v, pos,
 # stack updates it in place and never slices a layer's pool out.
 
 
-def window_blocks(window: int, block_size: int, table_width: int) -> int:
-    """Blocks a windowed layer's decode gathers per row: the fewest that
-    always hold the last `window` positions, ceil(window / blk) + 1 (a
-    window starting mid-block spans one more), or the whole table where
-    that is narrower."""
-    return min(-(-window // block_size) + 1, table_width)
-
-
 def window_tables(block_tables, lengths, window: int, block_size: int):
     """Each row's blocks that hold its last `window` of `lengths` positions:
     ([B, window_blocks] block ids, [B] logical index of the first one).
@@ -438,19 +435,41 @@ def paged_prefill_attention(q, k_pools, v_pools, layer, block_tables, q_pos,
     return o.reshape(b, c, h, d)
 
 
+def decode_kernel_on() -> bool:
+    """Whether paged decode attention runs the Pallas kernel: on a TPU, by
+    the platform check every kernel entry uses; the jnp twin elsewhere."""
+    return not machine.default_interpret()
+
+
 def paged_decode_attention(q, k_pools, v_pools, layer, block_tables, lengths,
                            window: int = 0):
-    """Decode attention over one layer of a paged KV cache (jnp twin of the
-    Pallas `kernels/decode_attention.paged_flash_decode`).
+    """Decode attention over one layer of a paged KV cache: the Pallas
+    `kernels/decode_attention.paged_flash_decode` on a TPU (each row's live
+    pages, read in place from the stacked pools), `paged_decode_twin`
+    elsewhere. Arguments and result as `paged_decode_twin`'s."""
+    if decode_kernel_on():
+        out = paged_flash_decode(q[:, 0], k_pools, v_pools, layer,
+                                 block_tables, lengths, window=window,
+                                 interpret=False)
+        return out[:, None]
+    return paged_decode_twin(q, k_pools, v_pools, layer, block_tables,
+                             lengths, window)
+
+
+def paged_decode_twin(q, k_pools, v_pools, layer, block_tables, lengths,
+                      window: int = 0, lo=None):
+    """Decode attention over one layer of a paged KV cache in jnp: the
+    reference the Pallas kernel is held to, and the guard's fallback.
 
     q: [B, 1, H, D]; k_pools/v_pools: [L, NB, blk, KH*D]; layer: [] int32
     (or a Python int); block_tables: [B, M] int32 (padded with the garbage
     block 0); lengths: [B] int32 — request b attends key positions <
     lengths[b]. A windowed layer (`window` > 0) attends only positions
     >= lengths[b] - window, and gathers only the `window_blocks` that hold
-    them, from each row's own place in its table. Returns [B, 1, H, D].
-    Rows with lengths == 0 (padding slots in a round) produce garbage the
-    caller discards.
+    them, from each row's own place in its table. `lo` [B], where given,
+    is each row's first attended position instead (the kernel's form of a
+    window), over the whole table. Returns [B, 1, H, D]; a row with no
+    position to attend (lengths == 0) gives zeros, as the kernel does.
     """
     b, _, h, d = q.shape
     k_pos = jnp.arange(block_tables.shape[1] * k_pools.shape[2])[None]   # [1,S]
@@ -458,14 +477,15 @@ def paged_decode_attention(q, k_pools, v_pools, layer, block_tables, lengths,
         blk = k_pools.shape[2]
         block_tables, first = window_tables(block_tables, lengths, window, blk)
         k_pos = first[:, None] * blk + jnp.arange(block_tables.shape[1] * blk)
+        lo = lengths - window
     k, v = _gather_layer_pages(k_pools, v_pools, layer, block_tables, d)
     kh = k.shape[2]
     qg = _group(q, kh)[:, 0] * (d ** -0.5)  # [B,KH,G,D]
     s = jnp.einsum("bkgd,bskd->bkgs", qg, k, preferred_element_type=jnp.float32)
     valid = k_pos < lengths[:, None]
-    if window:
-        valid &= k_pos >= lengths[:, None] - window
-    s = jnp.where(valid[:, None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
+    if lo is not None:
+        valid &= k_pos >= lo[:, None]
+    valid = valid[:, None, None]
+    p = jnp.where(valid, jax.nn.softmax(jnp.where(valid, s, NEG_INF), axis=-1), 0)
     o = jnp.einsum("bkgs,bskd->bkgd", p.astype(q.dtype), v)
     return o.reshape(b, 1, h, d)

@@ -373,8 +373,9 @@ def block_decode(p, x, layer_cache, cfg: ArchConfig, ctx: ShardingCtx, *, pos):
 # blocks, and every request in a round decodes at its OWN position (ragged
 # lengths — the dense path's single scalar `pos` becomes a [B] vector).
 # Supported families: attention (+ MoE FFN), windowed layers among them
-# (a mask, and a decode gather bounded to the window's blocks); SSM/hybrid
-# recurrent state and cross-attention keep the dense path.
+# (a mask, and a decode read bounded to the window's blocks); SSM/hybrid
+# recurrent state and cross-attention keep the dense path. On a TPU decode
+# attention is the Pallas paged kernel (`models.common.paged_decode_attention`).
 #
 # The paged paths name their parts with `jax.named_scope`, so each HLO
 # instruction's `op_name` says which part of the model emitted it:
@@ -459,7 +460,7 @@ def paged_attn_decode(p, x, k_pools, v_pools, layer, cfg: ArchConfig, *,
     x: [B, 1, d]; k_pools/v_pools: [L, NB, blk, KH*D]; lengths: [B] int32
     tokens already stored per request (the new KV row is written at that
     position); `window` > 0: the layer attends only the last `window`
-    positions and gathers only their blocks, unless the traced flag `full`
+    positions and reads only their blocks, unless the traced flag `full`
     says it is a full layer. Returns (attn_out, k_pools, v_pools).
     """
     with jax.named_scope("attention"):

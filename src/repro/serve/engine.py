@@ -19,11 +19,12 @@ first (`KVPager.ensure_writable` + a physical page copy here). Under pool
 pressure the engine reclaims least-recently-hit cache-only pages before the
 scheduler resorts to preemption.
 
-The decode math runs through the jnp twin (`models.common`), which jits on
-any backend, on every platform: the compiled decode step holds no Pallas
-kernel yet. `kernels/decode_attention.paged_flash_decode` is the TPU
-pipeline meant to replace it (validated for parity in
-tests/test_serve_paged.py, benchmarked in benchmarks/kernel_bench.py).
+On a TPU the decode step attends through the Pallas kernel
+`kernels/decode_attention.paged_flash_decode`, which reads each row's live
+pages in place from the stacked pools; elsewhere through its jnp twin
+(`models.common.paged_decode_twin`, which gathers at the padded width).
+The choice is the platform's (`models.common.decode_kernel_on`). The
+prefill chunk attends through the jnp gather on every platform.
 
 Because freed pages are reused immediately, the aggregate KV served over a
 workload routinely exceeds what the same HBM held as a dense
@@ -72,7 +73,8 @@ plus an async ``request`` span per request lifetime and instant events for
 admissions and cache evictions (preemptions are emitted by the scheduler).
 `window_blocks` is the blocks per row a windowed layer's attention gathers
 (0 in a model without windowed layers); `kv_bytes` the K+V bytes the
-decode program gathers over all layers at the round's padded width, also
+decode program reads over all layers (`decode_kv_bytes`: each row's live
+pages through the kernel, the padded gather through the twin), also
 summed in the `serve.kv_bytes_read` counter.
 No span adds a device synchronisation: a dispatch span, and `cow_fork`,
 end when the work is enqueued, not when the device has done it.
@@ -96,7 +98,7 @@ from repro.core import autotune, guard
 from repro.core.machine import get_machine
 from repro.kernels.decode_attention.decode_attention import paged_decode_spec
 from repro.models import build_model
-from repro.models.common import window_blocks
+from repro.models.common import decode_kernel_on, window_blocks
 from repro.models.lm import model_scope
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -150,14 +152,27 @@ def round_window_blocks(cfg: ArchConfig, block_size: int, table_width: int) -> i
 
 
 def decode_kv_bytes(cfg: ArchConfig, rows: int, table_width: int,
-                    block_size: int) -> int:
-    """K+V bytes one decode round of `rows` rows gathers over all layers:
-    a windowed layer its window's blocks, a full layer the whole table."""
-    wb = round_window_blocks(cfg, block_size, table_width)
-    blocks = sum(wb if w else table_width for w in cfg.layer_windows)
+                    block_size: int, lengths=None) -> int:
+    """K+V bytes one decode round of `rows` rows reads over all layers.
+
+    The jnp twin gathers at the padded width: a windowed layer its
+    window's blocks, a full layer the whole table. Given `lengths`, the
+    positions each of the round's rows attends, the Pallas kernel's read
+    instead: each row's pages from the block of its first attended
+    position (a windowed layer's lengths - window, else 0) to that of its
+    last, and no page past a row's end."""
     per_block = (2 * block_size * cfg.kv_heads * cfg.resolved_head_dim
                  * jnp.dtype(cfg.dtype).itemsize)
-    return rows * blocks * per_block
+    if lengths is None:
+        wb = round_window_blocks(cfg, block_size, table_width)
+        blocks = rows * sum(wb if w else table_width for w in cfg.layer_windows)
+    else:
+        lengths = np.asarray(lengths, np.int64)
+        last = -(-lengths // block_size)
+        first = {w: np.maximum(lengths - w, 0) // block_size if w else 0
+                 for w in set(cfg.layer_windows)}
+        blocks = sum(int((last - first[w]).sum()) for w in cfg.layer_windows)
+    return blocks * per_block
 
 
 _HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([^\s=%]+)\s+=\s")
@@ -216,12 +231,14 @@ class PagedServingEngine:
         kh, hd, g = cfg.kv_heads, cfg.resolved_head_dim, cfg.n_heads // cfg.kv_heads
 
         # scheduler <-> autotune coupling: in-flight requests per round =
-        # the solved pipeline depth of the paged decode spec (clamped to 2+)
+        # the solved pipeline depth of the paged decode spec at one page a
+        # tile (clamped to 2+); the kernel's own tiles are wider
         spec = paged_decode_spec(block_size, kh, g, hd, jnp.dtype(cfg.dtype),
                                  max_blocks=max(num_blocks, 1))
         self.solved_depth = autotune.choose_depth(
             spec.profile(), kernel="paged_decode", vars=spec.all_vars())
         self._pipeline_ctx_bytes = spec.context_bytes(self.solved_depth)
+        self._decode_kernel = decode_kernel_on()
         # a round can't usefully exceed one block-owning request per block
         self.round_width = int(max_in_flight
                                or min(max(2, self.solved_depth), num_blocks))
@@ -692,8 +709,11 @@ class PagedServingEngine:
                                  round=self.rounds, width=len(writable))
             decode = self._decode(tw)
             blk = self.pager.block_size
-            # the program gathers for every row of the round's width
-            kv_bytes = decode_kv_bytes(self.cfg, self.round_width, tw, blk)
+            # every row of the round's width attends, padding rows one
+            # position; the kernel reads live pages, the twin the padded table
+            kv_bytes = decode_kv_bytes(
+                self.cfg, self.round_width, tw, blk,
+                lengths + 1 if self._decode_kernel else None)
             with tracer.span("decode_round", width=len(writable),
                              table_width=tw,
                              window_blocks=round_window_blocks(self.cfg, blk, tw),

@@ -4,9 +4,9 @@ refcounted sharing with copy-on-write.
 The serving-side analogue of the paper's far-memory arena: the KV cache is
 not a dense ``[batch, max_len]`` allocation but a pool of fixed-size blocks
 ("pages") in HBM, and each request owns a *block table* — the list of pages
-its logical positions map onto. Pages are the coroutine tiles of the paged
-decode kernel (`kernels/decode_attention.paged_flash_decode`): the pipeline
-fetches them through the table, so physical placement is free and freed
+its logical positions map onto. The paged decode kernel
+(`kernels/decode_attention.paged_flash_decode`) fetches them through the
+table, a few pages to a coroutine tile, so physical placement is free and freed
 pages are reused immediately (defrag-free by construction — no page ever
 needs to move).
 

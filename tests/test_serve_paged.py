@@ -282,27 +282,34 @@ def _dense_ref_rows(q, kp, vp, bt, lengths):
     return jnp.concatenate(outs, axis=0)
 
 
-def test_paged_decode_matches_dense_on_ragged_lengths(rng):
-    """One round, per-request lengths spanning 8x: kernel vs the dense
-    models.common.decode_attention entry AND the kernel's own ref oracle."""
-    lengths = [16, 40, 128]  # 8x spread within one round
-    q, kp, vp, bt = _paged_problem(rng, lengths, h=8, kh=2, d=16, blk=16)
-    lens = jnp.asarray(lengths, jnp.int32)
-    out = paged_decode_attention(q, kp, vp, bt, lens)
-    ref = _dense_ref_rows(q, kp, vp, bt, lengths)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-    oracle = paged_decode_attention_ref(q, kp, vp, bt, lens)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
-                               rtol=2e-5, atol=2e-5)
-
-
 def _as_stacked_layer(rng, pool):
     """One layer's [NB, blk, KH, D] pool as layer 1 of a lane-folded
     [2, NB, blk, KH*D] stack whose layer 0 is noise."""
     folded = pool.reshape(*pool.shape[:2], -1)
     noise = jnp.asarray(rng.randn(*folded.shape), folded.dtype)
     return jnp.stack([noise, folded])
+
+
+def _kernel_on_layer(rng, q, kp, vp, bt, lengths, **kw):
+    """The Pallas kernel on `kp`/`vp` as layer 1 of a stacked pool."""
+    return paged_decode_attention(q, _as_stacked_layer(rng, kp),
+                                  _as_stacked_layer(rng, vp), 1, bt,
+                                  jnp.asarray(lengths, jnp.int32), **kw)
+
+
+def test_paged_decode_matches_dense_on_ragged_lengths(rng):
+    """One round, per-request lengths spanning 8x: kernel vs the dense
+    models.common.decode_attention entry AND the kernel's own ref oracle."""
+    lengths = [16, 40, 128]  # 8x spread within one round
+    q, kp, vp, bt = _paged_problem(rng, lengths, h=8, kh=2, d=16, blk=16)
+    lens = jnp.asarray(lengths, jnp.int32)
+    out = _kernel_on_layer(rng, q, kp, vp, bt, lengths)
+    ref = _dense_ref_rows(q, kp, vp, bt, lengths)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    oracle = paged_decode_attention_ref(q, kp, vp, bt, lens)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
+                               rtol=2e-5, atol=2e-5)
 
 
 def test_paged_jnp_twin_matches_dense_on_ragged_lengths(rng):
@@ -318,15 +325,13 @@ def test_paged_jnp_twin_matches_dense_on_ragged_lengths(rng):
 
 def test_paged_decode_explicit_depth_and_padding_rows(rng):
     """Depth sweep + a zero-length padding slot pointing at the garbage
-    page: real rows stay exact, the padding row is finite garbage."""
+    page: real rows stay exact, the padding row is finite."""
     lengths = [32, 8, 0]
     q, kp, vp, bt = _paged_problem(rng, lengths, h=4, kh=2, d=16, blk=8)
     bt = bt.at[2].set(0)  # padding slot: all garbage page
     ref = _dense_ref_rows(q, kp, vp, bt, lengths[:2])
     for depth in (1, 2, 5):
-        out = paged_decode_attention(q, kp, vp, bt,
-                                     jnp.asarray(lengths, jnp.int32),
-                                     depth=depth)
+        out = _kernel_on_layer(rng, q, kp, vp, bt, lengths, depth=depth)
         np.testing.assert_allclose(np.asarray(out[:2]), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
         assert np.isfinite(np.asarray(out)).all()
@@ -342,9 +347,111 @@ def test_paged_decode_ragged_sweep(kh, h, blk, seed):
     lengths = sorted(rng.randint(base, 8 * base + 1, size=4).tolist())
     lengths[0], lengths[-1] = base, max(lengths[-1], 4 * base)  # >=4x spread
     q, kp, vp, bt = _paged_problem(rng, lengths, h=h, kh=kh, d=16, blk=blk)
-    out = paged_decode_attention(q, kp, vp, bt, jnp.asarray(lengths, jnp.int32))
+    out = _kernel_on_layer(rng, q, kp, vp, bt, lengths)
     ref = _dense_ref_rows(q, kp, vp, bt, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+# a round's lengths at blocks of 16 (8 pages, 128 keys, a tile): a padding
+# row, a page's edge, a tile's edge and one past it, and a long row whose
+# last tile is partial
+_ROUND = [0, 16, 128, 129, 300]
+
+
+def _stacked_round(seed, kh, d, g, lengths=_ROUND, n_layers=3, blk=16):
+    """Random stacked, lane-folded pools [n_layers, NB, blk, KH*D] and
+    disjoint shuffled tables for `lengths`, one table column to spare."""
+    rng = np.random.RandomState(seed)
+    pages = [-(-n // blk) for n in lengths]
+    m = max(pages) + 1
+    nb = sum(pages) + 4
+    shape = (n_layers, nb, blk, kh * d)
+    kp = jnp.asarray(rng.randn(*shape), jnp.float32)
+    vp = jnp.asarray(rng.randn(*shape), jnp.float32)
+    ids = rng.permutation(np.arange(1, nb))
+    bt = np.zeros((len(lengths), m), np.int32)
+    off = 0
+    for r, n in enumerate(pages):
+        bt[r, :n] = ids[off:off + n]
+        off += n
+    q = jnp.asarray(rng.randn(len(lengths), kh * g, d), jnp.float32)
+    return q, kp, vp, jnp.asarray(bt), jnp.asarray(lengths, jnp.int32)
+
+
+def _twin(q, kp, vp, layer, bt, lens, window):
+    return mc.paged_decode_twin(q[:, None], kp, vp, layer, bt, lens,
+                                window)[:, 0]
+
+
+_HEADS = pytest.mark.parametrize("kh,d,g", [(8, 64, 2), (4, 128, 8)],
+                                 ids=["kh8-d64-g2", "kh4-d128-g8"])
+# 40 keys from 300 start mid-block (260 = 16 x 16 + 4); 24 from 129 too
+_WINDOWS = pytest.mark.parametrize("window", [0, 40, 24],
+                                   ids=["full", "window40", "window24"])
+
+
+@_HEADS
+@_WINDOWS
+def test_paged_kernel_matches_twin_on_a_ragged_round(kh, d, g, window):
+    """A round with a padding row and rows at a page's and a tile's edge,
+    full or windowed (windows that start mid-block): the kernel's rows
+    equal the twin's; the padding row reads nothing and gives zeros."""
+    q, kp, vp, bt, lens = _stacked_round(7, kh, d, g)
+    out = paged_decode_attention(q, kp, vp, 2, bt, lens, window=window)
+    want = _twin(q, kp, vp, 2, bt, lens, window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(out)[0], 0.0)
+
+
+@pytest.mark.parametrize("window", [0, 40], ids=["full", "window40"])
+def test_paged_kernel_reads_the_stack_by_a_traced_layer(window):
+    """Inside a jitted `lax.scan` over the layers, the traced layer index
+    picks each layer of the stacked pools, read in place."""
+    q, kp, vp, bt, lens = _stacked_round(8, 4, 32, 2)
+
+    @jax.jit
+    def all_layers(q, kp, vp, bt, lens):
+        def body(carry, layer):
+            return carry, paged_decode_attention(q, kp, vp, layer, bt, lens,
+                                                 window=window)
+        return jax.lax.scan(body, 0, jnp.arange(kp.shape[0]))[1]
+
+    outs = all_layers(q, kp, vp, bt, lens)
+    for layer in range(kp.shape[0]):
+        want = _twin(q, kp, vp, layer, bt, lens, window)
+        np.testing.assert_allclose(np.asarray(outs[layer]), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+
+
+@_HEADS
+@pytest.mark.parametrize("window", [0, 40], ids=["full", "window40"])
+def test_paged_kernel_never_reads_pages_outside_a_row(kh, d, g, window):
+    """Every page a row does not attend — past its end (the spare table
+    column and the unused pool pages) or wholly before its window — holds
+    NaN: the kernel's output is finite and equals the twin's on the same
+    pool with those pages finite. (The parity sentinel is off: the twin
+    gathers the whole padded table, so on the poisoned pool it reads them.)"""
+    from repro.core import guard
+
+    guard.set_parity("off")
+    q, kp, vp, bt, lens = _stacked_round(9, kh, d, g)
+    blk = kp.shape[2]
+    table, n = np.asarray(bt), np.asarray(lens)
+    used = set()
+    for r in range(len(n)):
+        lo = max(int(n[r]) - window, 0) if window else 0
+        used.update(table[r, lo // blk:-(-int(n[r]) // blk)].tolist())
+    bt = bt.at[:, -1].set(sorted(set(range(kp.shape[1])) - used)[0])
+    poison = np.ones(kp.shape[1], bool)
+    poison[sorted(used)] = False
+    kp_nan = kp.at[:, poison].set(jnp.nan)
+    vp_nan = vp.at[:, poison].set(jnp.nan)
+    out = paged_decode_attention(q, kp_nan, vp_nan, 1, bt, lens, window=window)
+    assert np.isfinite(np.asarray(out)).all()
+    want = _twin(q, kp, vp, 1, bt, lens, window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
 
@@ -555,3 +662,56 @@ def test_engine_rejects_unservable_shapes():
     with pytest.raises(ValueError):
         PagedServingEngine(get_config("mamba2-130m").reduced(),
                            block_size=4, num_blocks=4)
+
+
+def test_decode_kv_bytes_on_both_paths():
+    """The twin gathers every row at the padded width (a windowed layer its
+    window's blocks); the kernel reads each row's pages from its first
+    attended position's block through its last, none past its end."""
+    from repro.serve.engine import decode_kv_bytes
+
+    granite = get_config("granite-moe-1b-a400m").replace(dtype="bfloat16")
+    per_block = 2 * 16 * 8 * 64 * 2                   # K+V of a page, one layer
+    assert decode_kv_bytes(granite, 4, 192, 16) == 4 * 24 * 192 * per_block
+    # 1, 16 (a page's edge), 17, 128 (a tile's edge), 300 positions
+    lens = [1, 16, 17, 128, 300]
+    assert decode_kv_bytes(granite, 5, 192, 16, lens) == (
+        24 * (1 + 1 + 2 + 8 + 19) * per_block)
+    # 12 layers of window 2048 and 4 full ones, KH*D = 512 as in granite
+    windowed = granite.replace(n_layers=16, sliding_window=2048,
+                               full_attn_every=4)
+    assert windowed.layer_windows.count(2048) == 12
+    wide = decode_kv_bytes(windowed, 3, 512, 16)
+    assert wide == 3 * (12 * 129 + 4 * 512) * per_block
+    # 5000 positions: the window opens at 2952, inside block 184 of 313
+    lens = [100, 2049, 5000]
+    full = [7, 129, 313]
+    win = [7, 129, 313 - 184]
+    assert decode_kv_bytes(windowed, 3, 512, 16, lens) == (
+        (4 * sum(full) + 12 * sum(win)) * per_block)
+
+
+def test_engine_reckons_the_kernels_read_on_the_kernel_path(monkeypatch):
+    """With the platform check saying "kernel", every `decode_round` span
+    carries the live pages of its rows (padding rows attend one position,
+    one page), not the padded width."""
+    from repro.obs import trace as obs_trace
+    from repro.serve import engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "decode_kernel_on", lambda: True)
+    cfg = _f32_cfg()
+    obs_trace.reset()
+    rng = np.random.default_rng(5)
+    eng = PagedServingEngine(cfg, block_size=4, num_blocks=32, max_in_flight=4)
+    for n in (3, 9, 14):
+        eng.submit(rng.integers(0, cfg.vocab, n), max_new_tokens=6)
+    eng.run()
+    per_block = 2 * 4 * cfg.kv_heads * cfg.resolved_head_dim * 4
+    spans = [e["args"] for e in obs_trace.get_tracer().events
+             if e.get("name") == "decode_round" and e.get("ph") == "X"]
+    assert spans
+    for a in spans:
+        pages = a["n_tiles"] + (eng.round_width - a["width"])
+        assert a["kv_bytes"] == cfg.n_layers * pages * per_block
+        assert a["kv_bytes"] < engine_mod.decode_kv_bytes(
+            cfg, eng.round_width, a["table_width"], 4)

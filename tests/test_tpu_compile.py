@@ -73,11 +73,12 @@ def test_decode_kernels_compile(shape, kind, kh, d):
     bf16 = jnp.bfloat16
     q = shape((8, 32, d), bf16)
     if kind == "paged":
-        pool = shape((1024, 16, kh, d), bf16)
+        pools = shape((4, 1024, 16, kh * d), bf16)
         compiled = _compile(
-            lambda q, k, v, bt, ln: paged_flash_decode(q, k, v, bt, ln,
-                                                       interpret=False),
-            q, pool, pool, shape((8, 64), jnp.int32), shape((8,), jnp.int32))
+            lambda q, k, v, layer, bt, ln: paged_flash_decode(
+                q, k, v, layer, bt, ln, interpret=False),
+            q, pools, pools, shape((), jnp.int32), shape((8, 64), jnp.int32),
+            shape((8,), jnp.int32))
     else:
         cache = shape((8, 2048, kh, d), bf16)
         compiled = _compile(
@@ -147,6 +148,31 @@ _HLO_RESULT = re.compile(r"^\s*(?:ROOT\s+)?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\
 _POOL_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
 
 
+@pytest.fixture
+def decode_kernel_on(monkeypatch):
+    """Select the Pallas decode kernel as on a TPU: the platform check says
+    "not interpreted" (the compiles here target a described chip)."""
+    from repro.core import machine
+    monkeypatch.setattr(machine, "default_interpret", lambda: False)
+
+
+def _results_of_shape(hlo_text: str, dims) -> list:
+    """Instructions whose result has the shape `dims`."""
+    want = ",".join(map(str, dims))
+    return [line.strip()[:160] for line in hlo_text.splitlines()
+            if (m := _HLO_RESULT.match(line)) and m.group(2) == want]
+
+
+def _assert_decode_reads_pages_in_place(text: str, rows: int, width: int,
+                                        kh: int, d: int, blk: int = 16):
+    """The decode program holds the kernel, and neither the gathered pages
+    ([rows * width, blk, KH*D]) nor their head unfold
+    ([rows, width * blk, KH, D])."""
+    assert PALLAS_CALL in text
+    assert _results_of_shape(text, (rows * width, blk, kh * d)) == []
+    assert _results_of_shape(text, (rows, width * blk, kh, d)) == []
+
+
 def _pool_sized_moves(hlo_text: str, layer_elems: int):
     """Instructions that copy or slice at least one layer's pool: a `copy`,
     `dynamic-slice` or `dynamic-update-slice` (or a fusion named for one)
@@ -168,13 +194,15 @@ def _pool_sized_moves(hlo_text: str, layer_elems: int):
 
 @pytest.mark.parametrize("program", ["paged_decode_round",
                                      "paged_prefill_chunk"])
-def test_granite_moe_step_programs_update_the_pools_in_place(one_chip, shape,
-                                                             program):
+def test_granite_moe_step_programs_update_the_pools_in_place(
+        one_chip, shape, program, decode_kernel_on):
     """granite-moe-1b-a400m's step programs at the benchmark's sizes (7000
     blocks of 16 and the garbage page; a decode round of 20 rows or a
-    prefill chunk of 32, table width 192) update the donated pools in
-    place: both pools alias outputs, temporaries stay under 1 GB, and no
-    instruction copies or slices a pool-sized array."""
+    prefill chunk of 32, table width 192), with the Pallas decode kernel
+    selected, update the donated pools in place: both pools alias outputs,
+    temporaries stay under 1 GB (the decode round's under 20 MB), and no
+    instruction copies or slices a pool-sized array. The decode round reads
+    the pages through the kernel: no page gather, no head unfold."""
     from repro.configs import get_config
     from repro.models import build_model
     from repro.runtime.layouts import serving_config_overrides
@@ -203,21 +231,28 @@ def test_granite_moe_step_programs_update_the_pools_in_place(one_chip, shape,
     assert mem.alias_size_in_bytes >= 2 * pool_bytes
     assert mem.temp_size_in_bytes < 10**9
     layer_elems = pool.size // dims[0]
-    assert _pool_sized_moves(compiled.as_text(), layer_elems) == []
+    text = compiled.as_text()
+    assert _pool_sized_moves(text, layer_elems) == []
+    if program == "paged_decode_round":
+        assert mem.temp_size_in_bytes < 20 * 10**6
+        _assert_decode_reads_pages_in_place(text, 20, 192, 8, 64)
 
 
 @pytest.mark.parametrize("program", ["paged_decode_round",
                                      "paged_prefill_chunk"])
-def test_trinity_mini_step_programs_update_the_pools_in_place(one_chip, shape,
-                                                              program):
+def test_trinity_mini_step_programs_update_the_pools_in_place(
+        one_chip, shape, program, decode_kernel_on):
     """trinity-mini as the benchmark serves it (16 layers, 12 windowed and 4
     full; 2 dense then 14 expert layers holding 16 of 128 experts; 6144
     blocks of 16 and the garbage page; a decode round of 20 rows or a
-    prefill chunk of 32, table width 512): both pools alias outputs,
-    temporaries stay under 1 GB, and no instruction copies or slices an
-    array of a pool's shape or of one layer of it. (The relayout of a full
-    layer's gathered pages, [20, 8192, 4, 128], outnumbers one layer's
-    pool at this width, so the check goes by shape, not by size.)"""
+    prefill chunk of 32, table width 512), with the Pallas decode kernel
+    selected: both pools alias outputs, temporaries stay under 1 GB (the
+    decode round's under 20 MB), and no instruction copies or slices an
+    array of a pool's shape or of one layer of it. (The prefill chunk's
+    gathered pages outnumber one layer's pool at this width, so the check
+    goes by shape, not by size.) The decode round reads the pages through
+    the kernel: no page gather, full ([20, 512, ...]) or windowed
+    ([20, 129, ...]), and no head unfold."""
     from repro.configs import get_config
     from repro.models import build_model
     from repro.runtime.layouts import serving_config_overrides
@@ -246,5 +281,10 @@ def test_trinity_mini_step_programs_update_the_pools_in_place(one_chip, shape,
     assert mem.alias_size_in_bytes >= 2 * pool.size * pool.dtype.itemsize
     assert mem.temp_size_in_bytes < 10**9
     pool_shapes = {",".join(map(str, dims)), ",".join(map(str, dims[1:]))}
-    moves = _pool_sized_moves(compiled.as_text(), pool.size // dims[0])
+    text = compiled.as_text()
+    moves = _pool_sized_moves(text, pool.size // dims[0])
     assert [m for m in moves if _HLO_RESULT.match(m).group(2) in pool_shapes] == []
+    if program == "paged_decode_round":
+        assert mem.temp_size_in_bytes < 20 * 10**6
+        for width in (512, 129):
+            _assert_decode_reads_pages_in_place(text, 20, width, 4, 128)
